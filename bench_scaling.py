@@ -18,31 +18,21 @@ efficiency is compute-bound noise — but the harness still tracks framework
 regressions (a collective suddenly serializing shows up as a cliff), which
 is why tests run it at tiny sizes.
 
-Usage:  python bench_scaling.py            # 8 virtual CPU devices
+Usage:  python bench_scaling.py                  # every chip of the host
+        python bench_scaling.py --cpu-devices 8  # virtual CPU mesh, on request
 Emits one JSON line:
   {"metric": "weak_scaling_efficiency", "value": E, "unit": "%",
-   "vs_baseline": E/90, "per_n": {...}, "devices": N}
+   "vs_baseline": E/90, "per_n": {...}, "devices": N,
+   "platform": P, "device_kind": K, "device_count": C}
 """
 
+import argparse
 import json
 import os
 import sys
 import time
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-
-
-def _force_virtual_devices(n):
-    from horovod_tpu.utils.devices import force_host_device_count
-    force_host_device_count(n)
-    import jax
-    if len(jax.devices()) < max(n, 2):
-        # a 1-chip TPU host can't produce a scaling curve — run the
-        # harness on the virtual CPU mesh instead (clear_backends forces
-        # platform re-resolution even though a TPU backend exists)
-        from jax.extend import backend as jax_backend
-        jax.config.update("jax_platforms", "cpu")
-        jax_backend.clear_backends()
 
 
 def run_weak_scaling(batch_per_chip=64, hidden=1024, depth=4, steps=8,
@@ -131,8 +121,24 @@ def run_weak_scaling(batch_per_chip=64, hidden=1024, depth=4, steps=8,
     return throughput, efficiency
 
 
-def main():
-    _force_virtual_devices(int(os.environ.get("HOROVOD_SCALING_DEVICES", 8)))
+def main(argv=None):
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--cpu-devices", type=int, default=0,
+                    help="run on an N-device virtual CPU mesh instead of "
+                         "the chips (framework-regression smoke only)")
+    args = ap.parse_args(argv)
+    if args.cpu_devices:
+        from horovod_tpu.utils.devices import force_host_device_count
+        force_host_device_count(args.cpu_devices)
+    import jax
+
+    from horovod_tpu.hardware import device_info
+    if len(jax.devices()) < 2:
+        # one device has no scaling curve, and switching to the CPU
+        # unasked would print a CPU number where a chip's was expected
+        sys.exit(f"bench_scaling.py: found {device_info()}; a scaling "
+                 "curve needs >= 2 devices (--cpu-devices N runs the "
+                 "virtual CPU mesh)")
     env_int = lambda k, d: int(os.environ.get(k, d))
     throughput, efficiency = run_weak_scaling(
         batch_per_chip=env_int("HOROVOD_SCALING_BATCH", 64),
@@ -140,6 +146,7 @@ def main():
         depth=env_int("HOROVOD_SCALING_DEPTH", 4),
         steps=env_int("HOROVOD_SCALING_STEPS", 8),
         warmup=env_int("HOROVOD_SCALING_WARMUP", 2),
+        max_devices=args.cpu_devices or None,
         repeats=env_int("HOROVOD_SCALING_REPEATS", 1))
     top = max(efficiency)
     for n in sorted(throughput):
@@ -152,6 +159,7 @@ def main():
         "vs_baseline": round(efficiency[top] / 90.0, 3),
         "per_n": {str(n): round(efficiency[n], 2) for n in efficiency},
         "devices": top,
+        **device_info(),
     }))
 
 

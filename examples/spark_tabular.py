@@ -25,8 +25,8 @@ def train(num_features, steps):
     """Runs inside each Spark task / local rank process."""
     import jax
     # Spark executors are CPU ranks (as in the reference's Rossmann
-    # example); select the backend explicitly — env JAX_PLATFORMS can be
-    # overridden by images that pre-import jax at interpreter startup.
+    # example): select the backend explicitly, whatever JAX_PLATFORMS the
+    # executor inherited.
     jax.config.update("jax_platforms", "cpu")
     import jax.numpy as jnp
     import numpy as np

@@ -69,16 +69,8 @@ parser.add_argument("--attention",
 args = parser.parse_args()
 
 if args.cpu_devices:
-    # shared helper raises the flag (never duplicates it) and detects a
-    # frozen backend; it leaves TPU-reporting backends alone, so force
-    # the cpu platform explicitly — clear_backends() re-resolves even
-    # though the helper's platform probe created one
     from horovod_tpu.utils.devices import force_host_device_count
-    assert force_host_device_count(args.cpu_devices), \
-        "a jax backend already exists; set XLA_FLAGS before launch"
-    jax.config.update("jax_platforms", "cpu")
-    from jax.extend import backend as _jax_backend
-    _jax_backend.clear_backends()
+    force_host_device_count(args.cpu_devices)
 
 
 def main():

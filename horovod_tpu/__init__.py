@@ -33,10 +33,7 @@ horovod/tensorflow/__init__.py, horovod/common/basics.py):
 
 import numpy as np
 
-from .utils import compat as _compat
-_compat.install()  # jax version shims BEFORE any module touches jax.shard_map
-
-from .version import __version__  # noqa: F401,E402
+from .version import __version__  # noqa: F401
 from . import ops  # noqa: F401
 from .exceptions import (HorovodError, NotInitializedError, ShutDownError,  # noqa: F401
                          DuplicateNameError, MismatchError,

@@ -178,7 +178,7 @@ class ParameterManager:
     # growth is bounded the same way the reference bounds its fusion
     # buffer.
     PREFETCH_MAX = 16
-    # Largest-message guard (BENCH_r05's batch-512 sweep regression): a
+    # Largest-message guard (a batch-512 sweep once regressed this way): a
     # candidate may only become the incumbent if its measured wire
     # goodput at the largest observed message-size bin did not drop more
     # than this fraction below the incumbent's. Protects the big-batch
@@ -391,7 +391,7 @@ class ParameterManager:
         self._samples += 1
         guard_rejected = False
         if score > self._best[0]:
-            # Largest-message guard (BENCH_r05 batch-512 regression): a
+            # Largest-message guard: a
             # candidate whose goodput DROPS vs the incumbent at the
             # largest message size never becomes the incumbent, however
             # its overall score looks — the rejection is recorded in the

@@ -88,8 +88,7 @@ class KVTally:
 class CountingKV:
     """Wraps a KVClient with per-key op tallies plus a local read
     counter (the root's delta around ``coordinate()`` is its
-    reads-per-round). Same four-method surface the coordinator uses, so
-    ``safe_kv_client`` passes it through untouched."""
+    reads-per-round). Same four-method surface the coordinator uses."""
 
     def __init__(self, inner, tally):
         self._inner = inner

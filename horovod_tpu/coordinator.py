@@ -115,7 +115,6 @@ from .controlplane import aggregate as _tree
 from .controlplane.schedule import ScheduleManager
 from .exceptions import CoordinatorError
 from .negotiation import RequestMeta, construct_response
-from .utils.compat import kv_has_try_get, kv_try_get_bytes
 from .utils.logging import get_logger
 
 _logger = get_logger()
@@ -283,7 +282,6 @@ class MultiHostCoordinator:
 
     def __init__(self, config, num_ranks, stats=None, participants=None,
                  client=None, process_index=None, process_count=None):
-        from .utils.compat import safe_kv_client
         if client is None:
             # Normal path: the jax.distributed coordination service.
             # ``client``/``process_index``/``process_count`` exist for the
@@ -297,10 +295,7 @@ class MultiHostCoordinator:
                     "multi-host eager collectives require jax.distributed "
                     "initialization (launch with horovodrun or set "
                     "HOROVOD_TPU_COORDINATOR)")
-        # Old-jaxlib clients are unsafe to poll (compat.safe_kv_client);
-        # on sound generations (and injected KVClients) this is the raw
-        # client unchanged.
-        self._client = safe_kv_client(client)
+        self._client = client
         self._ns = f"{_PREFIX}/{next(_EPOCH)}"
         self.config = config
         self.num_ranks = num_ranks
@@ -913,7 +908,7 @@ class MultiHostCoordinator:
             metrics.COORD_KV_OPS.labels(op="fetch").inc()
             try:
                 if out:
-                    blob = kv_try_get_bytes(self._client, key)
+                    blob = self._client.key_value_try_get_bytes(key)
                 else:
                     blob = self._client.blocking_key_value_get_bytes(
                         key, timeout_ms)
@@ -1246,12 +1241,8 @@ class MultiHostCoordinator:
         # lock: a close() racing this round (ticker vs engine shutdown)
         # must neither crash the in-flight batch nor let it re-create a
         # pool nobody would release. Post-close rounds read serially.
-        # Old jaxlib (no native try-get) reads serially: the blocking-get
-        # fallback is process-wide serialized anyway (utils/compat.py), so
-        # a pool would only add overhead around the same lock.
         pool = None
-        if len(keys) > 1 and not self._closed \
-                and kv_has_try_get(self._client):
+        if len(keys) > 1 and not self._closed:
             pool = self._pool
             if pool is None:
                 with self._lock:
@@ -1288,7 +1279,7 @@ class MultiHostCoordinator:
 
     def _try_get(self, key):
         try:
-            blob = kv_try_get_bytes(self._client, key)
+            blob = self._client.key_value_try_get_bytes(key)
         except Exception as e:  # noqa: BLE001 — classified by caller
             if _is_timeout_error(e):
                 return None

@@ -359,9 +359,8 @@ class HangWatchdog:
                 out[p] = self._beacon_payload()
                 continue
             try:
-                from ..coordinator import kv_try_get_bytes
-                blob = kv_try_get_bytes(
-                    coord._client, f"{coord._ns}/{self.BEACON_KIND}/{p}")
+                blob = coord._client.key_value_try_get_bytes(
+                    f"{coord._ns}/{self.BEACON_KIND}/{p}")
                 if blob is not None:
                     out[p] = json.loads(bytes(blob).decode())
             except Exception:  # noqa: BLE001 — a dead peer has no beacon

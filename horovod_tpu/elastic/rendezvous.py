@@ -25,7 +25,6 @@ import json
 import time
 
 from ..exceptions import CoordinatorError
-from ..utils.compat import kv_try_get_bytes
 from ..utils.logging import get_logger
 
 _logger = get_logger()
@@ -35,18 +34,15 @@ _PREFIX = "hvdtpu-elastic/rdzv"
 
 def _default_client():
     from jax._src import distributed
-
-    from ..utils.compat import safe_kv_client
     client = distributed.global_state.client
     if client is None:
         raise CoordinatorError(
             "elastic rendezvous requires jax.distributed initialization "
             "(launch with horovodrun or set HOROVOD_TPU_COORDINATOR)")
-    # Same transport selection as the coordinator — and crucially the
-    # compat service (when active) is process-lifetime on process 0, so
-    # it is still there between the failed session's teardown and the
-    # recovered session's init.
-    return safe_kv_client(client)
+    # The coordinator's own client: the jax.distributed session outlives
+    # the horovod session, so the store is still there between the failed
+    # session's teardown and the recovered session's init.
+    return client
 
 
 def rendezvous(generation, expected, pid, *, min_workers=1, timeout=60.0,
@@ -85,7 +81,7 @@ def rendezvous(generation, expected, pid, *, min_workers=1, timeout=60.0,
             joined = []
             for p in expected:
                 try:
-                    blob = kv_try_get_bytes(client, f"{ns}/join/{p}")
+                    blob = client.key_value_try_get_bytes(f"{ns}/join/{p}")
                 except Exception:  # noqa: BLE001 — a miss retries below
                     blob = None
                 if blob:

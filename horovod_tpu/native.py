@@ -4,10 +4,10 @@ Reference equivalent: horovod/common/basics.py:22 — ``HorovodBasics`` loads
 the C core with ``ctypes.CDLL`` and the Python layer calls through it. Here
 the library (csrc/ → lib/libhorovod_tpu.so) carries the control plane (stats,
 response cache, fusion planner, timeline writer, message wire format, GP/EI
-autotuner, bf16 converters); if it is missing it is built on first import
-with the in-tree Makefile, and if no toolchain is available every consumer
-falls back to its pure-Python mirror (the behavior contract is identical —
-tests run against both).
+autotuner, bf16 converters); the first use runs the in-tree Makefile, which
+rebuilds it only when csrc/ is newer, and if no toolchain is available every
+consumer falls back to its pure-Python mirror (the behavior contract is
+identical — tests run against both).
 """
 
 import ctypes
@@ -70,11 +70,8 @@ def _declare(lib):
                                        c.c_char, c.c_int64, c.c_int]
     lib.hvd_timeline_cycle.argtypes = [c.c_void_p, c.c_int64]
     lib.hvd_timeline_close.argtypes = [c.c_void_p]
-    try:  # prebuilt libraries may predate the metrics counter splice
-        lib.hvd_timeline_counter.argtypes = [c.c_void_p, c.c_char_p,
-                                             c.c_int64, c.c_double]
-    except AttributeError:
-        pass
+    lib.hvd_timeline_counter.argtypes = [c.c_void_p, c.c_char_p,
+                                         c.c_int64, c.c_double]
 
     lib.hvd_request_list_serialize.restype = c.c_int64
     lib.hvd_request_list_parse.restype = c.c_int
@@ -98,13 +95,23 @@ def _declare(lib):
 
 
 def _build():
+    """Bring lib/libhorovod_tpu.so up to date with csrc/ by running
+    ``make`` — a no-op when the library is newer than every source, so a
+    library left over from an older csrc/ is never loaded. Returns False
+    when the build ran and failed (the library on disk is then stale)."""
     try:
         subprocess.run(["make", "-s"], cwd=_CSRC_DIR, check=True,
                        capture_output=True, timeout=120)
-        return True
-    except (subprocess.SubprocessError, FileNotFoundError, OSError) as e:
+    except FileNotFoundError as e:
+        # no make on this host: a library that exists was put there by
+        # whoever installed the package, and nothing here can date it
         _logger.info("native library build skipped: %s", e)
+    except (subprocess.SubprocessError, OSError) as e:
+        _logger.warning("native library build failed, using the "
+                        "pure-Python control plane: %s",
+                        getattr(e, "stderr", b"") or e)
         return False
+    return True
 
 
 def get_lib():
@@ -115,8 +122,8 @@ def get_lib():
         if _lib is not None or _tried:
             return _lib
         _tried = True
-        if not os.path.exists(_LIB_PATH) and os.path.isdir(_CSRC_DIR):
-            _build()
+        if os.path.isdir(_CSRC_DIR) and not _build():
+            return None
         if os.path.exists(_LIB_PATH):
             try:
                 _lib = _declare(ctypes.CDLL(_LIB_PATH))

@@ -25,8 +25,11 @@ blockwise and accumulates
 
 so gradients are exact without an S x S intermediate. Sequences up to
 one block run as a single kernel cell; longer lengths use the largest
-128-multiple divisor as the block, and only lengths with no such divisor
-fall back to the jax reference implementation (both directions).
+128-multiple divisor as the block. A causal length with no such divisor
+pads up to a block multiple (still the kernels); a non-causal or band-tile
+one raises — nothing here quietly computes attention densely. Callers that
+want the unfused math ask for it (``attention_impl="dense"``,
+``ring_attention(impl="dense")``).
 
 ``flash_attention(..., interpret=True)`` runs the kernels in the Pallas
 interpreter, which is how CPU tests validate them without a TPU.
@@ -55,9 +58,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from ..parallel.ring_attention import (dense_attention, _tile_bwd_math,
-                                       _tile_fwd_math)
 
 NEG_INF = -1e30
 
@@ -350,16 +350,25 @@ def _band_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
 
 
 def _pick_block(s, block_size):
-    """Largest kernel-friendly block that divides s, or None (dense
-    fallback). Short sequences use one block; otherwise blocks stay
-    multiples of 128 so tiles land on the (8, 128) TPU lanes — a 640-long
-    sequence gets block 128, not a silent dense fallback."""
+    """Largest kernel-friendly block that divides s, or None (ragged: the
+    caller pads or raises). Short sequences use one block; otherwise
+    blocks stay multiples of 128 so tiles land on the (8, 128) TPU lanes
+    — a 640-long sequence gets block 128."""
     if s <= block_size:
         return s
     for b in range((block_size // 128) * 128, 0, -128):
         if s % b == 0:
             return b
     return None
+
+
+def _ragged_error(s, block_size):
+    return ValueError(
+        f"flash attention: sequence length {s} exceeds one block "
+        f"({block_size}) and has no 128-multiple block dividing it. Only "
+        "a causal tile at offset 0 can pad (the mask hides the padded "
+        "keys); pad the sequence to a multiple of 128, or ask for the "
+        "unfused math (attention_impl='dense' / ring impl='dense')")
 
 
 def _to_slab(x):
@@ -406,7 +415,7 @@ def _pad_seq(x, s_pad):
 
 
 def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
-    """Returns (out, lse) — lse is None on the dense fallback path."""
+    """Returns (out, lse) with lse shaped (B*H, 1, S)."""
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
     if window is not None:
@@ -422,18 +431,17 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
         # so the causal mask hides them from every real query, and real
         # K rows feed padded queries whose outputs are discarded (their
         # zero cotangents contribute nothing in backward). This keeps
-        # O(S * block) memory where the dense fallback would be O(S^2).
+        # O(S * block) memory where unfused attention would be O(S^2).
         s_pad = -(-s // 128) * 128
         bs = max(block_size, 128)  # 128 is the minimum ragged tile
         out, lse = _flash_fwd_impl(
             _pad_seq(q, s_pad), _pad_seq(k, s_pad), _pad_seq(v, s_pad),
             causal, bs, interpret, window)
-        return out[:, :s], lse[:, :, :s] if lse is not None else None
+        return out[:, :s], lse[:, :, :s]
     if block is None:
         # non-causal ragged tail: the kernel has no length concept to
-        # hide padded K rows, so use the reference implementation
-        return dense_attention(q, k, v, causal=causal,
-                               window=window), None
+        # hide padded K rows
+        raise _ragged_error(s, block_size)
 
     n = s // block
     qs, ks, vs = _to_slab(q), _to_slab(k), _to_slab(v)
@@ -489,29 +497,13 @@ def _flash_fwd(q, k, v, causal, block_size, interpret, window=None):
     return out, (q, k, v, out, lse)
 
 
-def _dense_with_lse(q, k, v, causal, window=None):
-    """Unfused attention that also returns the per-row log-sum-exp —
-    the ragged-shape fallback for flash_attention_with_lse. GQA- and
-    window-aware (shared math: ring_attention._tile_fwd_math)."""
-    d = q.shape[3]
-    return _tile_fwd_math(q, k, v, 0, causal, window, 1.0 / (d ** 0.5))
-
-
 def _tile_lse(q, k, v, causal, window, block_size, interpret):
-    """Static-offset tile with lse: the fused kernel when the length
-    tiles, the jnp math otherwise. Ring attention's diagonal (and
-    fully-visible) tile compute — GQA and window ride the static kernels'
-    own masks and DMA clamps."""
+    """Static-offset tile with lse (B, H, S): ring attention's diagonal
+    (and fully-visible) tile compute — GQA and window ride the static
+    kernels' own masks and DMA clamps."""
     b, s, h, d = q.shape
-    if _pick_block(s, block_size) is None and not causal:
-        # non-causal ragged tail: _flash_fwd_impl's fallback would run
-        # the tile densely WITHOUT the lse — go straight to the lse math
-        # instead of computing the tile twice
-        return _dense_with_lse(q, k, v, causal, window)
     out, lse = _flash_fwd_impl(q, k, v, causal, block_size, interpret,
                                window)
-    if lse is None:
-        return _dense_with_lse(q, k, v, causal, window)
     return out, lse.reshape(b, h, s)
 
 
@@ -536,13 +528,6 @@ def _flash_lse_bwd(causal, block_size, interpret, window, res, g):
     q, k, v, out, lse = res
     g_out, g_lse = g
     b, s, h, d = q.shape
-    if _pick_block(s, block_size) is None and not causal:
-        # mirror of the forward: only non-causal ragged lengths used the
-        # dense path (causal ones took the pad-to-block kernel)
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: _dense_with_lse(q_, k_, v_, causal, window),
-            q, k, v)
-        return vjp((g_out, g_lse))
     # The lse cotangent enters dS as +P*g_lse, i.e. exactly -delta's slot:
     # dS = P * (dO V^T - (delta - g_lse))  — see _flash_bwd's math.
     return _flash_bwd_impl(causal, block_size, interpret, q, k, v, out,
@@ -554,13 +539,6 @@ flash_attention_with_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 def _flash_bwd(causal, block_size, interpret, window, res, g):
     q, k, v, out, lse = res
-    if lse is None:
-        # ragged fallback: exact gradients through the reference impl
-        _, vjp = jax.vjp(
-            lambda q_, k_, v_: dense_attention(q_, k_, v_, causal=causal,
-                                               window=window),
-            q, k, v)
-        return vjp(g)
     return _flash_bwd_impl(causal, block_size, interpret, q, k, v, out,
                            lse, g, None, window)
 
@@ -580,7 +558,8 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
         # Padded rows carry zero cotangents and out=0 (delta=0); lse pads
         # to +1e30 so p = exp(score - lse) underflows to exactly 0 for
         # padded queries (0 * inf NaNs are impossible).
-        assert causal, "non-causal ragged lengths take the dense fallback"
+        if not causal:
+            raise _ragged_error(s, block_size)
         s_pad = -(-s // 128) * 128
         bs = max(block_size, 128)  # mirror of the forward's ragged choice
         lse_pad = jnp.pad(lse, ((0, 0), (0, 0), (0, s_pad - s)),
@@ -706,13 +685,14 @@ flash_attention.defvjp(_flash_fwd, _flash_bwd)
 def _band_tile_fwd(q, k, v, off, window, block_size, interpret):
     """(out, lse) for one causal band tile whose q rows sit ``off``
     (traced) global positions after the visiting kv tile's origin.
-    GQA-aware; jnp fallback on ragged lengths."""
+    GQA-aware; a ragged length raises (a padded key at a traced offset
+    cannot be hidden by the mask)."""
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
     scale = 1.0 / (d ** 0.5)
     block = _pick_block(s, block_size)
     if block is None:
-        return _tile_fwd_math(q, k, v, off, True, window, scale)
+        raise _ragged_error(s, block_size)
     n = s // block
     qs, ks, vs = _to_slab(q), _to_slab(k), _to_slab(v)
     off_arr = jnp.asarray(off, jnp.int32).reshape(1)
@@ -756,6 +736,8 @@ def _band_tile_bwd(q, k, v, g, lse, delta, off, window, block_size,
     h_kv = k.shape[2]
     scale = 1.0 / (d ** 0.5)
     block = _pick_block(s, block_size)
+    if block is None:
+        raise _ragged_error(s, block_size)
     n = s // block
     qs, ks, vs, dos = _to_slab(q), _to_slab(k), _to_slab(v), _to_slab(g)
     lse_s = lse.astype(jnp.float32).reshape(b * h, 1, s)
@@ -825,8 +807,8 @@ def _tile_bwd_dispatch(q, k, v, g, lse, delta, off, causal, window,
                        block_size, interpret):
     """Backward for one ring tile given the GLOBAL lse/delta (B, H, S):
     static kernels for the diagonal (off=None, offset 0) and
-    fully-visible (causal=False) tiles, band kernels for traced offsets,
-    jnp math on ragged lengths. Returns f32 (dq, dk, dv) with dk/dv at
+    fully-visible (causal=False) tiles, band kernels for traced offsets.
+    Returns f32 (dq, dk, dv) with dk/dv at
     the reduced (GQA) head count — the ring's traveling-accumulator
     contract (parallel/ring_attention.py::_ring_core_bwd).
 
@@ -838,20 +820,12 @@ def _tile_bwd_dispatch(q, k, v, g, lse, delta, off, causal, window,
     guarantees the precondition (each row's diagonal tile always sees
     its own key); interpret mode asserts it for any other caller."""
     b, s, h, d = q.shape
-    block = _pick_block(s, block_size)
     if off is not None:
         if interpret:
             jax.debug.callback(_assert_finite_lse, lse)
         # band tile: causal-with-offset (+ optional window)
-        if block is None:
-            dq, dk, dv = _tile_bwd_math(q, k, v, g, lse, delta, off, True,
-                                        window, 1.0 / (d ** 0.5))
-        else:
-            dq, dk, dv = _band_tile_bwd(q, k, v, g, lse, delta, off,
-                                        window, block_size, interpret)
-    elif block is None and not causal:
-        dq, dk, dv = _tile_bwd_math(q, k, v, g, lse, delta, 0, False,
-                                    None, 1.0 / (d ** 0.5))
+        dq, dk, dv = _band_tile_bwd(q, k, v, g, lse, delta, off,
+                                    window, block_size, interpret)
     else:
         # static tile: diagonal (causal, window) or fully-visible; the
         # causal-ragged case takes _flash_bwd_impl's pad-to-block path

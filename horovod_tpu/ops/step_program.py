@@ -1,8 +1,8 @@
 """Compiled hot loop: one jitted, buffer-donated XLA step program.
 
-BENCH_r05 left eager ResNet at 16.2% MFU with ~80 ms/step of per-step
-Python orchestration while the fully in-graph transformer path held 53%
-— the gap is orchestration, not the wire. This module closes it by
+An eager training step pays per-step Python orchestration and a blocking
+device->host readback that a fully in-graph step does not — the gap is
+orchestration, not the wire. This module closes it by
 compiling the *whole* training step — forward, backward, fused gradient
 exchange, optimizer apply, and (opt-in) the guard health matrix — into
 ONE jitted program with donated parameter/optimizer-state buffers, so a
@@ -931,6 +931,14 @@ class CompiledTrainStep:
         (the engine gauge aggregates across objects)."""
         total = self.cache_hits + self.cache_misses
         return self.cache_hits / total if total else 0.0
+
+    @property
+    def donates(self):
+        """Whether the compiled program donates the params/opt-state
+        buffers it is called with: the ``donate=`` pin, else the
+        HOROVOD_FUSION_DONATE policy resolved against the mesh's platform
+        on the first call (None until then)."""
+        return self._donate_eff
 
     def _bind_engine(self, eng):
         """Elastic re-init / fresh session: signatures and deferred guard

@@ -129,8 +129,8 @@ def exchange_gradients(grads, average=True, compression=Compression.none,
     the whole pytree into a few wire buckets) and returns the exchanged
     pytree. With the default ``to_host=False`` the *results* are jax
     device arrays sliced out of the fused buffer inside the jitted wire
-    program — the result readback that dominated the eager step cost
-    (BENCH_r05: 74 of ~80 ms) never happens, and a jitted optimizer
+    program — the result readback that dominates the eager step cost
+    never happens, and a jitted optimizer
     apply consumes them straight from HBM:
 
         grads = hvd.exchange_gradients(grads)           # stays on device

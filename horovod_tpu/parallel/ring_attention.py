@@ -87,8 +87,8 @@ def _tile_masks(sq, sk, off, causal, window):
 
 def _tile_fwd_math(q, k, v, off, causal, window, scale):
     """One tile's normalized attention + per-row lse, in plain jnp — the
-    numerics baseline and the ragged-length fallback for the Pallas tile
-    kernels (ops/flash_attention.py). off = q_global_start -
+    numerics baseline for the Pallas tile kernels
+    (ops/flash_attention.py). off = q_global_start -
     kv_global_start (may be traced). GQA-aware (k/v carry reduced heads).
 
     Fully-masked rows come back with lse ~ NEG_INF and a garbage-but-

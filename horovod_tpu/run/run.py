@@ -17,7 +17,10 @@ The launcher spawns one process per slot directly:
   — this replaces both mpirun's out-of-band wireup and the NIC ring-probe:
   the coordinator address is explicit, so there is nothing to probe);
 - on Cloud TPU pods the platform already supplies topology; ``horovodrun``
-  there is one process per *host* with all local chips visible.
+  there is one process per *host* with all local chips visible;
+- a TPU chip belongs to one process: local slots on a TPU host each get a
+  chip of their own (``_tpu_slot_envs``) or the launch is refused — N
+  workers are never left to race for the same chips.
 
 Behavior parity kept: the CLI flags (-np, -H, -p/--ssh-port,
 --start-timeout, --verbose, --disable-cache accepted), the
@@ -27,6 +30,7 @@ whole-job teardown when any rank fails (mpirun semantics).
 """
 
 import argparse
+import glob
 import os
 import shlex
 import signal
@@ -243,8 +247,57 @@ def _start_timeout_error(start_timeout):
         f"environment variable.")
 
 
+# One process per chip. A TPU chip belongs to one process, and a worker that
+# is given no chip of its own opens every chip of its host, so N local
+# workers would race for the same N chips. libtpu takes the split from the
+# environment — the variables jax's own multi-process harness sets
+# (jax/_src/test_multiprocess.py). Listed here: the process grids that have
+# run on hardware, for a host whose chips ALL take part, one per process.
+_TPU_PROCESS_GRIDS = {4: "2,2,1"}
+
+
+def _local_tpu_chips():
+    """TPU chips this host can open, counted from the device nodes libtpu
+    opens — never through jax: a launcher that touched a backend would
+    hold the chips its children need."""
+    return len(glob.glob("/dev/accel[0-9]*") + glob.glob("/dev/vfio/[0-9]*"))
+
+
+def _tpu_slot_envs(base_env, host_list, np_):
+    """Env that pins one chip to each local slot, indexed by local rank;
+    None when the job opens no local chip more than once (a CPU job, a
+    host without TPUs, remote hosts — which this parent cannot inspect —
+    or ONE process, which drives every chip itself). A local TPU job of
+    any other shape raises: left alone its workers would race for the
+    same chips and fail or hang."""
+    platforms = base_env.get("JAX_PLATFORMS", "")
+    if (np_ == 1 or (platforms and "tpu" not in platforms.split(","))
+            or not all(_is_local(h) for h, _ in host_list)):
+        return None
+    chips = _local_tpu_chips()
+    if chips == 0:
+        return None
+    if len(host_list) != 1 or np_ != chips or chips not in _TPU_PROCESS_GRIDS:
+        raise ValueError(
+            f"-np {np_} on a host with {chips} TPU chip(s): a chip belongs "
+            f"to one process, and local slots can each be given a chip of "
+            f"their own only when -np equals the chip count (supported "
+            f"counts: {sorted(_TPU_PROCESS_GRIDS)}). Run ONE process "
+            f"instead — it drives every chip and hvd.size() is the chip "
+            f"count — or set JAX_PLATFORMS=cpu for a CPU job.")
+    ports = [_free_port() for _ in range(np_)]
+    shared = {
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+        "TPU_PROCESS_BOUNDS": _TPU_PROCESS_GRIDS[chips],
+        "TPU_PROCESS_ADDRESSES": ",".join(f"localhost:{p}" for p in ports),
+        "ALLOW_MULTIPLE_LIBTPU_LOAD": "1",
+    }
+    return [dict(shared, TPU_VISIBLE_CHIPS=str(i), CLOUD_TPU_TASK_ID=str(i),
+                 TPU_PROCESS_PORT=str(ports[i])) for i in range(np_)]
+
+
 def _rank_env(base_env, coordinator, np_, rank, local_rank, local_size,
-              cross_rank, cross_size):
+              cross_rank, cross_size, tpu_slots=None):
     env = dict(base_env)
     env.update({
         "HOROVOD_TPU_COORDINATOR": coordinator,
@@ -260,6 +313,8 @@ def _rank_env(base_env, coordinator, np_, rank, local_rank, local_size,
         "HOROVOD_LOCAL_RANK": str(local_rank),
         "HOROVOD_LOCAL_SIZE": str(local_size),
     })
+    if tpu_slots is not None:
+        env.update(tpu_slots[local_rank])
     return env
 
 
@@ -357,7 +412,8 @@ def check_all_hosts_ssh_successful(hosts, ssh_port=None, fn_cache=None,
 
 
 def launch_via_services(np_, command, host_list, ssh_port=None,
-                        start_timeout=30, verbose=False, env=None):
+                        start_timeout=30, verbose=False, env=None,
+                        tpu_slots=None):
     """RPC launch path: one TaskService per host, one command per slot.
 
     This is the reference's driver/task-service architecture
@@ -434,7 +490,8 @@ def launch_via_services(np_, command, host_list, ssh_port=None,
         for rank, (host, local_rank, local_size, cross_rank) in \
                 enumerate(placements):
             renv = _rank_env(fwd_env, coordinator, np_, rank, local_rank,
-                             local_size, cross_rank, len(host_list))
+                             local_size, cross_rank, len(host_list),
+                             tpu_slots)
             clients[cross_rank].run_command(rank, command, renv)
 
         # mpirun teardown semantics: first failure kills the job. A dead
@@ -927,7 +984,15 @@ def launch(np_, command, hosts=None, ssh_port=None, start_timeout=None,
         from ..config import Config
         start_timeout = Config.from_env().start_timeout
     host_list = _parse_hosts(hosts, np_)
+    tpu_slots = _tpu_slot_envs(env if env is not None else os.environ,
+                               host_list, np_)
     if elastic:
+        if tpu_slots is not None:
+            raise ValueError(
+                "--elastic restarts and resizes single workers, which a "
+                "fixed one-chip-per-process grid cannot follow; on a TPU "
+                "host run one process over every chip, or a CPU worker "
+                "pool (JAX_PLATFORMS=cpu)")
         if any(not _is_local(h) for h, _ in host_list):
             raise ValueError(
                 "--elastic supervises local slots; for multi-host jobs "
@@ -959,7 +1024,8 @@ def launch(np_, command, hosts=None, ssh_port=None, start_timeout=None,
         return launch_via_services(np_, command, host_list,
                                    ssh_port=ssh_port,
                                    start_timeout=start_timeout,
-                                   verbose=verbose, env=env)
+                                   verbose=verbose, env=env,
+                                   tpu_slots=tpu_slots)
     base_env = dict(env if env is not None else os.environ)
     coordinator = f"{host_list[0][0]}:{_free_port()}"
     placements = _placements(host_list, np_)
@@ -972,7 +1038,8 @@ def launch(np_, command, hosts=None, ssh_port=None, start_timeout=None,
         for rank, (host, local_rank, local_size, cross_rank) in \
                 enumerate(placements):
             renv = _rank_env(base_env, coordinator, np_, rank, local_rank,
-                             local_size, cross_rank, len(host_list))
+                             local_size, cross_rank, len(host_list),
+                             tpu_slots)
             if _is_local(host):
                 cmd = command
                 popen_env = renv

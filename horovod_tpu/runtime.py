@@ -72,6 +72,25 @@ _state = _State()
 _logger = get_logger()
 
 
+def compile_cache_dir():
+    """Where this process keeps XLA's persistent compile cache: the
+    directory ``JAX_COMPILATION_CACHE_DIR`` names when it is set, else
+    ``<checkout>/.jax_cache``. The path is part of what a later process
+    must find again, so it is fixed — never derived from a temp dir, a
+    pid or the clock."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
+
+
+def _place_compile_cache():
+    """Point jax at :func:`compile_cache_dir`. With the variable set jax
+    reads it itself and nothing is set in code, so the cache can be
+    placed from outside."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", compile_cache_dir())
+
+
 def _maybe_init_distributed():
     """Join the multi-process job if launcher env vars are present.
 
@@ -84,36 +103,19 @@ def _maybe_init_distributed():
     if not coord:
         return
     # Re-init after shutdown(): the jax.distributed session outlives the
-    # horovod session (like MPI, it initializes once per process) — skip
-    # when the client already exists instead of tripping initialize()'s
-    # call-order check.
-    from jax._src import distributed
-    if distributed.global_state.client is not None:
+    # horovod session (like MPI, it initializes once per process).
+    if jax.distributed.is_initialized():
         return
     # Must run before anything touches an XLA backend (jax.distributed's
-    # contract); the env check above is therefore ordered first.
-    # CPU multi-process jobs additionally need a collectives backend
-    # selected before the CPU client exists — without one, jaxlib
-    # (<= 0.4.37) raises "Multiprocess computations aren't implemented on
-    # the CPU backend" at the first cross-process program. Default to
-    # gloo, but never clobber an explicit user choice (e.g.
-    # JAX_CPU_COLLECTIVES_IMPLEMENTATION=mpi).
-    try:
-        current = jax.config.values.get(
-            "jax_cpu_collectives_implementation", "MISSING")
-        if current in (None, "", "none"):
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # noqa: BLE001 — newer jax may drop/rename the knob
-        pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coord,
-            num_processes=int(os.environ["HOROVOD_TPU_NUM_PROCESSES"]),  # hvdlint: disable=HVD003 -- launcher-worker protocol var
-            process_id=int(os.environ["HOROVOD_TPU_PROCESS_ID"]),  # hvdlint: disable=HVD003 -- launcher-worker protocol var
-        )
-    except RuntimeError as e:
-        if "already initialized" not in str(e):
-            raise
+    # contract); the env check above is therefore ordered first. CPU
+    # multi-process jobs ride jax's default cross-process collectives
+    # (gloo) unless the user picked another
+    # (JAX_CPU_COLLECTIVES_IMPLEMENTATION=mpi).
+    jax.distributed.initialize(
+        coordinator_address=coord,
+        num_processes=int(os.environ["HOROVOD_TPU_NUM_PROCESSES"]),  # hvdlint: disable=HVD003 -- launcher-worker protocol var
+        process_id=int(os.environ["HOROVOD_TPU_PROCESS_ID"]),  # hvdlint: disable=HVD003 -- launcher-worker protocol var
+    )
 
 
 def init(comm=None, num_ranks=None):
@@ -148,6 +150,7 @@ def init(comm=None, num_ranks=None):
                 "horovod_tpu has no MPI: init(comm=...) takes a list of "
                 "device positions (world ranks), e.g. comm=[0, 2, 5] — "
                 "not an MPI communicator object.")
+        _place_compile_cache()
         _maybe_init_distributed()
 
         cfg = config_mod.Config.from_env()

@@ -231,15 +231,16 @@ class ServeEngine:
                                   num_pages, page_size,
                                   max_pages_per_seq, cfg.dtype)
         shape = (cfg.n_layers, num_pages, page_size, h_kv, cfg.head_dim)
-        self._k_pool = jnp.zeros(shape, cfg.dtype)
-        self._v_pool = jnp.zeros(shape, cfg.dtype)
+        pool_sh = None
         if mesh is not None:
             if tp_axis is None:
                 raise ValueError("mesh serving needs tp_axis")
             pool_sh = NamedSharding(mesh, P(None, None, None, tp_axis,
                                             None))
-            self._k_pool = jax.device_put(self._k_pool, pool_sh)
-            self._v_pool = jax.device_put(self._v_pool, pool_sh)
+        # born sharded: the whole pool never sits on the default device
+        self._k_pool = jnp.zeros(shape, cfg.dtype, device=pool_sh)
+        self._v_pool = jnp.zeros(shape, cfg.dtype, device=pool_sh)
+        if mesh is not None:
             axes = tfm.ShardAxes(dp=None, sp=None, tp=tp_axis, ep=None)
             params = jax.device_put(params, jax.tree.map(
                 lambda s: NamedSharding(mesh, s),

@@ -108,13 +108,11 @@ class NativeTimeline:
 
     def counter(self, name, value):
         """Chrome "C" counter sample (metrics.py splices registry values in
-        here so metrics and trace share one file). Older native libraries
-        without the symbol degrade to a no-op."""
+        here so metrics and trace share one file)."""
         if not self.enabled:
             return
-        fn = getattr(self._lib, "hvd_timeline_counter", None)
-        if fn is not None:
-            fn(self._h, name.encode(), self._ts(), float(value))
+        self._lib.hvd_timeline_counter(self._h, name.encode(), self._ts(),
+                                       float(value))
 
     def close(self):
         if self.enabled:

@@ -1,18 +1,10 @@
 """Minimal TCP key-value service with the jax.distributed client surface.
 
-Why this exists: jaxlib generations up to 0.4.37 ship a coordination-
-service client whose ``GetKeyValue`` cancellation path races value
-arrival — a blocking get whose deadline expires around a concurrent
-insert of the same key segfaults inside the client (and those clients
-also lack ``key_value_try_get_bytes`` entirely). Every timeout-polling
-protocol — which the multi-host coordinator is — trips it within
-seconds. On such clients, :func:`horovod_tpu.utils.compat.safe_kv_client`
-transparently swaps the control plane onto this service: process 0 hosts
-one process-lifetime server thread, publishes its address through the
-raw client using the two provably-safe primitives (a write-once set and
-a long-deadline wakeup get), and every process talks to it through
-:class:`KVClient`, which implements the exact four-method surface the
-coordinator uses:
+Why this exists: the simulated-rank harness (controlplane/simrank.py)
+drives hundreds of real coordinators with no jax runtime at all, so it
+needs a store that is not the jax.distributed coordination service.
+:class:`KVClient` implements the exact four-method surface the
+coordinator uses of that service's client:
 
 - ``key_value_set_bytes(key, value, allow_overwrite=...)``
 - ``blocking_key_value_get_bytes(key, timeout_ms)`` (raises a
@@ -20,7 +12,8 @@ coordinator uses:
 - ``key_value_try_get_bytes(key)`` (None when missing)
 - ``key_value_delete(key)``
 
-Newer jaxlib never loads this path. Trust model matches the coordination
+A real job never loads this path (its coordinator holds the
+jax.distributed client). Trust model matches the coordination
 service itself (unauthenticated, job-internal network); the server binds
 loopback unless told otherwise.
 

@@ -290,7 +290,7 @@ def test_autotune_largest_message_guard(tmp_path):
     incumbent = pm._best
     assert incumbent[0] > 0
     # sample 2: vastly higher overall score, but large-message goodput
-    # collapsed (the BENCH_r05 batch-512 signature)
+    # collapsed (the batch-512 sweep signature)
     pm.record_wire(1 << 20, 1.0)
     pm.record_bytes(1 << 40)
     assert pm._best == incumbent  # guard held the incumbent

@@ -10,6 +10,14 @@ from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import dense_attention
 
 
+def _dense_with_lse(q, k, v, causal):
+    """Unfused attention that also returns the per-row log-sum-exp —
+    the numerics reference for flash_attention_with_lse."""
+    from horovod_tpu.parallel.ring_attention import _tile_fwd_math
+    return _tile_fwd_math(q, k, v, 0, causal, None,
+                          1.0 / (q.shape[3] ** 0.5))
+
+
 @pytest.mark.parametrize("causal", [True, False])
 @pytest.mark.parametrize("shape", [(1, 128, 2, 32), (2, 256, 4, 64)])
 def test_flash_matches_dense(hvd_init, causal, shape):
@@ -71,7 +79,8 @@ def test_transformer_flash_matches_dense(hvd_init):
 
 @pytest.mark.parametrize("causal", [True, False])
 def test_flash_backward_kernels_multiblock(hvd_init, causal):
-    """Fused backward across several q/k blocks (block=64, s=256)."""
+    """Fused backward across several q/k blocks (block=128, s=256: a
+    2x2 grid — a block under 128 is not a kernel tile)."""
     shape = (2, 256, 2, 32)
     key = jax.random.PRNGKey(3)
     q, k, v = (jax.random.normal(kk, shape, jnp.float32)
@@ -79,7 +88,7 @@ def test_flash_backward_kernels_multiblock(hvd_init, causal):
     cot = jax.random.normal(jax.random.PRNGKey(4), shape, jnp.float32)
 
     _, vjp_flash = jax.vjp(
-        lambda *xs: flash_attention(*xs, causal, 64, True), q, k, v)
+        lambda *xs: flash_attention(*xs, causal, 128, True), q, k, v)
     _, vjp_dense = jax.vjp(
         lambda *xs: dense_attention(*xs, causal=causal), q, k, v)
     for a, b in zip(vjp_flash(cot), vjp_dense(cot)):
@@ -235,8 +244,7 @@ def test_ring_gqa_dense_matches_and_flash_guards(hvd_init):
 def test_flash_with_lse_gqa(hvd_init):
     """flash_attention_with_lse handles grouped-query K/V (the gate was
     lifted for ring x flash GQA) — out AND lse match the dense math."""
-    from horovod_tpu.ops.flash_attention import (_dense_with_lse,
-                                                 flash_attention_with_lse)
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
     B, S, H, G, D = 1, 128, 4, 2, 16
     key = jax.random.PRNGKey(17)
     kq, kk, kv = jax.random.split(key, 3)
@@ -370,8 +378,7 @@ def test_flash_with_lse_ragged_causal(hvd_init):
     """flash_attention_with_lse at a ragged causal length takes the
     padded kernel path in BOTH directions (the backward previously
     re-ran the O(S^2) dense vjp)."""
-    from horovod_tpu.ops.flash_attention import (_dense_with_lse,
-                                                 flash_attention_with_lse)
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
 
     B, S, H, D = 1, 200, 2, 16
     key = jax.random.PRNGKey(23)
@@ -422,3 +429,19 @@ def test_band_bwd_rejects_nonfinite_lse():
         out = _tile_bwd_dispatch(q, k, v, g, bad_lse, delta, off,
                                  True, None, 8, True)
         jax.block_until_ready(out)
+
+
+def test_flash_noncausal_ragged_raises_not_dense(hvd_init):
+    """A non-causal length that exceeds one block and tiles into no
+    128-multiple has no kernel path (padded keys cannot be hidden
+    without a mask). It must raise — in the plain, the lse and the band
+    entry points — instead of quietly running unfused attention."""
+    from horovod_tpu.ops.flash_attention import (_band_tile_fwd,
+                                                 flash_attention_with_lse)
+    q = jnp.ones((1, 200, 2, 16), jnp.float32)
+    with pytest.raises(ValueError, match="no 128-multiple block"):
+        flash_attention(q, q, q, False, 128, True)
+    with pytest.raises(ValueError, match="no 128-multiple block"):
+        flash_attention_with_lse(q, q, q, False, 128, True)
+    with pytest.raises(ValueError, match="no 128-multiple block"):
+        _band_tile_fwd(q, q, q, jnp.int32(200), None, 128, True)

@@ -230,3 +230,31 @@ def test_python_dash_m_entry():
                          timeout=120)
     assert out.returncode == 0
     assert out.stdout.strip()
+
+
+def test_tpu_slots_one_chip_per_process(monkeypatch):
+    """A chip belongs to one process: on a TPU host local slots each get a
+    chip of their own, or the launch is refused — never N workers left to
+    race for the same chips. (The chip count is faked; the pinned grid
+    itself ran on a 4-chip v5e host — docs/launcher.md.)"""
+    from horovod_tpu.run import run as launcher
+    local = [("localhost", 4)]
+    monkeypatch.setattr(launcher, "_local_tpu_chips", lambda: 4)
+    slots = launcher._tpu_slot_envs({}, local, 4)
+    assert [s["TPU_VISIBLE_CHIPS"] for s in slots] == ["0", "1", "2", "3"]
+    assert len({s["TPU_PROCESS_PORT"] for s in slots}) == 4
+    assert len({s["TPU_PROCESS_ADDRESSES"] for s in slots}) == 1
+    assert all(s["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+               and s["TPU_PROCESS_BOUNDS"] == "2,2,1" for s in slots)
+    env = launcher._rank_env({}, "localhost:1", 4, 2, 2, 4, 0, 1, slots)
+    assert env["TPU_VISIBLE_CHIPS"] == "2"
+    # one process drives every chip itself; a CPU job opens none
+    assert launcher._tpu_slot_envs({}, [("localhost", 1)], 1) is None
+    assert launcher._tpu_slot_envs({"JAX_PLATFORMS": "cpu"}, local, 4) is None
+    # any other local shape would race: refused, naming the way out
+    with pytest.raises(ValueError, match="Run ONE process"):
+        launcher._tpu_slot_envs({}, [("localhost", 2)], 2)
+    with pytest.raises(ValueError, match="--elastic"):
+        launch(4, ["true"], elastic=True, env={})
+    monkeypatch.setattr(launcher, "_local_tpu_chips", lambda: 0)
+    assert launcher._tpu_slot_envs({}, local, 4) is None

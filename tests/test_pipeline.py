@@ -10,8 +10,6 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import backend_caps
-
 from horovod_tpu.models import transformer as tfm
 from horovod_tpu.parallel import create_mesh
 from horovod_tpu.parallel.pipeline import (pipeline, last_stage_value,
@@ -366,8 +364,6 @@ def test_1f1b_memory_flat_in_microbatches(eight_devices):
     assert t16 <= t4 * 1.1, (t4, t16)         # 1F1B memory does not
 
 
-@pytest.mark.skipif(not backend_caps.supports_pipeline_moe_grad(),
-                    reason="backend cannot differentiate the MoE pipeline under shard_map (_SpecError)")
 def test_pipeline_moe_homogeneous(eight_devices):
     """All-MoE layers compose with both pipeline schedules: the aux
     load-balancing loss rides the activation pytree through the pipe, so
@@ -528,8 +524,6 @@ def test_1f1b_interleaved_transformer(eight_devices):
 
 # ------------------------------------------------- round 5: mixed MoE x PP
 
-@pytest.mark.skipif(not backend_caps.supports_pipeline_moe_grad(),
-                    reason="backend cannot differentiate the MoE pipeline under shard_map (_SpecError)")
 def test_pipeline_mixed_dense_moe(eight_devices):
     """Round-4 verdict #4: a pp=2 config with moe_layers={1,3} of 4
     (every-other-layer MoE, the real-world MoE transformer shape) trains

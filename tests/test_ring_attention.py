@@ -7,8 +7,6 @@ import numpy as np
 import pytest
 from jax.sharding import Mesh, PartitionSpec as P
 
-import backend_caps
-
 from horovod_tpu.parallel.ring_attention import dense_attention, ring_attention
 
 
@@ -19,9 +17,6 @@ def _mesh(n):
 @pytest.mark.parametrize("sp", [2, 4, 8])
 @pytest.mark.parametrize("causal", [True, False])
 def test_ring_matches_dense(hvd_init, sp, causal):
-    if not causal and not backend_caps.supports_ring_noncausal():
-        pytest.skip("backend cannot partition the non-causal ring "
-                    "custom_vjp (PartitionId unsupported)")
     B, S, H, D = 2, 32, 4, 16
     key = jax.random.PRNGKey(0)
     q, k, v = (jax.random.normal(kk, (B, S, H, D), jnp.float32)

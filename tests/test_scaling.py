@@ -36,7 +36,6 @@ def test_weak_scaling_isolated_floor():
     transient failure retries — a REAL regression fails all three."""
     env = dict(os.environ)
     env.update({
-        "HOROVOD_SCALING_DEVICES": "4",
         "HOROVOD_SCALING_REPEATS": "3",
         "HOROVOD_SCALING_HIDDEN": "64",
         "HOROVOD_SCALING_DEPTH": "2",
@@ -67,7 +66,8 @@ def test_weak_scaling_isolated_floor():
         raising, so every mode gets the full 3 attempts."""
         try:
             out = subprocess.run(
-                [sys.executable, os.path.join(REPO, "bench_scaling.py")],
+                [sys.executable, os.path.join(REPO, "bench_scaling.py"),
+                 "--cpu-devices", "4"],
                 capture_output=True, text=True, timeout=600, cwd=REPO,
                 env=env)
         except subprocess.TimeoutExpired:
@@ -104,10 +104,10 @@ def test_weak_scaling_isolated_floor():
 @pytest.mark.slow
 def test_bench_scaling_emits_metric_line(tmp_path):
     env = dict(os.environ)
-    env["HOROVOD_SCALING_DEVICES"] = "2"
     # JAX_PLATFORMS inherited — see test_weak_scaling_isolated_floor.
     out = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench_scaling.py")],
+        [sys.executable, os.path.join(REPO, "bench_scaling.py"),
+         "--cpu-devices", "2"],
         capture_output=True, text=True, timeout=300, cwd=REPO, env=env)
     assert out.returncode == 0, out.stderr[-2000:]
     line = out.stdout.strip().splitlines()[-1]
@@ -116,3 +116,4 @@ def test_bench_scaling_emits_metric_line(tmp_path):
     assert payload["unit"] == "%"
     assert payload["value"] > 0
     assert "per_n" in payload and "1" in payload["per_n"]
+    assert payload["platform"] == "cpu" and payload["device_count"] >= 2

@@ -15,10 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-import pytest
 from jax.sharding import PartitionSpec as P
-
-import backend_caps
 
 import horovod_tpu as hvd
 from horovod_tpu import ops
@@ -110,8 +107,6 @@ def test_shutdown_dump_has_nonzero_jit_counters(tmp_path):
         hvd.init()
 
 
-@pytest.mark.skipif(not backend_caps.supports_axis_gated_callbacks(),
-                    reason="backend cannot partition axis-gated debug callbacks (PartitionId unsupported)")
 def test_jit_callbacks_mode_counts_executions(hvd_init):
     """HOROVOD_PROFILER_JIT_CALLBACKS=1 counts every execution, not just the
     trace."""
