@@ -12,7 +12,11 @@ static const char* kOps[] = {
     "allreduce", "allreduce_cached", "allreduce_jit", "allgather",
     "allgather_jit", "broadcast", "broadcast_jit", "alltoall",
     "alltoall_jit", "reducescatter", "reducescatter_jit", "gather",
-    "gatherv"};
+    "gatherv",
+    // what a device-trace capture found inside the compiled step
+    // (stats.py XLA_OPS)
+    "allreduce_xla", "allgather_xla", "reducescatter_xla", "alltoall_xla",
+    "collectivepermute_xla"};
 
 void CollectiveStats::Record(const std::string& op, int64_t nbytes,
                              int64_t time_us) {

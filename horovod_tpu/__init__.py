@@ -31,9 +31,13 @@ horovod/tensorflow/__init__.py, horovod/common/basics.py):
   upstream analog is Petastorm + tf.data prefetch — docs/data.md).
 """
 
-import numpy as np
+import time as _time
 
-from .version import __version__  # noqa: F401
+_T_IMPORT = _time.perf_counter()  # the `import` span starts here
+
+import numpy as np  # noqa: E402
+
+from .version import __version__  # noqa: F401,E402
 from . import ops  # noqa: F401
 from .exceptions import (HorovodError, NotInitializedError, ShutDownError,  # noqa: F401
                          DuplicateNameError, MismatchError,
@@ -47,6 +51,7 @@ from .runtime import (init, shutdown, is_initialized, rank, size,  # noqa: F401
                       expert_parallel_size, model_mesh,
                       model_parallel_size, state)
 from .ops import engine as _engine_mod
+from . import diag  # noqa: F401  (hvd.diag.spans(), hvd.diag.span)
 from . import metrics as _metrics_mod
 
 
@@ -171,15 +176,27 @@ def broadcast_parameters(params, root_rank=0):
     """
     import jax
     leaves, treedef = jax.tree.flatten(params)
-    # Async-submit every leaf, then synchronize: one engine cycle fuses the
-    # whole pytree into a few large batches instead of paying a blocking
-    # round-trip per tensor (the reference does the same —
-    # broadcast_async_ then synchronize, torch/__init__.py:211-241).
-    handles = [broadcast_async(np.asarray(leaf), root_rank,
-                               name=f"broadcast_parameters.{i}")
-               for i, leaf in enumerate(leaves)]
-    out = [_first(synchronize(h)) for h in handles]
+    _, out = _broadcast_leaves(leaves, root_rank, "broadcast_parameters")
     return jax.tree.unflatten(treedef, out)
+
+
+def _broadcast_leaves(leaves, root_rank, prefix):
+    """Pull every leaf to the host, async-submit them all, then
+    synchronize: one engine cycle fuses the whole pytree into a few large
+    batches instead of paying a blocking round-trip per tensor (the
+    reference does the same — broadcast_async_ then synchronize,
+    torch/__init__.py:211-241). Span ``bcast`` with its two parts,
+    ``bcast.host_pull`` and ``bcast.engine`` (docs/diagnostics.md).
+    Returns ``(host arrays, results)``."""
+    with diag.span("bcast", leaves=len(leaves)) as sp:
+        with diag.span("bcast.host_pull"):
+            arrs = [np.asarray(leaf) for leaf in leaves]
+        sp.set(bytes=int(sum(a.nbytes for a in arrs)))
+        with diag.span("bcast.engine"):
+            handles = [broadcast_async(arr, root_rank, name=f"{prefix}.{i}")
+                       for i, arr in enumerate(arrs)]
+            res = [_first(synchronize(h)) for h in handles]
+    return arrs, res
 
 
 def broadcast_optimizer_state(opt_state, root_rank=0):
@@ -190,15 +207,10 @@ def broadcast_optimizer_state(opt_state, root_rank=0):
     """
     import jax
     leaves, treedef = jax.tree.flatten(opt_state)
-    arrs = [np.asarray(leaf) for leaf in leaves]
-    handles = [broadcast_async(arr, root_rank,
-                               name=f"broadcast_optimizer_state.{i}")
-               for i, arr in enumerate(arrs)]
-    out = []
-    for leaf, arr, h in zip(leaves, arrs, handles):
-        res = _first(synchronize(h))
-        out.append(res.item() if arr.ndim == 0 and not hasattr(leaf, "shape")
-                   else res)
+    arrs, res = _broadcast_leaves(leaves, root_rank,
+                                  "broadcast_optimizer_state")
+    out = [r.item() if a.ndim == 0 and not hasattr(leaf, "shape") else r
+           for leaf, a, r in zip(leaves, arrs, res)]
     return jax.tree.unflatten(treedef, out)
 
 
@@ -227,3 +239,5 @@ from . import data  # noqa: F401,E402
 # Inference serving (paged KV cache, continuous batching, SLO-driven
 # elasticity): hvd.serve.Engine(model, params) — see docs/serving.md.
 from . import serve  # noqa: F401,E402
+
+diag.record_span("import", _T_IMPORT, _time.perf_counter())

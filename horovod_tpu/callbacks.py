@@ -163,7 +163,10 @@ class TelemetryCallback(Callback):
     (docs/performance.md "Compiled hot loop") — so the supervisor can
     see a resize's recompile cost land and drain; the
     ``hvd_step_program_*`` gauges themselves are kept fresh by the step
-    object on every call."""
+    object on every call. A compiled step returns at its enqueue, so
+    with ``compiled_step=`` a step's time (``hvd_step_seconds``,
+    ``hvd_examples_per_sec``, ``hvd_step_mfu``, the sentry's feed) is
+    the interval between successive step ENDS, not begin to end."""
 
     def __init__(self, batch_size=None, skew_interval=50, dataset=None,
                  policy_dir=None, signal_interval=0.5, compiled_step=None):
@@ -177,6 +180,7 @@ class TelemetryCallback(Callback):
         self.policy_dir = policy_dir
         self.signal_interval = signal_interval
         self._t0 = None
+        self._t_end = None   # previous step's end (compiled_step= only)
         self._steps = 0
         self._last_skew = None
         self._last_stall = None
@@ -185,14 +189,32 @@ class TelemetryCallback(Callback):
         self._last_mfu = None
         self._peak_flops = None  # lazy: resolved on first step
 
+    def on_train_begin(self, logs=None):
+        self._t_end = None   # a pause before training is not a step
+
+    def on_epoch_begin(self, epoch, logs=None):
+        self._t_end = None   # nor is what ran between two epochs
+
     def on_batch_begin(self, batch, logs=None):
         self._t0 = time.perf_counter()
 
     def on_batch_end(self, batch, logs=None):
         if self._t0 is None:
             return
-        dt = time.perf_counter() - self._t0
+        now = time.perf_counter()
+        dt = now - self._t0
         self._t0 = None
+        # A compiled step returns when it is ENQUEUED: begin -> end times
+        # the dispatch, not the step. The interval between successive
+        # step ends is the whole loop iteration, and in steady state —
+        # the device queue full, the enqueue blocking on it — the device
+        # step. The first step of a run has no previous end and keeps
+        # begin -> end (it compiles, so it blocks).
+        whole_iteration = (self.compiled_step is not None
+                           and self._t_end is not None)
+        if whole_iteration:
+            dt = now - self._t_end
+        self._t_end = now
         self._steps += 1
         metrics.STEPS_TOTAL.inc()
         metrics.STEP_SECONDS.observe(dt)
@@ -214,8 +236,10 @@ class TelemetryCallback(Callback):
             # the full step wall time is wait + dt and the stall share
             # is wait / (wait + dt) — not wait / dt, which saturates at
             # 1.0 the moment waiting matches compute.
+            # (Between step ends the wait is already inside dt.)
             wait = self.dataset.take_wait()
-            stall = wait / (wait + dt) if wait + dt > 0 else 0.0
+            total = dt if whole_iteration else wait + dt
+            stall = min(wait / total, 1.0) if total > 0 else 0.0
             metrics.DATA_STALL_RATIO.set(stall)
             self._last_stall = stall
         if (self.skew_interval and self._steps % self.skew_interval == 0

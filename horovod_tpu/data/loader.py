@@ -289,12 +289,13 @@ class DistributedDataset:
         if depth > 0 and steps_left > 0:
             self._start_producer(depth)
         for _ in range(steps_left):
-            t0 = time.perf_counter()
-            if depth > 0 and self._producer is not None:
-                batch = self._get_prefetched()
-            else:
-                batch = self._produce(self._plan, self._step)
-            wait = time.perf_counter() - t0
+            with diag.span("data.wait", batch=self._step) as sp:
+                if depth > 0 and self._producer is not None:
+                    sp.set(depth=self._producer[1].qsize())
+                    batch = self._get_prefetched()
+                else:
+                    batch = self._produce(self._plan, self._step)
+                wait = time.perf_counter() - sp.t0
             self._record_wait(wait, tuner)
             self._step += 1
             self._state.segments[-1][1] = self._step
@@ -317,22 +318,24 @@ class DistributedDataset:
 
     def _produce(self, plan, step):
         idx = plan[step * self.batch_size:(step + 1) * self.batch_size]
-        batch = self._fetch(idx)
-        if self._transform is not None:
-            batch = self._transform(batch)
+        with diag.span("data.fetch", batch=step):
+            batch = self._fetch(idx)
+            if self._transform is not None:
+                batch = self._transform(batch)
         if self._sharding is not None:
             import jax
             sh = self._sharding
-            if getattr(sh, "is_fully_addressable", True):
-                batch = jax.device_put(batch, sh)
-            else:
-                # Multi-process sharding: each process holds only ITS
-                # shard of the global batch, so the global array is
-                # assembled from per-process local data (device_put
-                # would expect the full global value).
-                batch = jax.tree.map(
-                    lambda a: jax.make_array_from_process_local_data(
-                        sh, np.asarray(a)), batch)
+            with diag.span("data.put", batch=step):
+                if getattr(sh, "is_fully_addressable", True):
+                    batch = jax.device_put(batch, sh)
+                else:
+                    # Multi-process sharding: each process holds only ITS
+                    # shard of the global batch, so the global array is
+                    # assembled from per-process local data (device_put
+                    # would expect the full global value).
+                    batch = jax.tree.map(
+                        lambda a: jax.make_array_from_process_local_data(
+                            sh, np.asarray(a)), batch)
         return batch
 
     def _record_wait(self, wait, tuner):

@@ -11,6 +11,10 @@ that gap:
   abort) plus input-wait and step marks. Off the steady-state critical
   path by construction: one GIL-atomic counter increment and one tuple
   store per event, no locks anywhere.
+- ``recorder.span`` / ``spans()``: host spans inside the program
+  (``import``, ``init``, ``bcast.*``, ``step.*``, ``jax.*``, ``data.*``)
+  in the same ring, each also a ``TraceAnnotation("hvd_<name>")`` on the
+  profiler's clock.
 - ``recorder.HangWatchdog``: created only when
   ``HOROVOD_STALL_TIMEOUT_SECONDS > 0`` — dumps a durable post-mortem
   (``flight-rank<N>.json`` + all-thread stacks) for any collective
@@ -34,10 +38,12 @@ that gap:
 """
 
 from .recorder import (FlightRecorder, HangWatchdog, dump_post_mortem, get,
-                       install, start_watchdog, uninstall)
+                       install, record_span, span, spans, start_watchdog,
+                       uninstall)
 from .sentry import PerfSentry
 from .xla_trace import StepTracer, parse_trace_dir, trace_steps
 
 __all__ = ["FlightRecorder", "HangWatchdog", "get", "install", "uninstall",
+           "span", "spans", "record_span",
            "start_watchdog", "dump_post_mortem", "PerfSentry", "StepTracer",
            "parse_trace_dir", "trace_steps"]

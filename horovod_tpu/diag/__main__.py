@@ -17,11 +17,14 @@ produces:
 With ``--xla-trace DIR`` (an ``xla-trace-<seq>/`` capture directory from
 ``hvd.trace_steps`` / ``HOROVOD_XPROF_STEPS``), the merge also splices
 the XLA *device* trace into the same timeline — each device event
-phase-labeled via the capture's ``xla-trace-meta.json`` sidecar and
-clock-aligned through the sidecar's wall-clock window — and the report
-gains a per-phase device-time breakdown (forward / backward / exchange /
-optimizer / guard / other), the device-level critical path next to the
-host-side flight view.
+phase-labeled via the capture's ``xla-trace-meta.json`` sidecar and laid
+on the flight view's clock through the sidecar's clock mapping (the
+program's spans are in the ring and in the capture; the median
+difference is the offset) — and the report gains a per-phase device-time
+breakdown (forward / backward / exchange / optimizer / guard / other),
+the named kernels, the collectives by message size and the clock
+mapping's quality: the device-level critical path next to the host-side
+flight view.
 
 Usage::
 
@@ -67,8 +70,9 @@ def load_dumps(paths):
 def _chrome_events(dump):
     """One rank's dump as Chrome events with ts/dur in WALL microseconds
     (merge_remote then shifts them against the global epoch). Spans
-    (wire, readback, input-wait, step) become "X" complete events ending
-    at their recorded wall time; lifecycle points become "i" instants."""
+    (wire, readback, input-wait, step, the program's own ``diag.span``s)
+    become "X" complete events ending at their recorded wall time;
+    lifecycle points become "i" instants."""
     out = []
     rank = dump.get("rank", 0)
     for tid, label in ((0, "wire"), (1, "readback"), (2, "input"),
@@ -77,6 +81,7 @@ def _chrome_events(dump):
                     "args": {"name": label}})
     out.append({"name": "process_name", "ph": "M", "pid": 0,
                 "args": {"name": f"rank{rank} flight"}})
+    span_tids = {}
     for ev in dump.get("events", ()):
         try:
             wall_us = int(float(ev["wall"]) * 1e6)
@@ -101,6 +106,14 @@ def _chrome_events(dump):
             out.append({"name": "INPUT_WAIT", "cat": "input", "ph": "X",
                         "pid": 0, "tid": 2, "ts": wall_us - wait_us,
                         "dur": wait_us})
+        elif kind == "span" and "t0" in ev:
+            # a program span (diag.span): stored at its end, one lane per
+            # thread after the fixed ones
+            dur_us = int((float(ev["t"]) - float(ev["t0"])) * 1e6)
+            tid = span_tids.setdefault(ev.get("tid"), 5 + len(span_tids))
+            out.append({"name": name, "cat": "span", "ph": "X", "pid": 0,
+                        "tid": tid, "ts": wall_us - dur_us, "dur": dur_us,
+                        "args": args})
         elif kind == "step":
             dt_us = int(float(ev.get("dt", 0)) * 1e6)
             out.append({"name": f"STEP {ev.get('step', '?')}",
@@ -110,72 +123,53 @@ def _chrome_events(dump):
             out.append({"name": f"{kind}:{name}" if name != kind else kind,
                         "cat": "lifecycle", "ph": "i", "s": "t", "pid": 0,
                         "tid": 4, "ts": wall_us, "args": args})
+    for tid in span_tids.values():
+        out.append({"name": "thread_name", "ph": "M", "pid": 0, "tid": tid,
+                    "args": {"name": f"spans {tid - 5}"}})
     return out
 
 
 def load_xla_trace(trace_dir):
     """Device-trace view for ``--xla-trace``: per-phase totals (from the
-    ``xla-trace-meta.json`` sidecar, re-parsing the raw capture when the
+    ``xla-trace-meta.json`` sidecar, re-reducing the raw capture when the
     sidecar is absent) plus phase-labeled Chrome events on wall-clock
     microseconds, ready for the same merge_remote splicing as the flight
-    dumps. Returns None when the directory holds no device events; the
-    events list is empty when no sidecar pins the wall-clock window
-    (device timestamps alone cannot be aligned to the flight view)."""
-    from .xla_trace import (_SUFFIX_RE, _iter_trace_files,
-                            _load_trace_events, load_meta, parse_trace_dir)
+    dumps. Returns None when the directory holds no device events.
+
+    Clock: the sidecar's ``summary.clock.offset_ns`` maps the flight
+    ring's ``perf_counter`` onto the profiler's clock (the median over
+    every span that is in both, diag/xla_trace.py ``clock_map``), and its
+    ``mono_start`` / ``wall_start`` are one instant on ``perf_counter``
+    and on the wall clock the dumps are merged on. The events list is
+    empty when the capture has no such pair (no program span ran in it):
+    device timestamps alone cannot be aligned to the flight view."""
+    from .xla_trace import load_meta, read_capture, summarize
     meta = load_meta(trace_dir) or {}
-    summary = meta.get("summary") or parse_trace_dir(trace_dir)
+    events = read_capture(trace_dir)
+    summary = meta.get("summary") or summarize(events)
     if summary is None:
         print(f"warning: no parseable device events under {trace_dir}",
               file=sys.stderr)
         return None
     op_phases = meta.get("op_phases") or {}
-    cache = {}
-
-    def resolve(op):
-        if op not in cache:
-            hit = op_phases.get(op)
-            if hit is None:
-                base = _SUFFIX_RE.sub("", op)
-                cands = {tuple(v) for k, v in op_phases.items()
-                         if _SUFFIX_RE.sub("", k) == base}
-                hit = cands.pop() if len(cands) == 1 else None
-            cache[op] = hit
-        return cache[op]
-
+    offset = (summary.get("clock") or {}).get("offset_ns")
+    mono0, wall0 = meta.get("mono_start"), meta.get("wall_start")
     raw, lanes = [], {}
-    wall0 = meta.get("wall_start")
-    if isinstance(wall0, (int, float)) and wall0 > 0:
-        for path in _iter_trace_files(trace_dir):
-            for ev in _load_trace_events(path) or ():
-                if not isinstance(ev, dict) or ev.get("ph") != "X":
-                    continue
-                args = ev.get("args")
-                op = args.get("hlo_op") if isinstance(args, dict) else None
-                ts = ev.get("ts")
-                if not op or not isinstance(ts, (int, float)):
-                    continue
-                tid = lanes.setdefault((ev.get("pid"), ev.get("tid")),
-                                       len(lanes))
-                hit = resolve(str(op)) or (None, None)
-                phase = hit[0] or "other"
-                raw.append({"name": f"{phase}:{op}", "cat": phase,
+    if events and None not in (offset, mono0, wall0):
+        for tid, key in enumerate(sorted(events["lanes"])):
+            lanes[key] = tid
+            for instr, start_ns, dur_ns, _ in events["lanes"][key]["ops"]:
+                phase = (op_phases.get(instr) or [None])[0] or "other"
+                mono = (start_ns - offset) * 1e-9
+                raw.append({"name": f"{phase}:{instr}", "cat": phase,
                             "ph": "X", "pid": 0, "tid": tid,
-                            "ts": float(ts),
-                            "dur": float(ev.get("dur") or 0.0)})
-        # Clock alignment: the capture started (sidecar wall_start) at
-        # the step tick right before the first device event, so the
-        # earliest device timestamp maps onto wall_start and every event
-        # shifts by the same offset into wall microseconds.
-        ts_min = min((e["ts"] for e in raw), default=0.0)
-        shift = float(wall0) * 1e6 - ts_min
-        for e in raw:
-            e["ts"] += shift
+                            "ts": (mono - mono0 + wall0) * 1e6,
+                            "dur": dur_ns * 1e-3})
     evs = [{"name": "process_name", "ph": "M", "pid": 0,
             "args": {"name": "xla device trace"}}]
     evs += [{"name": "thread_name", "ph": "M", "pid": 0, "tid": t,
-             "args": {"name": f"device lane {t}"}}
-            for t in range(len(lanes))]
+             "args": {"name": f"device lane {key}"}}
+            for key, t in lanes.items()]
     return {"dir": trace_dir, "meta": meta, "summary": summary,
             "events": evs + raw, "aligned": bool(raw)}
 
@@ -306,6 +300,28 @@ def print_xla_report(xla):
         print("  staged exchange: " + "  ".join(
             f"{k}={round(v / steps / lanes * 1e3, 3)}ms"
             for k, v in stages.items()))
+    if s.get("kernels"):
+        print("  kernels ms/step/lane: " + "  ".join(
+            f"{k}={round(v['s'] / steps / lanes * 1e3, 3)}"
+            f"({round(v['calls'] / steps / lanes, 1)} calls)"
+            for k, v in sorted(s["kernels"].items())))
+    for row in s.get("collectives") or ():
+        calls = row["calls"] / steps / lanes
+        print(f"  collective {row['op']} {row['bytes']} B: "
+              f"{round(calls, 2)} calls/step/lane, "
+              f"{round(row['device_s'] / row['calls'] * 1e3, 3)} ms a "
+              f"call, {round(row['exposed_s'] / row['calls'] * 1e3, 3)} "
+              "ms of it with nothing else running on the chip")
+    idle = s.get("idle")
+    if idle and idle["by"]:
+        print("  idle ms/step/lane: " + "  ".join(
+            f"{k}={round(v / steps / lanes * 1e3, 4)}"
+            for k, v in sorted(idle["by"].items(), key=lambda kv: -kv[1])))
+    clock = s.get("clock")
+    if clock:
+        print(f"  clock: {clock['pairs']} span pairs, spread "
+              f"{round(clock['spread_ns'] * 1e-3, 1)} us, host-device "
+              f"skew <= {clock.get('host_device_skew_bound_us')} us")
 
 
 def main(argv=None):

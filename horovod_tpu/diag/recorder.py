@@ -22,6 +22,18 @@ Design constraints (ISSUE 8 / docs/diagnostics.md):
 Event tuples are ``(seq, t_mono, t_wall, event, name, op, nbytes, dtype,
 extra)`` — monotonic (``perf_counter``) for intra-rank spans, wall clock
 for cross-rank alignment in the ``python -m horovod_tpu.diag`` merger.
+
+**Spans.** ``span(name, **attrs)`` is the program's own measurement of
+where host time goes (docs/diagnostics.md "Host spans"): one ring entry
+per span — event ``"span"``, stored at its end, ``extra`` holding its
+start (``t0``, ``perf_counter``), thread, id, the id of the span that
+encloses it on that thread (``parent``, 0 at top level) and its
+attributes — and, for the same interval, a
+``jax.profiler.TraceAnnotation("hvd_" + name)``, so the span lies in the
+xplane of ANY profiler capture on the profiler's clock. TraceMe's own
+"is a session active" check is the only gate: with no capture a span
+costs about 2 us in all, 0.4 us of it the annotation. ``spans()`` reads
+them back as plain tuples.
 """
 
 import itertools
@@ -31,6 +43,9 @@ import sys
 import threading
 import time
 import traceback
+from time import perf_counter
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 from .. import metrics
 from ..utils.logging import get_logger
@@ -198,6 +213,111 @@ def _thread_stacks():
 
 _recorder = None
 _recorder_config = None
+# The recorder ``spans()`` reads once ``uninstall()`` dropped the live one:
+# a harness reads its run's spans after ``hvd.shutdown()``.
+_retired = None
+
+
+# ------------------------------------------------------------------- spans
+
+#: Spans taken while no recorder is installed (the package import, anything
+#: before ``hvd.init()``), as ring tuples; ``install()`` adopts them.
+_early = []
+_EARLY_CAP = 256
+_span_ids = itertools.count(1)
+_tls = threading.local()
+
+
+def _store_span(name, t0, t1, parent, sid, extra):
+    """One ring entry for a finished span; ``extra`` (the span's own
+    attribute dict) gains the bookkeeping keys."""
+    extra["t0"] = t0
+    extra["tid"] = threading.get_ident()
+    extra["id"] = sid
+    extra["parent"] = parent
+    rec = _recorder
+    if rec is not None:
+        i = next(rec._count)
+        rec._ring[i & rec._mask] = (i, t1, time.time(), "span", name, "",
+                                    0, "", extra)
+    elif len(_early) < _EARLY_CAP:
+        _early.append((-1, t1, time.time(), "span", name, "", 0, "",
+                       extra))
+
+
+class span:
+    """``with diag.span("step.execute", step=n):`` — one ring entry and
+    one ``TraceAnnotation("hvd_step.execute")`` for the enclosed
+    interval. ``step_trace=n`` adds a
+    ``StepTraceAnnotation("hvd_step", step_num=n)`` around the same
+    interval, so xprof and the trace reducers can group device events by
+    step. Attributes are small scalars; ``set(**attrs)`` adds some found
+    while the span is open. Per-thread nesting gives each span its
+    parent's id."""
+
+    __slots__ = ("name", "attrs", "id", "parent", "t0", "_note",
+                 "_step_note", "_stack")
+
+    def __init__(self, name, step_trace=None, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self._note = TraceAnnotation("hvd_" + name)
+        self._step_note = (None if step_trace is None else
+                           StepTraceAnnotation("hvd_step",
+                                               step_num=step_trace))
+
+    def set(self, **attrs):
+        self.attrs.update(attrs)
+
+    def __enter__(self):
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self._stack = stack
+        self.parent = stack[-1] if stack else 0
+        self.id = next(_span_ids)
+        stack.append(self.id)
+        if self._step_note is not None:
+            self._step_note.__enter__()
+        self._note.__enter__()
+        self.t0 = perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        t1 = perf_counter()
+        self._note.__exit__(*exc)
+        if self._step_note is not None:
+            self._step_note.__exit__(*exc)
+        self._stack.pop()
+        _store_span(self.name, self.t0, t1, self.parent, self.id,
+                    self.attrs)
+        return False
+
+
+def record_span(name, t0, t1, **attrs):
+    """A span whose interval was measured elsewhere (jax's own compile
+    durations), as a child of whichever span is open on this thread."""
+    stack = getattr(_tls, "stack", None)
+    _store_span(name, t0, t1, stack[-1] if stack else 0, next(_span_ids),
+                attrs)
+
+
+def spans():
+    """The spans the ring still holds, oldest first, as plain tuples
+    ``(name, start, end, thread, id, parent, attrs)`` — ``start`` / ``end``
+    on ``time.perf_counter``, ``parent`` the id of the enclosing span on
+    that thread (0: none), ``attrs`` a dict (``step``, ``hit``, ``bytes``
+    ...). Reads the live recorder, after ``hvd.shutdown()`` the one it
+    retired, before ``hvd.init()`` the spans taken so far."""
+    rec = _recorder or _retired
+    out = []
+    for e in (rec._ring if rec is not None else []) + _early:
+        if e is not None and e[3] == "span":
+            x = dict(e[8])
+            out.append((e[4], x.pop("t0"), e[1], x.pop("tid"), x.pop("id"),
+                        x.pop("parent"), x))
+    out.sort(key=lambda s: s[1])
+    return out
 
 
 def install(config, rank=0, process_index=0, digest=""):
@@ -209,10 +329,15 @@ def install(config, rank=0, process_index=0, digest=""):
         _recorder = None
         metrics.registry().remove_collect_hook("diag")
         return None
-    _recorder = FlightRecorder(capacity=config.flight_buffer, rank=rank,
-                               process_index=process_index, digest=digest,
-                               diag_dir=getattr(config, "diag_dir", ""))
-    rec = _recorder
+    rec = FlightRecorder(capacity=config.flight_buffer, rank=rank,
+                         process_index=process_index, digest=digest,
+                         diag_dir=getattr(config, "diag_dir", ""))
+    # adopt the spans taken before there was a ring (the import)
+    for e in _early:
+        i = next(rec._count)
+        rec._ring[i & rec._mask] = (i,) + e[1:]
+    del _early[:]
+    _recorder = rec
     metrics.registry().set_collect_hook(
         "diag", lambda: metrics.DIAG_EVENTS.set(rec.events_recorded))
     return _recorder
@@ -224,7 +349,8 @@ def get():
 
 
 def uninstall():
-    global _recorder, _recorder_config
+    global _recorder, _recorder_config, _retired
+    _retired = _recorder or _retired
     _recorder = None
     _recorder_config = None
     metrics.registry().remove_collect_hook("diag")

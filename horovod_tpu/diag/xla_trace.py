@@ -7,29 +7,46 @@ dispatch per step. This module opens that box without TensorBoard:
 - The step-program builders wrap each region in ``jax.named_scope``
   labels (``hvd_forward`` / ``hvd_backward`` / ``hvd_exchange`` /
   ``hvd_optimizer`` / ``hvd_guard``, plus ``hvd_ici`` / ``hvd_dcn``
-  inside the staged exchange). The scopes survive compilation as the
+  inside the staged exchange); the Pallas kernels carry names of their
+  own (``hvd_flash_fwd`` ...). The scopes survive compilation as the
   per-instruction ``op_name`` metadata in the optimized HLO.
 - ``hvd.trace_steps(n)`` (or ``HOROVOD_XPROF_STEPS=n``) arms a one-shot
   :class:`StepTracer`. The next ``n`` compiled steps are captured with
-  ``jax.profiler`` into ``xla-trace-<seq>/`` under ``HOROVOD_DIAG_DIR``.
-- The capture's device events carry an ``hlo_op`` arg naming the HLO
-  instruction that ran. :func:`parse_trace_dir` joins those names
-  against the traced executable's HLO text (``build_op_phase_map``) and
-  sums device microseconds per phase; instructions outside any ``hvd_``
-  scope land in ``other``. The parsed summary plus wall-clock window is
-  written next to the capture as ``xla-trace-meta.json`` so the
-  ``python -m horovod_tpu.diag --xla-trace`` merger can clock-align the
-  device view with the flight-recorder timeline offline.
+  ``jax.profiler`` (python tracer off) into ``xla-trace-<seq>/`` under
+  ``HOROVOD_DIAG_DIR``.
+- :func:`read_capture` reads the capture's ``*.xplane.pb`` with
+  ``jax.profiler.ProfileData`` into plain lists — one reader, two
+  extractors. On a TPU each chip is a plane ``/device:TPU:<n>`` whose
+  ``XLA Ops`` line has one event per instruction that ran, NAMED by the
+  instruction's HLO text (``%fusion.3 = f32[...] fusion(...)``; the
+  instruction name is parsed from it), whose ``XLA Modules`` line has one
+  event per program execution and whose ``Async XLA Ops`` line has the
+  start-to-done spans of asynchronous collectives. The CPU backend (what
+  the tests run on) puts its ops on ``/host:CPU`` thread lines, named by
+  instruction, with ``hlo_op`` / ``hlo_module`` / ``device_ordinal`` /
+  ``run_id`` stats. The program's own host spans (``hvd_*``
+  ``TraceAnnotation``s, diag/recorder.py) are on ``/host:CPU`` in both.
+- :func:`summarize` joins the instruction names against the HLO text of
+  the executable that RAN (:func:`live_hlo`, no further compile) and sums
+  device SELF time (an enclosing ``while`` does not count its body twice)
+  per phase; instructions outside any ``hvd_`` scope land in ``other``.
+  It also reports the named kernels, the collectives by opcode and
+  message size, the host spans in the window and the clock mapping.
+  The summary plus the clock mapping is written next to the capture as
+  ``xla-trace-meta.json`` so the ``python -m horovod_tpu.diag
+  --xla-trace`` merger can lay the device view on the flight-recorder
+  timeline offline.
 
 Inert by default: no tracer object exists until armed (mirroring the
 guard's disabled-state contract), and the per-step cost with a tracer
 installed but idle is one attribute check.
 """
 
-import gzip
+import glob
 import json
 import os
 import re
+import statistics
 import time
 
 from .. import metrics
@@ -49,28 +66,49 @@ PHASES = ("forward", "backward", "exchange", "optimizer", "guard",
           "dispatch", "expert", "combine", "prefill", "decode")
 #: Staged-exchange tiers annotated by ops/collectives.py.
 STAGES = ("ici", "dcn")
+#: Collective opcodes (``-start`` forms included by prefix) and the
+#: ``stats`` / profiler.txt label each is written under.
+COLLECTIVES = {"all-reduce": "allreduce_xla", "all-gather": "allgather_xla",
+               "reduce-scatter": "reducescatter_xla",
+               "all-to-all": "alltoall_xla",
+               "collective-permute": "collectivepermute_xla"}
 
 META_FILENAME = "xla-trace-meta.json"
 
-_PHASE_RE = re.compile(r"hvd_(forward|backward|exchange|optimizer|guard"
-                       r"|dispatch|expert|combine|prefill|decode)")
+_REGION_RE = re.compile(r"hvd_(forward|backward|exchange|optimizer|guard"
+                        r"|prefill|decode)")
+_MOE_RE = re.compile(r"hvd_(dispatch|expert|combine)")
 _STAGE_RE = re.compile(r"hvd_(ici|dcn)")
-# Optimized-HLO instruction metadata: `%name = ... metadata={...
-# op_name="jit(f)/jit(main)/hvd_forward/dot_general" ...}`. The op_name
-# carries the named_scope path; the instruction name is what trace
-# events reference via their `hlo_op` arg.
-_HLO_META_RE = re.compile(
-    r'%?([\w.\-]+)\s*=\s*[^\n]*metadata=\{[^}]*op_name="([^"]*)"')
-_SUFFIX_RE = re.compile(r"\.\d+$")
+_SCOPE_RE = re.compile(r"hvd_[a-z0-9_]+")
+# An HLO instruction, as a line of the optimized HLO text and as the name
+# of a TPU `XLA Ops` event: `%name = <result type> opcode(...), ...,
+# metadata={... op_name="jit(f)/hvd_forward/dot_general" ...}`. The
+# op_name carries the named_scope path.
+_NAME_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_OPCODE_RE = re.compile(r"[\]\})] ([a-z][a-z0-9\-]*)\(")
+_OP_NAME_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
+_ARRAY_RE = re.compile(r"\b([a-z]+[0-9]*[a-z0-9]*)\[([0-9,]*)\]")
+_MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
+_DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
+                "f64": 8, "c64": 8, "c128": 16}
 
 
 def phase_of_op_name(op_name):
     """Phase bucket for an HLO ``op_name`` scope path, or None when the
-    instruction sits outside every hvd_ scope. The LAST hvd_ label wins
-    so collectives nested inside ``hvd_optimizer`` (ZeRO modes exchange
-    inside the update transform) attribute to ``exchange``."""
-    hits = _PHASE_RE.findall(op_name or "")
-    return hits[-1] if hits else None
+    instruction sits outside every hvd_ scope. The FIRST step-region
+    label wins: the backward of a custom-vjp kernel is named
+    ``hvd_backward/transpose(hvd_forward)/...`` and remat's recomputed
+    forward ``hvd_backward/.../rematted_computation/...`` — both are
+    backward time. The MoE sub-phases are the exception: they are leaves
+    nested inside forward/backward whose buckets are pure wire or pure
+    compute, so the innermost of them wins over the region."""
+    op_name = op_name or ""
+    moe = _MOE_RE.findall(op_name)
+    if moe:
+        return moe[-1]
+    m = _REGION_RE.search(op_name)
+    return m.group(1) if m else None
 
 
 def stage_of_op_name(op_name):
@@ -79,57 +117,180 @@ def stage_of_op_name(op_name):
     return hits[-1] if hits else None
 
 
-def build_op_phase_map(hlo_text):
-    """``{hlo_instruction_name: op_name}`` from optimized-HLO text
-    (``jitted.lower(...).compile().as_text()``). Only instructions whose
-    metadata carries an op_name appear; the trace join tolerates misses
-    (they fall into ``other``)."""
-    return {name: op for name, op in _HLO_META_RE.findall(hlo_text or "")}
+def kernel_of_op_name(op_name):
+    """The name a custom call runs under: the innermost ``hvd_*`` scope
+    that is not a phase or stage label (``hvd_flash_dq``), else None."""
+    for label in reversed(_SCOPE_RE.findall(op_name or "")):
+        if not (_REGION_RE.match(label) or _MOE_RE.match(label)
+                or _STAGE_RE.match(label)):
+            return label
+    return None
 
 
-def _iter_trace_files(trace_dir):
-    for dirpath, _, filenames in os.walk(trace_dir):
-        for fn in sorted(filenames):
-            if fn.endswith(".trace.json.gz") or fn.endswith(".trace.json"):
-                yield os.path.join(dirpath, fn)
-
-
-def _load_trace_events(path):
-    """The ``traceEvents`` list from one capture file, or None when the
-    file is unreadable/malformed — the caller skips it (satellite
-    contract: bad trace files degrade to "no data", never a crash)."""
-    try:
-        if path.endswith(".gz"):
-            with gzip.open(path, "rt", encoding="utf-8", errors="replace") as f:
-                doc = json.load(f)
-        else:
-            with open(path, encoding="utf-8", errors="replace") as f:
-                doc = json.load(f)
-    except Exception:  # noqa: BLE001 - malformed capture, skip
-        _logger.warning("xla_trace: skipping unreadable trace file %s", path)
+def parse_instruction(text):
+    """``(name, opcode, result type)`` of one HLO instruction's text (a
+    line of HLO, or the name of a TPU ``XLA Ops`` event), ``None`` when
+    the text is not an instruction."""
+    m = _NAME_RE.match(text)
+    if not m:
         return None
-    events = doc.get("traceEvents") if isinstance(doc, dict) else None
-    return events if isinstance(events, list) else None
+    op = _OPCODE_RE.search(text, m.end() - 1)
+    if not op:
+        return None
+    return m.group(1), op.group(1), text[m.end():op.start() + 1]
 
 
-def _resolve_phase(op, op_map, cache):
-    """Join one trace ``hlo_op`` name against the registered HLO map:
-    exact instruction-name match first, then a numeric-suffix-stripped
-    match accepted only when unambiguous (separate compilations number
-    instructions differently)."""
-    if op in cache:
-        return cache[op]
-    op_name = op_map.get(op)
-    if op_name is None:
-        base = _SUFFIX_RE.sub("", op)
-        candidates = {v for k, v in op_map.items()
-                      if _SUFFIX_RE.sub("", k) == base}
-        op_name = candidates.pop() if len(candidates) == 1 else None
-    phase = phase_of_op_name(op_name) if op_name else None
-    stage = stage_of_op_name(op_name) if op_name else None
-    cache[op] = (phase, stage)
-    return phase, stage
+def shape_bytes(shape, largest=False):
+    """Bytes of a result type (``f32[8,128]{1,0}``; a tuple sums its
+    arrays, or with ``largest`` takes the biggest — the output of an
+    asynchronous start whose tuple also carries its operand)."""
+    sizes = []
+    for dtype, dims in _ARRAY_RE.findall(re.sub(r"\{[^}]*\}", "", shape)):
+        width = _DTYPE_BYTES.get(dtype, 1 if dtype.startswith("f8") else 0)
+        n = 1
+        for d in dims.split(","):
+            n *= int(d) if d else 1
+        sizes.append(n * width)
+    if not sizes:
+        return 0
+    return max(sizes) if largest else sum(sizes)
 
+
+def build_op_table(hlo_text):
+    """``{instruction name: (opcode, result type, op_name)}`` from
+    optimized-HLO text; ``op_name`` is "" where the compiler created the
+    instruction without metadata."""
+    table = {}
+    for line in (hlo_text or "").splitlines():
+        inst = parse_instruction(line)
+        if inst:
+            m = _OP_NAME_RE.search(line)
+            table[inst[0]] = (inst[1], inst[2], m.group(1) if m else "")
+    return table
+
+
+def build_op_phase_map(hlo_text):
+    """``{hlo_instruction_name: op_name}`` for the instructions whose
+    metadata carries an op_name; the trace join tolerates misses (they
+    fall into ``other``)."""
+    return {name: row[2] for name, row in build_op_table(hlo_text).items()
+            if row[2]}
+
+
+def live_hlo(module_names=None):
+    """``{module name: HLO text}`` of the executables this process holds
+    (``client.live_executables()``): the programs that RAN, so the
+    instruction names are the trace's and no further compile is paid.
+    ``module_names`` keeps only those (a big program's text is tens of
+    MB; only the traced ones are stringified)."""
+    import jax
+    out = {}
+    for exe in jax.devices()[0].client.live_executables():
+        try:
+            module = exe.hlo_modules()[0]
+            if module_names is None or module.name in module_names:
+                out[module.name] = (out.get(module.name, "")
+                                    + module.to_string())
+        except Exception:  # noqa: BLE001 - an executable without HLO
+            continue
+    return out
+
+
+# ------------------------------------------------------------- the reader
+
+def _tpu_lane(plane):
+    """Extractor for a ``/device:TPU:<n>`` plane: the instruction name is
+    parsed from the event's HLO text, which is kept (opcode and result
+    type are in it even when no HLO text was registered)."""
+    lane = {"ops": [], "modules": [], "async": []}
+    for line in plane.lines:
+        if line.name == "XLA Modules":
+            lane["modules"] = [[ev.name.split("(")[0], ev.start_ns,
+                                ev.duration_ns] for ev in line.events]
+        elif line.name in ("XLA Ops", "Async XLA Ops"):
+            rows = lane["ops" if line.name == "XLA Ops" else "async"]
+            for ev in line.events:
+                inst = parse_instruction(ev.name)
+                rows.append([inst[0] if inst else ev.name, ev.start_ns,
+                             ev.duration_ns, ev.name])
+    return lane
+
+
+def _host_plane(plane, lanes, host):
+    """``/host:CPU`` in one pass: the program's ``hvd_*`` annotations go
+    to ``host``; and — the CPU backend's extractor — the events that carry
+    an ``hlo_op`` stat are ops, one lane per ``device_ordinal``, a program
+    execution being the extent of one ``run_id``."""
+    runs = {}
+    for line in plane.lines:
+        for ev in line.events:
+            stats = dict(ev.stats)
+            if ev.name.startswith("hvd_"):
+                step = stats.get("step_num")
+                host.append([ev.name, ev.start_ns, ev.duration_ns,
+                             None if step is None else int(step)])
+            op = stats.get("hlo_op")
+            if not op:
+                continue
+            key = f"cpu:{stats.get('device_ordinal', line.name)}"
+            lane = lanes.setdefault(key, {"ops": [], "modules": [],
+                                          "async": []})
+            end = ev.start_ns + ev.duration_ns
+            lane["ops"].append([str(op), ev.start_ns, ev.duration_ns, None])
+            run = runs.setdefault(
+                (key, stats.get("hlo_module", ""), stats.get("run_id")),
+                [ev.start_ns, end])
+            run[0] = min(run[0], ev.start_ns)
+            run[1] = max(run[1], end)
+    for (key, module, _), (t0, t1) in runs.items():
+        lanes[key]["modules"].append([str(module), t0, t1 - t0])
+
+
+def read_capture(trace_dir):
+    """A ``jax.profiler`` capture directory as plain lists, or None when
+    it holds no readable xplane file::
+
+        {"lanes": {lane: {"ops": [[instruction, start_ns, dur_ns,
+                                   hlo text or None], ...],
+                          "modules": [[module, start_ns, dur_ns], ...],
+                          "async": [[instruction, start_ns, dur_ns,
+                                     hlo text], ...]}},
+         "host": [[annotation, start_ns, dur_ns, step_num or None], ...],
+         "files": [paths]}
+
+    ``host`` holds the program's ``hvd_*`` annotations; times are on the
+    profiler's clock. Unreadable files degrade to "no data", never a
+    crash."""
+    if not trace_dir or not os.path.isdir(trace_dir):
+        return None
+    import jax
+    lanes, host, files = {}, [], []
+    for path in sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                                 recursive=True)):
+        try:
+            data = jax.profiler.ProfileData.from_file(path)
+            planes = list(data.planes)
+        except Exception:  # noqa: BLE001 - malformed capture, skip
+            _logger.warning("xla_trace: skipping unreadable trace file %s",
+                            path)
+            continue
+        files.append(path)
+        for plane in planes:
+            m = re.match(r"^/device:TPU:(\d+)$", plane.name)
+            if m:
+                lanes[f"tpu:{m.group(1)}"] = _tpu_lane(plane)
+            elif plane.name == "/host:CPU":
+                _host_plane(plane, lanes, host)
+    if not files:
+        return None
+    for lane in lanes.values():
+        for rows in lane.values():
+            rows.sort(key=lambda r: (r[1], -r[2]))
+    host.sort(key=lambda r: r[1])
+    return {"lanes": lanes, "host": host, "files": files}
+
+
+# ----------------------------------------------------------- the reduction
 
 def _merge_intervals(ivs):
     """Union of (start, end) intervals as a sorted disjoint list."""
@@ -143,7 +304,7 @@ def _merge_intervals(ivs):
     return out
 
 
-def _overlap_us(iv, merged):
+def _overlap(iv, merged):
     """Length of ``iv``'s intersection with a merged interval union."""
     s, e = iv
     total = 0.0
@@ -156,22 +317,102 @@ def _overlap_us(iv, merged):
     return total
 
 
-def parse_trace_dir(trace_dir, op_map=None):
-    """Parse a ``jax.profiler`` capture directory into per-phase device
-    time. Returns None when the directory holds no parseable device
-    events; otherwise a dict::
+def _self_times(rows):
+    """Self time of each event of one lane: its duration minus what the
+    events it encloses cover. ``rows`` sorted by (start, -duration)."""
+    selfs = [r[2] for r in rows]
+    stack = []
+    for i, (_, s, d, *_) in enumerate(rows):
+        e = s + d
+        while stack and rows[stack[-1]][1] + rows[stack[-1]][2] <= s:
+            stack.pop()
+        if stack and e <= rows[stack[-1]][1] + rows[stack[-1]][2]:
+            selfs[stack[-1]] -= d
+        stack.append(i)
+    return selfs
+
+
+def _collective(opcode):
+    """The base collective opcode of ``opcode`` (``all-reduce-start`` ->
+    ``all-reduce``), or None; ``-done`` halves are not collectives of
+    their own (the async span covers them)."""
+    for base in COLLECTIVES:
+        if opcode == base or opcode == base + "-start":
+            return base
+    return None
+
+
+def _in_window(ring_spans, window):
+    """The ring spans that lie inside ``window`` (``(t0, t1)`` on
+    ``perf_counter``; None: all of them)."""
+    return [s for s in ring_spans or ()
+            if window is None or (s[1] >= window[0] and s[2] <= window[1])]
+
+
+def clock_map(ring_spans, host_events, window=None):
+    """The offset that lays the flight ring (``perf_counter`` seconds) on
+    the profiler's clock (ns): every span that is both in the ring and in
+    the xplane is a pair — the k-th ``name`` span inside the capture
+    ``window`` (``(t0, t1)`` on ``perf_counter``) against the k-th
+    ``hvd_<name>`` annotation — and the offset is the median of
+    ``annotation start - span start``. Names whose counts differ on the
+    two sides are left out. Returns ``{"offset_ns", "pairs",
+    "spread_ns"}`` or None without a pair."""
+    by_name = {}
+    for name, t0, *_ in _in_window(ring_spans, window):
+        by_name.setdefault("hvd_" + name, []).append(t0)
+    seen = {}
+    for name, start_ns, _, step in host_events or ():
+        if step is None:  # the StepTraceAnnotation doubles step.execute
+            seen.setdefault(name, []).append(start_ns)
+    diffs = []
+    for name, starts in by_name.items():
+        got = seen.get(name, ())
+        if len(got) == len(starts):
+            diffs += [ns - t0 * 1e9
+                      for t0, ns in zip(sorted(starts), sorted(got))]
+    if not diffs:
+        return None
+    offset = statistics.median(diffs)
+    return {"offset_ns": offset, "pairs": len(diffs),
+            "spread_ns": max(diffs) - min(diffs)}
+
+
+def _host_self_seconds(inside):
+    """Self time per span name over the spans ``inside`` the window: a
+    span's duration minus its children's (by parent id)."""
+    child = {}
+    for _, t0, t1, _, _, parent, _ in inside:
+        child[parent] = child.get(parent, 0.0) + (t1 - t0)
+    out = {}
+    for name, t0, t1, _, sid, _, _ in inside:
+        key = "hvd_" + name
+        out[key] = out.get(key, 0.0) + (t1 - t0) - child.get(sid, 0.0)
+    return out
+
+
+def summarize(events, op_table=None, ring_spans=None, window=None):
+    """Reduce :func:`read_capture`'s lists. Returns None when no device
+    op ran in the capture; otherwise a dict::
 
         {"phases": {phase: seconds, ..., "other": s},
          "stages": {"ici": s, "dcn": s},
-         "moe": {...} or None,
-         "exchange": {...} or None,
-         "total_s": s, "events": n, "lanes": n_device_threads,
+         "moe": {...} or None, "exchange": {...} or None,
+         "kernels": {name: {"s": seconds, "calls": n}},
+         "collectives": [{"op", "bytes", "calls", "device_s",
+                          "exposed_s"}, ...],
+         "host": {"hvd_<span>": self seconds},
+         "clock": {"offset_ns", "pairs", "spread_ns",
+                   "host_device_skew_bound_us"} or None,
+         "idle": {"idle_s": s, "by": {span or reason: s}},
+         "total_s": s, "events": n, "lanes": n, "step_runs": n,
          "ts_min_us": t, "ts_max_us": t, "files": [paths]}
 
-    ``lanes`` is the number of distinct device timelines that
-    contributed; with one process driving N local devices the phase sums
-    cover N lanes, so per-step-per-device time is
-    ``phases[p] / steps / lanes``.
+    Every device time is SELF time summed over the lanes (one lane per
+    chip): per-step-per-chip time is ``phases[p] / steps / lanes``.
+    ``op_table`` is :func:`build_op_table` of the programs that ran;
+    without it TPU ops still have their opcode and result type (from the
+    event's text) but no scope, so everything is ``other``.
 
     ``moe`` appears when the capture contains MoE sub-phases
     (``hvd_dispatch``/``hvd_combine`` wrap only the dispatch/combine
@@ -179,105 +420,233 @@ def parse_trace_dir(trace_dir, op_map=None):
     device time the alltoall intervals spend overlapped with the union
     of expert-compute intervals across ALL lanes — an alltoall lane is
     stalled on peers, so any concurrent expert compute anywhere on the
-    mesh is dispatch latency the chunked pipeline hid —
-    and ``hidden_frac = hidden_s / alltoall_s`` is the overlap fraction
-    the bench/CI acceptance gate reads (``alltoall_hidden_frac``).
+    mesh is dispatch latency the chunked pipeline hid — and
+    ``hidden_frac = hidden_s / alltoall_s`` is the overlap fraction the
+    bench/CI acceptance gate reads (``alltoall_hidden_frac``).
 
     ``exchange`` appears when the capture contains gradient-exchange
-    device time (``hvd_exchange`` scopes — one interval per bucketed
-    psum under HOROVOD_EXCHANGE_BUCKETS > 1): the same interval fold as
-    ``moe``, with the compute union taken over the
-    forward/backward/optimizer/expert phases across ALL lanes — any
-    concurrent compute anywhere on the mesh while an exchange interval
-    runs is wire latency the bucketed pipeline hid.
-    ``hidden_frac = hidden_s / exchange_s`` feeds
-    ``hvd_exchange_hidden_frac`` and the bench/CI overlap gates."""
-    if not trace_dir or not os.path.isdir(trace_dir):
+    device time (``hvd_exchange`` scopes — one interval per bucketed psum
+    under HOROVOD_EXCHANGE_BUCKETS > 1): the same interval fold, with the
+    compute union taken over the forward/backward/optimizer/expert phases
+    across ALL lanes. ``hidden_frac = hidden_s / exchange_s`` feeds
+    ``hvd_exchange_hidden_frac`` and the bench/CI overlap gates.
+
+    ``kernels``: custom calls by the name they run under
+    (:func:`kernel_of_op_name`; an unnamed Mosaic call by its
+    instruction's). ``collectives``:
+    one row per opcode and message size — calls and device seconds over
+    all lanes, and ``exposed_s``, the part of them during which no other
+    op ran on that chip (all of a synchronous collective; for an
+    asynchronous one, its start-to-done span less the compute under it).
+    ``host``: self time per program span inside ``window`` (from the
+    flight ring, ``ring_spans``). ``clock``: :func:`clock_map` plus
+    ``host_device_skew_bound_us`` — over the step program's executions,
+    the least (device start - ``step.execute`` start on the mapped clock):
+    the enqueue cannot precede the run, so the host-device skew is at
+    most that, and an idle gap shorter than it cannot be given to a
+    span: ``idle`` files each gap between two busy intervals of a chip
+    under the innermost program span open at its middle, or under
+    ``under_skew_bound`` (:func:`_idle_by_span`)."""
+    if not events:
         return None
-    op_map = op_map or {}
-    cache = {}
+    op_table = op_table or {}
     phases = {p: 0.0 for p in PHASES}
     phases["other"] = 0.0
     stages = {s: 0.0 for s in STAGES}
-    lanes = set()
-    files, n_events = [], 0
-    ts_min, ts_max = None, None
-    expert_iv, a2a_iv = [], []
-    exch_iv, compute_iv = [], []
-    for path in _iter_trace_files(trace_dir):
-        events = _load_trace_events(path)
-        if not events:
+    kernels, coll = {}, {}
+    expert_iv, a2a_iv, exch_iv, compute_iv = [], [], [], []
+    n_events, ts_min, ts_max = 0, None, None
+    lanes_seen, step_runs, idle_lanes = 0, [], []
+    cache = {}
+
+    def info(instr, text):
+        row = cache.get(instr)
+        if row is None:
+            opcode, shape, op_name = op_table.get(instr, ("", "", ""))
+            if not opcode and text:
+                inst = parse_instruction(text)
+                if inst:
+                    opcode, shape = inst[1], inst[2]
+            row = cache[instr] = (opcode, shape, op_name,
+                                  phase_of_op_name(op_name),
+                                  stage_of_op_name(op_name))
+        return row
+
+    for lane in events["lanes"].values():
+        ops = lane["ops"]
+        if not ops:
             continue
-        files.append(path)
-        for ev in events:
-            if not isinstance(ev, dict) or ev.get("ph") != "X":
-                continue
-            args = ev.get("args")
-            if not isinstance(args, dict):
-                continue
-            op = args.get("hlo_op")
-            if not op:
-                continue
-            dur = float(ev.get("dur") or 0.0)
-            ts = ev.get("ts")
-            if isinstance(ts, (int, float)):
-                ts_min = ts if ts_min is None else min(ts_min, ts)
-                end = ts + dur
-                ts_max = end if ts_max is None else max(ts_max, end)
-            n_events += 1
-            lanes.add((ev.get("pid"), ev.get("tid")))
-            phase, stage = _resolve_phase(str(op), op_map, cache)
-            phases[phase if phase in phases else "other"] += dur
+        lanes_seen += 1
+        n_events += len(ops)
+        ts_min = ops[0][1] if ts_min is None else min(ts_min, ops[0][1])
+        end = max(o[1] + o[2] for o in ops)
+        ts_max = end if ts_max is None else max(ts_max, end)
+        busy = []   # leaf, non-collective ops: what can hide a collective
+        pending = []
+        for (instr, start, dur, text), self_ns in zip(ops,
+                                                      _self_times(ops)):
+            opcode, shape, op_name, phase, stage = info(instr, text)
+            iv = (start, start + dur)
+            phases[phase if phase in phases else "other"] += self_ns
             if stage in stages:
-                stages[stage] += dur
-            if isinstance(ts, (int, float)):
-                if phase == "expert":
-                    expert_iv.append((ts, ts + dur))
-                elif phase in ("dispatch", "combine"):
-                    a2a_iv.append((ts, ts + dur))
-                if phase == "exchange":
-                    exch_iv.append((ts, ts + dur))
-                elif phase in ("forward", "backward", "optimizer",
-                               "expert"):
-                    compute_iv.append((ts, ts + dur))
+                stages[stage] += self_ns
+            if phase == "expert":
+                expert_iv.append(iv)
+            elif phase in ("dispatch", "combine"):
+                a2a_iv.append(iv)
+            if phase == "exchange":
+                exch_iv.append(iv)
+            elif phase in ("forward", "backward", "optimizer", "expert"):
+                compute_iv.append(iv)
+            if opcode == "custom-call":
+                # a kernel: a custom call under a kernel name, or Mosaic's
+                # (XLA's own tiny custom calls are not worth a row)
+                label = kernel_of_op_name(op_name)
+                if label or (text and _MOSAIC_TARGET in text):
+                    k = kernels.setdefault(label or instr,
+                                           {"s": 0.0, "calls": 0})
+                    k["s"] += self_ns * 1e-9
+                    k["calls"] += 1
+            base = _collective(opcode)
+            if base and not opcode.endswith("-start"):
+                pending.append((base, shape_bytes(shape), iv))
+            elif not base and not opcode.endswith("-done") \
+                    and self_ns == dur:
+                busy.append(iv)
+        for instr, start, dur, text in lane["async"]:
+            opcode, shape, *_ = info(instr, text)
+            base = _collective(opcode)
+            if base:
+                pending.append((base, shape_bytes(
+                    shape, largest=base != "all-reduce"),
+                    (start, start + dur)))
+        idle_lanes.append(_merge_intervals(
+            (o[1], o[1] + o[2]) for o in ops))
+        busy = _merge_intervals(busy)
+        for base, nbytes, iv in pending:
+            row = coll.setdefault((base, nbytes), {
+                "op": base, "bytes": nbytes, "calls": 0, "device_s": 0.0,
+                "exposed_s": 0.0})
+            row["calls"] += 1
+            row["device_s"] += (iv[1] - iv[0]) * 1e-9
+            row["exposed_s"] += (iv[1] - iv[0] - _overlap(iv, busy)) * 1e-9
+        step_runs.append(_step_runs(lane["modules"]))
     if n_events == 0:
         return None
     moe = None
-    a2a_us = phases["dispatch"] + phases["combine"]
-    if a2a_us > 0.0:
+    a2a_ns = phases["dispatch"] + phases["combine"]
+    if a2a_ns > 0.0:
         merged = _merge_intervals(expert_iv)
-        hidden_us = sum(_overlap_us(iv, merged) for iv in a2a_iv)
+        hidden_ns = sum(_overlap(iv, merged) for iv in a2a_iv)
         moe = {
-            "dispatch_s": phases["dispatch"] * 1e-6,
-            "combine_s": phases["combine"] * 1e-6,
-            "expert_s": phases["expert"] * 1e-6,
-            "alltoall_s": a2a_us * 1e-6,
-            "hidden_s": hidden_us * 1e-6,
-            "hidden_frac": hidden_us / a2a_us,
+            "dispatch_s": phases["dispatch"] * 1e-9,
+            "combine_s": phases["combine"] * 1e-9,
+            "expert_s": phases["expert"] * 1e-9,
+            "alltoall_s": a2a_ns * 1e-9,
+            "hidden_s": hidden_ns * 1e-9,
+            "hidden_frac": min(hidden_ns / a2a_ns, 1.0),
         }
     exchange = None
-    exch_us = phases["exchange"]
-    if exch_us > 0.0:
+    exch_ns = phases["exchange"]
+    if exch_ns > 0.0:
         merged = _merge_intervals(compute_iv)
-        hidden_us = sum(_overlap_us(iv, merged) for iv in exch_iv)
+        hidden_ns = sum(_overlap(iv, merged) for iv in exch_iv)
         exchange = {
-            "exchange_s": exch_us * 1e-6,
-            "hidden_s": hidden_us * 1e-6,
-            "hidden_frac": hidden_us / exch_us,
+            "exchange_s": exch_ns * 1e-9,
+            "hidden_s": hidden_ns * 1e-9,
+            "hidden_frac": min(hidden_ns / exch_ns, 1.0),
         }
-    to_s = 1e-6  # trace durations are microseconds
+    inside = _in_window(ring_spans, window)
+    clock = clock_map(inside, events.get("host"))
+    if clock is not None:
+        clock["host_device_skew_bound_us"] = _skew_bound_us(
+            step_runs, inside, clock["offset_ns"])
+    idle = _idle_by_span(idle_lanes, inside, clock)
+    to_s = 1e-9  # xplane times are nanoseconds
     return {
         "phases": {k: v * to_s for k, v in phases.items()},
         "stages": {k: v * to_s for k, v in stages.items()},
         "moe": moe,
         "exchange": exchange,
+        "kernels": kernels,
+        "collectives": sorted(coll.values(),
+                              key=lambda r: -r["device_s"]),
+        "host": _host_self_seconds(inside),
+        "clock": clock,
+        "idle": idle,
         "total_s": sum(phases.values()) * to_s,
         "events": n_events,
-        "lanes": max(len(lanes), 1),
-        "ts_min_us": ts_min,
-        "ts_max_us": ts_max,
-        "files": files,
+        "lanes": max(lanes_seen, 1),
+        "step_runs": max((len(r) for r in step_runs), default=0),
+        "ts_min_us": ts_min * 1e-3,
+        "ts_max_us": ts_max * 1e-3,
+        "files": events.get("files", []),
     }
+
+
+def _step_runs(modules):
+    """Start times (ns) of the executions of the program that took most
+    device time on one lane: the train step."""
+    totals = {}
+    for name, _, dur in modules:
+        totals[name] = totals.get(name, 0.0) + dur
+    if not totals:
+        return []
+    step = max(totals, key=totals.get)
+    return sorted(start for name, start, _ in modules if name == step)
+
+
+def _skew_bound_us(step_runs, inside, offset_ns):
+    """min over lanes and captured steps of (k-th step-program start on
+    the device - k-th ``step.execute`` start mapped onto the profiler's
+    clock), in us; None when the two sides cannot be matched. ``inside``:
+    the ring spans of the capture window."""
+    enq = sorted(s[1] * 1e9 + offset_ns for s in inside
+                 if s[0] == "step.execute")
+    gaps = [run - e for runs in step_runs if len(runs) == len(enq)
+            for run, e in zip(runs, enq)]
+    return min(gaps) * 1e-3 if gaps else None
+
+
+def _idle_by_span(idle_lanes, ring_spans, clock):
+    """``{"idle_s": s, "by": {label: s}}`` over the lanes: each gap
+    between two busy intervals of a chip, filed under the innermost
+    program span open at its middle on the loop's thread (on the mapped
+    clock), ``no_span``
+    when none was, ``under_skew_bound`` when the gap is shorter than the
+    host-device skew bound (it cannot be given to a span) and
+    ``unmapped`` without a clock."""
+    by = {}
+    bound_ns = None
+    mapped = []
+    if clock is not None:
+        bound = clock.get("host_device_skew_bound_us")
+        bound_ns = None if bound is None else bound * 1e3
+        # the thread that enqueues the steps is the one the chip waits
+        # for; the producer thread's spans overlap it and explain nothing
+        loop = {s[3] for s in ring_spans or () if s[0] == "step.execute"}
+        mapped = [(s[1] * 1e9 + clock["offset_ns"],
+                   s[2] * 1e9 + clock["offset_ns"], "hvd_" + s[0])
+                  for s in ring_spans or () if not loop or s[3] in loop]
+    for merged in idle_lanes:
+        for (_, e0), (s1, _) in zip(merged, merged[1:]):
+            gap, mid = s1 - e0, (e0 + s1) / 2
+            if clock is None:
+                label = "unmapped"
+            elif bound_ns is None or gap < bound_ns:
+                label = "under_skew_bound"
+            else:
+                open_ = [m for m in mapped if m[0] <= mid <= m[1]]
+                label = (max(open_, key=lambda m: m[0])[2] if open_
+                         else "no_span")
+            by[label] = by.get(label, 0.0) + gap * 1e-9
+    return {"idle_s": sum(by.values()), "by": by}
+
+
+def parse_trace_dir(trace_dir, op_table=None, ring_spans=None, window=None):
+    """:func:`summarize` of :func:`read_capture`: a capture directory to
+    its summary, None when it holds no device events."""
+    return summarize(read_capture(trace_dir), op_table, ring_spans, window)
 
 
 def load_meta(trace_dir):
@@ -300,9 +669,14 @@ class StepTracer:
     path, ``TelemetryCallback`` covers eager loops). The first tick
     after arming starts the device trace; after ``n`` further ticks the
     trace stops, parses, writes the sidecar meta and exports
-    ``hvd_xla_phase_seconds`` / ``hvd_wire_stage_seconds``. Single
-    training-thread discipline: tick/arm race at worst delays a capture
-    by a step, never corrupts state."""
+    ``hvd_xla_phase_seconds`` / ``hvd_wire_stage_seconds``. A ticker that
+    can wait for the device passes ``drain``: the tracer calls it right
+    before the trace starts and right before it stops, so the capture
+    holds ``n`` WHOLE steps, the first from an empty queue (which is what
+    makes ``clock.host_device_skew_bound_us`` tight) — one pipeline
+    bubble at each end of an on-demand capture. Single training-thread
+    discipline: tick/arm race at worst delays a capture by a step, never
+    corrupts state."""
 
     def __init__(self, diag_dir="", rank=0):
         self.diag_dir = diag_dir or "."
@@ -316,7 +690,7 @@ class StepTracer:
         self._active = False
         self._owner = None
         self._seq = 0
-        self._op_map = {}
+        self._op_table = {}
         self._wall_start = 0.0
         self._mono_start = 0.0
 
@@ -328,17 +702,12 @@ class StepTracer:
     def armed(self):
         return self._want > 0
 
-    def wants_hlo(self):
-        """Whether callers should pay for HLO text right now (armed or
-        mid-capture); keeps the lower/compile cost strictly on-demand."""
-        return self._want > 0 or self._active
-
     def register_hlo(self, hlo_text):
-        """Merge the traced executable's instruction->op_name map (the
-        join key for :func:`parse_trace_dir`). Call once per program
-        about to run under the capture."""
+        """Add a program's instructions to the join table by hand. The
+        tracer finds the HLO of what ran by itself (:func:`live_hlo`);
+        this is for a program whose executable is gone by ``stop()``."""
         if hlo_text:
-            self._op_map.update(build_op_phase_map(hlo_text))
+            self._op_table.update(build_op_table(hlo_text))
 
     def arm(self, n, out_dir=None):
         """Request a capture of the next ``n`` full steps (n >= 1)."""
@@ -353,12 +722,13 @@ class StepTracer:
         self._owner = None
         self._want = n
 
-    def tick(self, owner=None, hlo=None):
+    def tick(self, owner=None, drain=None):
         """Step-boundary hook. ``owner`` locks the step cadence to the
         first caller that ticks (a compiled step and a telemetry
         callback in the same loop would otherwise double-count).
-        ``hlo`` is HLO text or a zero-arg provider, consulted only while
-        a capture is wanted."""
+        ``drain`` is a zero-arg callable that returns once the device
+        has finished every step enqueued so far; it is called only at
+        the two ends of a capture."""
         if not self._want and not self._active:
             return
         if owner is not None:
@@ -366,20 +736,22 @@ class StepTracer:
                 self._owner = owner
             elif self._owner is not owner:
                 return
-        if hlo is not None:
-            try:
-                self.register_hlo(hlo() if callable(hlo) else hlo)
-            except Exception:  # noqa: BLE001 - tracing must never kill a step
-                _logger.warning("xla_trace: HLO registration failed",
-                                exc_info=True)
         if not self._active:
-            self._start()
+            self._start(drain)
             return
         self._seen += 1
         if self._seen >= self._n:
-            self.stop()
+            self.stop(drain)
 
-    def _start(self):
+    @staticmethod
+    def _drain(drain):
+        if drain is not None:
+            try:
+                drain()
+            except Exception:  # noqa: BLE001 - tracing must never kill a step
+                _logger.warning("xla_trace: drain failed", exc_info=True)
+
+    def _start(self, drain=None):
         import jax
         # Claim the first unused sequence dir: a tracer recreated after an
         # elastic re-init restarts _seq at 0, and blindly reusing
@@ -391,9 +763,14 @@ class StepTracer:
                                f"xla-trace-{self._seq:03d}")
             if not (os.path.isdir(out) and os.listdir(out)):
                 break
+        self._drain(drain)
         try:
             os.makedirs(out, exist_ok=True)
-            jax.profiler.start_trace(out)
+            # The python tracer would record every frame of the loop:
+            # megabytes a step, and host time that is the tracer's own.
+            opts = jax.profiler.ProfileOptions()
+            opts.python_tracer_level = 0
+            jax.profiler.start_trace(out, profiler_options=opts)
         except Exception:  # noqa: BLE001 - e.g. a foreign trace is active
             _logger.warning("xla_trace: could not start device trace",
                             exc_info=True)
@@ -405,7 +782,7 @@ class StepTracer:
         self._mono_start = time.perf_counter()
         self._active = True
 
-    def stop(self):
+    def stop(self, drain=None):
         """Stop and finalize the current capture (no-op when idle).
         Returns the parsed summary dict, or None."""
         self._owner = None
@@ -414,6 +791,8 @@ class StepTracer:
             return None
         import jax
         self._active = False
+        self._drain(drain)
+        mono_stop = time.perf_counter()
         try:
             jax.profiler.stop_trace()
         except Exception:  # noqa: BLE001
@@ -421,22 +800,26 @@ class StepTracer:
             return None
         wall_stop = time.time()
         steps = max(self._seen, 1)
-        summary = parse_trace_dir(self.last_dir, self._op_map)
+        summary = self._summarize((self._mono_start, mono_stop))
         meta = {
-            "version": 1,
+            "version": 2,
             "rank": self.rank,
             "steps": steps,
             "wall_start": self._wall_start,
             "wall_stop": wall_stop,
             "wall_elapsed_s": wall_stop - self._wall_start,
+            # one instant on both host clocks: with summary.clock's
+            # offset it lays profiler ns on the flight dumps' wall clock
+            "mono_start": self._mono_start,
             "trace_dir": self.last_dir,
             "summary": summary,
             # Per-instruction phase/stage labels so the offline diag CLI
             # (--xla-trace) can phase-attribute individual device events
             # without the executable's HLO text.
-            "op_phases": {instr: [phase_of_op_name(op),
-                                  stage_of_op_name(op)]
-                          for instr, op in self._op_map.items()},
+            "op_phases": {instr: [phase_of_op_name(row[2]),
+                                  stage_of_op_name(row[2])]
+                          for instr, row in self._op_table.items()
+                          if row[2]},
         }
         try:
             path = os.path.join(self.last_dir, META_FILENAME)
@@ -451,19 +834,7 @@ class StepTracer:
         self.last_summary = summary
         metrics.XLA_TRACE_CAPTURES.inc()
         if summary:
-            lanes = summary["lanes"]
-            for phase, sec in summary["phases"].items():
-                metrics.XLA_PHASE_SECONDS.labels(phase=phase).set(sec)
-            for stage, sec in summary["stages"].items():
-                if sec > 0.0:
-                    metrics.WIRE_STAGE_SECONDS.labels(stage=stage).observe(
-                        sec / steps / lanes)
-            if summary.get("moe"):
-                metrics.MOE_ALLTOALL_HIDDEN_FRAC.set(
-                    summary["moe"]["hidden_frac"])
-            if summary.get("exchange"):
-                metrics.EXCHANGE_HIDDEN_FRAC.set(
-                    summary["exchange"]["hidden_frac"])
+            self._export(summary, steps)
         rec = recorder.get()
         if rec is not None:
             rec.record("xla_trace", name=self.last_dir or "",
@@ -471,6 +842,51 @@ class StepTracer:
                               "total_s": summary["total_s"] if summary
                               else 0.0})
         return summary
+
+    def _summarize(self, window):
+        """Read the capture, join it against the HLO of the programs
+        that ran in it and reduce; None on an empty or unreadable one."""
+        try:
+            events = read_capture(self.last_dir)
+            if events:
+                names = {m[0] for lane in events["lanes"].values()
+                         for m in lane["modules"]}
+                for text in live_hlo(names).values():
+                    self.register_hlo(text)
+            return summarize(events, self._op_table, recorder.spans(),
+                             window)
+        except Exception:  # noqa: BLE001 - a capture that cannot be read
+            _logger.warning("xla_trace: could not reduce %s", self.last_dir,
+                            exc_info=True)
+            return None
+
+    @staticmethod
+    def _export(summary, steps):
+        """The summary into the gauges, and its collectives into the
+        per-collective profile (stats.py -> profiler.txt) under their
+        ``*_xla`` labels: per logical collective (one chip's calls), with
+        the mean device time of a call."""
+        lanes = summary["lanes"]
+        for phase, sec in summary["phases"].items():
+            metrics.XLA_PHASE_SECONDS.labels(phase=phase).set(sec)
+        for stage, sec in summary["stages"].items():
+            if sec > 0.0:
+                metrics.WIRE_STAGE_SECONDS.labels(stage=stage).observe(
+                    sec / steps / lanes)
+        if summary.get("moe"):
+            metrics.MOE_ALLTOALL_HIDDEN_FRAC.set(
+                summary["moe"]["hidden_frac"])
+        if summary.get("exchange"):
+            metrics.EXCHANGE_HIDDEN_FRAC.set(
+                summary["exchange"]["hidden_frac"])
+        from .. import runtime
+        st = runtime._state.stats if runtime.is_initialized() else None
+        if st is not None:
+            for row in summary["collectives"]:
+                per_call = row["device_s"] / row["calls"]
+                for _ in range(max(round(row["calls"] / lanes), 1)):
+                    st.record(COLLECTIVES[row["op"]], row["bytes"],
+                              per_call)
 
 
 # --------------------------------------------------------- module plumbing

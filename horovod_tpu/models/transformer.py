@@ -606,12 +606,15 @@ def loss_fn(params, tokens, targets, cfg, axes=None):
     With cfg.loss_chunk set, the head + CE run per sequence chunk and
     full logits never materialize."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
-    if cfg.loss_chunk:
-        x, aux = trunk_with_aux(params, tokens, cfg, axes)
-        nll = _chunked_cross_entropy(params, x, targets, cfg, axes)
-    else:
-        logits, aux = forward_with_aux(params, tokens, cfg, axes)
-        nll = _cross_entropy(logits, targets, axes)
+    x, aux = trunk_with_aux(params, tokens, cfg, axes)
+    # Head matmul + cross entropy under a device name of their own
+    # (docs/diagnostics.md: the trace readers' `hvd_head_ce`), forward
+    # and backward alike.
+    with jax.named_scope("hvd_head_ce"):
+        if cfg.loss_chunk:
+            nll = _chunked_cross_entropy(params, x, targets, cfg, axes)
+        else:
+            nll = _cross_entropy(_head(params, x, cfg), targets, axes)
     loss = nll + MOE_AUX_COEF * aux
     return _pmean(loss, (axes.dp, axes.sp))
 

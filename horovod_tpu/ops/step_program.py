@@ -64,6 +64,7 @@ from jax.sharding import PartitionSpec as P
 
 from .. import guard, metrics, runtime
 from ..diag import xla_trace
+from ..diag.recorder import span
 from ..runtime import AXIS
 from ..stats import record_jit_traced
 from .collectives import (_nbytes, exchange_bucket_plan, segment_health,
@@ -763,7 +764,8 @@ class CompiledTrainStep:
         self._signatures = set()
         self._guard_pending = None
         self._zmeta = None
-        self._proginfo = {}
+        self._flops = {}   # signature -> whole-program FLOPs
+        self._calls = 0    # the `step` span's ordinal
         self.flops_per_step = 0.0
         self.cache_hits = 0
         self.cache_misses = 0
@@ -949,7 +951,7 @@ class CompiledTrainStep:
             self._donate_eff = None
             self._signatures = set()
             self._guard_pending = None
-            self._proginfo = {}
+            self._flops = {}
 
     def _step_mesh(self, st):
         """The mesh the step program maps over: the flat data-parallel
@@ -1041,32 +1043,21 @@ class CompiledTrainStep:
         model-digest component; the caller appends batch/world/zero)."""
         return f"{_callable_digest(self._loss_fn)[:12]}|{self._exchange}"
 
-    def _analyze(self, info, prog, params, opt_state, batch, tracer):
+    def _analyze(self, prog, params, opt_state, batch):
         """One-time per-signature program introspection, before the first
         execution (donation leaves the example buffers dead afterwards):
-        whole-program FLOPs from ``Lowered.cost_analysis`` (no backend
-        compile) for the MFU accounting, and — only while a trace
-        capture is wanted — the optimized-HLO text whose instruction
-        names key the device-trace join (costs one AOT compile)."""
+        whole-program FLOPs from ``Lowered.cost_analysis`` (one more
+        lowering, no backend compile) for the MFU accounting, 0.0 when
+        it cannot be had. Span
+        ``step.analyze``. The device-trace join needs no HLO from here:
+        the tracer reads it from the executable that ran
+        (diag/xla_trace.py ``live_hlo``)."""
         try:
-            lowered = prog.lower(params, opt_state, *batch)
+            cost = prog.lower(params, opt_state, *batch).cost_analysis()
+            cost = cost[0] if isinstance(cost, (list, tuple)) else cost
+            return float((cost or {}).get("flops", 0.0))
         except Exception:  # noqa: BLE001 - introspection is best-effort
-            info["flops"] = info["flops"] or 0.0
-            return
-        if info["flops"] is None:
-            try:
-                cost = lowered.cost_analysis()
-                cost = (cost[0] if isinstance(cost, (list, tuple))
-                        else cost)
-                info["flops"] = float((cost or {}).get("flops", 0.0))
-            except Exception:  # noqa: BLE001
-                info["flops"] = 0.0
-        if (tracer is not None and tracer.wants_hlo()
-                and info["hlo"] is None):
-            try:
-                info["hlo"] = lowered.compile().as_text()
-            except Exception:  # noqa: BLE001
-                info["hlo"] = ""
+            return 0.0
 
     def _flush_guard(self, monitor):
         """Fold the PREVIOUS compiled step's in-graph health matrix and
@@ -1085,6 +1076,13 @@ class CompiledTrainStep:
     # ------------------------------------------------------------- hot path
 
     def __call__(self, params, opt_state, *batch):
+        # One `step` span per call with its parts as children (names are
+        # the contract: docs/diagnostics.md "Host spans").
+        self._calls += 1
+        with span("step", step=self._calls) as sp:
+            return self._step(sp, params, opt_state, *batch)
+
+    def _step(self, sp, params, opt_state, *batch):
         st = runtime.state()
         self._bind_engine(st.engine)
         cfg = st.config
@@ -1104,8 +1102,9 @@ class CompiledTrainStep:
         self._flush_guard(monitor)
         donate = self._resolve_donate(st)
         buckets = self._resolve_buckets(cfg)
-        sig = self._signature(params, opt_state, batch, with_health, donate,
-                              buckets)
+        with span("step.signature"):
+            sig = self._signature(params, opt_state, batch, with_health,
+                                  donate, buckets)
         if sig not in self._signatures:
             if len(self._signatures) >= cfg.step_program_churn_limit:
                 return self._fallback("shape_churn", params, opt_state,
@@ -1124,32 +1123,36 @@ class CompiledTrainStep:
                                        average, comp, with_health, donate,
                                        has_aux, zmeta, buckets, spec)
 
-        prog, was_hit, hits, misses = st.engine.step_program(sig, build)
+        with span("step.lookup"):
+            prog, was_hit, hits, misses = st.engine.step_program(sig, build)
+        sp.set(hit=was_hit)
         if was_hit:
             self.cache_hits += 1
         else:
             self.cache_misses += 1
         metrics.STEP_PROGRAM_CACHE_HITS.set(hits)
         metrics.STEP_PROGRAM_CACHE_MISSES.set(misses)
-        info = self._proginfo.get(sig)
-        if info is None:
-            info = self._proginfo[sig] = {"flops": None, "hlo": None}
+        flops = self._flops.get(sig)
         tracer = xla_trace.get()
         scope = (jax.enable_x64() if _needs_x64(params, opt_state, batch)
                  else contextlib.nullcontext())
         with scope:
-            if info["flops"] is None or (tracer is not None
-                                         and tracer.wants_hlo()
-                                         and info["hlo"] is None):
-                self._analyze(info, prog, params, opt_state, batch, tracer)
+            if flops is None:
+                with span("step.analyze"):
+                    flops = self._flops[sig] = self._analyze(
+                        prog, params, opt_state, batch)
             if tracer is not None:
-                tracer.tick(owner=self, hlo=info["hlo"])
-            outs = prog(params, opt_state, *batch)
+                # `params` is the previous step's output: waiting for it
+                # drains the device (only at the two ends of a capture)
+                tracer.tick(owner=self, drain=functools.partial(
+                    jax.block_until_ready, params))
+            with span("step.execute", step_trace=self._calls):
+                outs = prog(params, opt_state, *batch)
         metrics.STEP_COMPILED_TOTAL.inc()
         self.compiled_steps += 1
-        if info["flops"]:
-            self.flops_per_step = info["flops"]
-            metrics.STEP_FLOPS_TOTAL.inc(info["flops"])
+        if flops:
+            self.flops_per_step = flops
+            metrics.STEP_FLOPS_TOTAL.inc(flops)
         if with_health:
             health = outs[-1]
             outs = outs[:-1]

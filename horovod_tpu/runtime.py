@@ -32,6 +32,7 @@ launcher env vars when present.
 import atexit
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -118,6 +119,55 @@ def _maybe_init_distributed():
     )
 
 
+#: jax.monitoring's duration events (jax 0.9.0: dispatch.py, compiler.py)
+#: under the span names they are recorded as. ``backend_compile_duration``
+#: wraps ``compile_or_get_cached``, so on a persistent-cache hit it
+#: contains ``cache_retrieval_time_sec``, which fires first: the listener
+#: takes that part out so that ``jax.compile`` + ``jax.cache_load`` is the
+#: backend time jax reports, counted once.
+_JAX_DURATION_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "jax.cache_load",
+}
+_JAX_SPAN_MIN_S = 1e-3
+_jax_watch = threading.local()
+_jax_watch_registered = False
+
+
+def _on_jax_duration(event, duration, **_):
+    name = _JAX_DURATION_SPANS.get(event)
+    if name is None:
+        return
+    from . import diag
+    now = time.perf_counter()
+    if name == "jax.cache_load":
+        _jax_watch.loaded = getattr(_jax_watch, "loaded", 0.0) + duration
+    elif name == "jax.compile":
+        duration = max(duration - getattr(_jax_watch, "loaded", 0.0), 0.0)
+        _jax_watch.loaded = 0.0
+    # jax reports the trace of every jitted helper (jnp.where ...) inside
+    # a trace as an event of its own: thousands per program, each inside
+    # the outer one's interval. Under a millisecond is not worth a slot
+    # of the ring.
+    if duration >= _JAX_SPAN_MIN_S:
+        diag.record_span(name, now - duration, now)
+
+
+def _watch_jax_compiles():
+    """One ``jax.monitoring`` listener per process: every trace, lowering,
+    backend compile and persistent-cache load becomes a ``jax.*`` span,
+    the child of whichever span is open on that thread (the first
+    ``step.execute``, the jitted init). jax has no way to take a listener
+    back, so a re-init registers nothing new."""
+    global _jax_watch_registered
+    if not _jax_watch_registered:
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_jax_duration)
+        _jax_watch_registered = True
+
+
 def init(comm=None, num_ranks=None):
     """Initialize the runtime. Idempotent, like the reference's
     ``InitializeHorovodOnce`` (operations.cc:1891-1907).
@@ -138,185 +188,193 @@ def init(comm=None, num_ranks=None):
         (shorthand for ``comm=range(num_ranks)``). Mutually exclusive
         with ``comm``.
     """
+    from . import diag
     with _state.lock:
         if _state.initialized and not _state.shutdown:
             return
-        if comm is not None and num_ranks is not None:
-            raise ValueError("pass either comm= or num_ranks=, not both")
-        if comm is not None and not (
-                isinstance(comm, (list, tuple, range))
-                and all(isinstance(r, (int, np.integer)) for r in comm)):
+        with diag.span("init"):
+            _init_locked(comm, num_ranks)
+
+
+def _init_locked(comm, num_ranks):
+    """``init`` proper, under the state lock and inside its span."""
+    if comm is not None and num_ranks is not None:
+        raise ValueError("pass either comm= or num_ranks=, not both")
+    if comm is not None and not (
+            isinstance(comm, (list, tuple, range))
+            and all(isinstance(r, (int, np.integer)) for r in comm)):
+        raise ValueError(
+            "horovod_tpu has no MPI: init(comm=...) takes a list of "
+            "device positions (world ranks), e.g. comm=[0, 2, 5] — "
+            "not an MPI communicator object.")
+    _place_compile_cache()
+    _maybe_init_distributed()
+
+    cfg = config_mod.Config.from_env()
+    devices = list(jax.devices())
+    if comm is not None:
+        ranks = [int(r) for r in comm]
+        if len(set(ranks)) != len(ranks):
+            raise ValueError(f"comm has duplicate ranks: {ranks}")
+        bad = [r for r in ranks if not 0 <= r < len(devices)]
+        if bad:
             raise ValueError(
-                "horovod_tpu has no MPI: init(comm=...) takes a list of "
-                "device positions (world ranks), e.g. comm=[0, 2, 5] — "
-                "not an MPI communicator object.")
-        _place_compile_cache()
-        _maybe_init_distributed()
+                f"comm ranks {bad} out of range [0, {len(devices)})")
+        devices = [devices[r] for r in ranks]
+    elif num_ranks is not None:
+        if num_ranks > len(devices):
+            raise ValueError(
+                f"num_ranks={num_ranks} exceeds available devices "
+                f"({len(devices)})")
+        devices = devices[:num_ranks]
+    # The topology layer owns mesh construction (parallel/mesh.py);
+    # elastic recovery rebuilds the job through this same call with
+    # the surviving device subset (init(comm=survivor_positions)).
+    from .parallel.mesh import (data_parallel_mesh, expert_data_mesh,
+                                model_expert_data_mesh)
+    mesh = data_parallel_mesh(devices, axis_name=AXIS)
+    # The 2-D (data, expert) mesh for expert-parallel MoE training
+    # (docs/performance.md "Expert-parallel MoE"). Built from the
+    # SAME device list as the 1-D mesh, so an elastic re-init over
+    # survivors rebuilds it too — and validates the degree still
+    # divides the shrunken world before any MoE program can run.
+    exp_mesh = None
+    if cfg.expert_parallel > 1:
+        exp_mesh = expert_data_mesh(
+            devices, expert_parallel=cfg.expert_parallel,
+            data_axis=AXIS, expert_axis="ep")
+    # The 3-D (data, expert, model) mesh for tensor-parallel dense
+    # trunks (docs/performance.md "Composable parallelism"). The ep
+    # axis is present even at size 1 so per-leaf sharding specs can
+    # always name the full ("hvd", "ep", "model") axis set.
+    mdl_mesh = None
+    if cfg.model_parallel > 1:
+        mdl_mesh = model_expert_data_mesh(
+            devices, expert_parallel=cfg.expert_parallel,
+            model_parallel=cfg.model_parallel,
+            data_axis=AXIS, expert_axis="ep", model_axis="model")
 
-        cfg = config_mod.Config.from_env()
-        devices = list(jax.devices())
-        if comm is not None:
-            ranks = [int(r) for r in comm]
-            if len(set(ranks)) != len(ranks):
-                raise ValueError(f"comm has duplicate ranks: {ranks}")
-            bad = [r for r in ranks if not 0 <= r < len(devices)]
-            if bad:
-                raise ValueError(
-                    f"comm ranks {bad} out of range [0, {len(devices)})")
-            devices = [devices[r] for r in ranks]
-        elif num_ranks is not None:
-            if num_ranks > len(devices):
-                raise ValueError(
-                    f"num_ranks={num_ranks} exceeds available devices "
-                    f"({len(devices)})")
-            devices = devices[:num_ranks]
-        # The topology layer owns mesh construction (parallel/mesh.py);
-        # elastic recovery rebuilds the job through this same call with
-        # the surviving device subset (init(comm=survivor_positions)).
-        from .parallel.mesh import (data_parallel_mesh, expert_data_mesh,
-                                    model_expert_data_mesh)
-        mesh = data_parallel_mesh(devices, axis_name=AXIS)
-        # The 2-D (data, expert) mesh for expert-parallel MoE training
-        # (docs/performance.md "Expert-parallel MoE"). Built from the
-        # SAME device list as the 1-D mesh, so an elastic re-init over
-        # survivors rebuilds it too — and validates the degree still
-        # divides the shrunken world before any MoE program can run.
-        exp_mesh = None
-        if cfg.expert_parallel > 1:
-            exp_mesh = expert_data_mesh(
-                devices, expert_parallel=cfg.expert_parallel,
-                data_axis=AXIS, expert_axis="ep")
-        # The 3-D (data, expert, model) mesh for tensor-parallel dense
-        # trunks (docs/performance.md "Composable parallelism"). The ep
-        # axis is present even at size 1 so per-leaf sharding specs can
-        # always name the full ("hvd", "ep", "model") axis set.
-        mdl_mesh = None
-        if cfg.model_parallel > 1:
-            mdl_mesh = model_expert_data_mesh(
-                devices, expert_parallel=cfg.expert_parallel,
-                model_parallel=cfg.model_parallel,
-                data_axis=AXIS, expert_axis="ep", model_axis="model")
+    _state.config = cfg
+    _state.devices = devices
+    _state.mesh = mesh
+    _state.expert_mesh = exp_mesh
+    _state.model_mesh = mdl_mesh
+    _state.num_ranks = len(devices)
+    # Ranks are mesh positions, NOT device ids (device ids are not dense
+    # across processes on every backend).
+    local_positions = [i for i, d in enumerate(devices)
+                       if d.process_index == jax.process_index()]
+    _state.local_num_ranks = max(len(local_positions), 1)
+    first_local = min(local_positions, default=0)
+    _state.first_rank = first_local
 
-        _state.config = cfg
-        _state.devices = devices
-        _state.mesh = mesh
-        _state.expert_mesh = exp_mesh
-        _state.model_mesh = mdl_mesh
-        _state.num_ranks = len(devices)
-        # Ranks are mesh positions, NOT device ids (device ids are not dense
-        # across processes on every backend).
-        local_positions = [i for i, d in enumerate(devices)
-                           if d.process_index == jax.process_index()]
-        _state.local_num_ranks = max(len(local_positions), 1)
-        first_local = min(local_positions, default=0)
-        _state.first_rank = first_local
+    # Launcher-provided topology (one-process-per-chip deployments);
+    # mirrors OMPI_COMM_WORLD_LOCAL_RANK-style discovery the reference
+    # relies on (reference: test/common.py:26-59). Fallback: position of
+    # this process's first device among the host's devices.
+    _state.local_rank = int(os.environ.get("HOROVOD_TPU_LOCAL_RANK", 0))  # hvdlint: disable=HVD003 -- launcher-worker protocol var
+    _state.local_size = int(os.environ.get("HOROVOD_TPU_LOCAL_SIZE",  # hvdlint: disable=HVD003 -- launcher-worker protocol var (the knob form is Config.tpu_local_size)
+                                           _state.local_num_ranks))
+    _state.cross_rank = int(os.environ.get("HOROVOD_TPU_CROSS_RANK",  # hvdlint: disable=HVD003 -- launcher-worker protocol var
+                                           jax.process_index()))
+    _state.cross_size = int(os.environ.get("HOROVOD_TPU_CROSS_SIZE",  # hvdlint: disable=HVD003 -- launcher-worker protocol var
+                                           jax.process_count()))
 
-        # Launcher-provided topology (one-process-per-chip deployments);
-        # mirrors OMPI_COMM_WORLD_LOCAL_RANK-style discovery the reference
-        # relies on (reference: test/common.py:26-59). Fallback: position of
-        # this process's first device among the host's devices.
-        _state.local_rank = int(os.environ.get("HOROVOD_TPU_LOCAL_RANK", 0))  # hvdlint: disable=HVD003 -- launcher-worker protocol var
-        _state.local_size = int(os.environ.get("HOROVOD_TPU_LOCAL_SIZE",  # hvdlint: disable=HVD003 -- launcher-worker protocol var (the knob form is Config.tpu_local_size)
-                                               _state.local_num_ranks))
-        _state.cross_rank = int(os.environ.get("HOROVOD_TPU_CROSS_RANK",  # hvdlint: disable=HVD003 -- launcher-worker protocol var
-                                               jax.process_index()))
-        _state.cross_size = int(os.environ.get("HOROVOD_TPU_CROSS_SIZE",  # hvdlint: disable=HVD003 -- launcher-worker protocol var
-                                               jax.process_count()))
+    from .stats import create_stats
+    from .timeline import create_timeline
+    _state.stats = create_stats()
+    # Multi-host: ONE global trace, written by process 0 (reference:
+    # rank 0's writer consumes every rank's events, timeline.h:46-74).
+    # Non-zero processes collect in memory and ship at shutdown.
+    multihost = jax.process_count() > 1
+    _state.timeline = create_timeline(
+        cfg.timeline, enabled=bool(cfg.timeline),
+        mark_cycles=cfg.timeline_mark_cycles,
+        collect=multihost and jax.process_index() != 0,
+        multihost=multihost)
 
-        from .stats import create_stats
-        from .timeline import create_timeline
-        _state.stats = create_stats()
-        # Multi-host: ONE global trace, written by process 0 (reference:
-        # rank 0's writer consumes every rank's events, timeline.h:46-74).
-        # Non-zero processes collect in memory and ship at shutdown.
-        multihost = jax.process_count() > 1
-        _state.timeline = create_timeline(
-            cfg.timeline, enabled=bool(cfg.timeline),
-            mark_cycles=cfg.timeline_mark_cycles,
-            collect=multihost and jax.process_index() != 0,
-            multihost=multihost)
+    # Flight recorder BEFORE the engine: the engine caches diag.get()
+    # at construction for its lock-free hot-path instrumentation
+    # (docs/diagnostics.md). The membership digest ties dumps to the
+    # participant set the events belong to.
+    from . import diag
+    from .diag import sentry as _sentry
+    from .diag import xla_trace as _xla_trace
+    from .ops.engine import _participants_digest
+    diag.install(cfg, rank=first_local,
+                 process_index=jax.process_index(),
+                 digest=_participants_digest(mesh))
+    _watch_jax_compiles()
+    # XLA step tracer + perf sentry, both None unless their knobs
+    # opt in (HOROVOD_XPROF_STEPS / HOROVOD_PERF_SENTRY): disabled
+    # builds hold no tracer object and no profiler state.
+    _xla_trace.install(cfg, rank=first_local)
+    _sentry.install(cfg, rank=first_local)
 
-        # Flight recorder BEFORE the engine: the engine caches diag.get()
-        # at construction for its lock-free hot-path instrumentation
-        # (docs/diagnostics.md). The membership digest ties dumps to the
-        # participant set the events belong to.
-        from . import diag
-        from .diag import sentry as _sentry
-        from .diag import xla_trace as _xla_trace
-        from .ops.engine import _participants_digest
-        diag.install(cfg, rank=first_local,
-                     process_index=jax.process_index(),
-                     digest=_participants_digest(mesh))
-        # XLA step tracer + perf sentry, both None unless their knobs
-        # opt in (HOROVOD_XPROF_STEPS / HOROVOD_PERF_SENTRY): disabled
-        # builds hold no tracer object and no profiler state.
-        _xla_trace.install(cfg, rank=first_local)
-        _sentry.install(cfg, rank=first_local)
+    # Step-integrity guard + chaos injector, same BEFORE-the-engine
+    # rule: the engine caches guard.get()/guard.inject.get() at
+    # construction (docs/robustness.md). Both None unless
+    # HOROVOD_GUARD / HOROVOD_GUARD_INJECT opt in.
+    from . import guard
+    guard.install(cfg, process_index=jax.process_index())
 
-        # Step-integrity guard + chaos injector, same BEFORE-the-engine
-        # rule: the engine caches guard.get()/guard.inject.get() at
-        # construction (docs/robustness.md). Both None unless
-        # HOROVOD_GUARD / HOROVOD_GUARD_INJECT opt in.
-        from . import guard
-        guard.install(cfg, process_index=jax.process_index())
+    from .ops.engine import EagerEngine
+    _state.engine = EagerEngine(mesh=mesh, num_ranks=_state.num_ranks,
+                                config=cfg, stats=_state.stats,
+                                timeline=_state.timeline)
+    # Hang watchdog (None unless HOROVOD_STALL_TIMEOUT_SECONDS > 0 —
+    # the zero default is fully inert: no thread, no KV beacons).
+    _state.diag_watchdog = diag.start_watchdog(_state.engine, cfg)
+    if cfg.autotune:
+        # Multi-host: only process 0 runs the tuning loop; its parameter
+        # changes ride the coordinator's decision log so every process
+        # applies them at the same decision index (reference SyncParams,
+        # parameter_manager.cc:223-262). Non-zero processes apply
+        # incoming autotune decisions in the engine and never tune.
+        if jax.process_count() > 1 and jax.process_index() != 0:
+            _logger.info("autotune: process %d defers to process 0's "
+                         "synced parameters", jax.process_index())
+        else:
+            from .autotune import ParameterManager
+            _state.autotuner = ParameterManager(cfg)
+            if jax.process_count() > 1:
+                _state.autotuner.sync_publish = \
+                    _state.engine.publish_autotune
+            _state.engine.autotuner = _state.autotuner
 
-        from .ops.engine import EagerEngine
-        _state.engine = EagerEngine(mesh=mesh, num_ranks=_state.num_ranks,
-                                    config=cfg, stats=_state.stats,
-                                    timeline=_state.timeline)
-        # Hang watchdog (None unless HOROVOD_STALL_TIMEOUT_SECONDS > 0 —
-        # the zero default is fully inert: no thread, no KV beacons).
-        _state.diag_watchdog = diag.start_watchdog(_state.engine, cfg)
-        if cfg.autotune:
-            # Multi-host: only process 0 runs the tuning loop; its parameter
-            # changes ride the coordinator's decision log so every process
-            # applies them at the same decision index (reference SyncParams,
-            # parameter_manager.cc:223-262). Non-zero processes apply
-            # incoming autotune decisions in the engine and never tune.
-            if jax.process_count() > 1 and jax.process_index() != 0:
-                _logger.info("autotune: process %d defers to process 0's "
-                             "synced parameters", jax.process_index())
-            else:
-                from .autotune import ParameterManager
-                _state.autotuner = ParameterManager(cfg)
-                if jax.process_count() > 1:
-                    _state.autotuner.sync_publish = \
-                        _state.engine.publish_autotune
-                _state.engine.autotuner = _state.autotuner
+    # Runtime metrics: lifecycle counters, the stats/device-memory
+    # collect hooks, and the export sinks (JSONL / Prometheus /
+    # timeline counter splice) — see metrics.py and docs/observability.md.
+    from . import metrics
+    from .stats import register_metrics
+    register_metrics(_state.stats)
+    metrics.registry().set_collect_hook("device_memory",
+                                        _collect_device_memory)
+    _state.metrics_exporters = metrics.start_exporters(
+        cfg, timeline=_state.timeline,
+        process_index=jax.process_index())
+    metrics.RUNTIME_INITS.inc()
+    metrics.RUNTIME_UP.set(1)
+    metrics.RUNTIME_RANKS.set(_state.num_ranks)
+    metrics.MODEL_PARALLEL.set(cfg.model_parallel if mdl_mesh
+                               is not None else 1)
+    # The autoscaler's resize observable: worker PROCESSES in this
+    # session (ranks count chips) — shrinks when an elastic recovery
+    # re-inits over the survivors' devices (docs/elastic.md).
+    metrics.ELASTIC_WORLD_SIZE.set(
+        len({d.process_index for d in devices}))
+    _record_elastic_restarts()
+    _record_elastic_resize()
 
-        # Runtime metrics: lifecycle counters, the stats/device-memory
-        # collect hooks, and the export sinks (JSONL / Prometheus /
-        # timeline counter splice) — see metrics.py and docs/observability.md.
-        from . import metrics
-        from .stats import register_metrics
-        register_metrics(_state.stats)
-        metrics.registry().set_collect_hook("device_memory",
-                                            _collect_device_memory)
-        _state.metrics_exporters = metrics.start_exporters(
-            cfg, timeline=_state.timeline,
-            process_index=jax.process_index())
-        metrics.RUNTIME_INITS.inc()
-        metrics.RUNTIME_UP.set(1)
-        metrics.RUNTIME_RANKS.set(_state.num_ranks)
-        metrics.MODEL_PARALLEL.set(cfg.model_parallel if mdl_mesh
-                                   is not None else 1)
-        # The autoscaler's resize observable: worker PROCESSES in this
-        # session (ranks count chips) — shrinks when an elastic recovery
-        # re-inits over the survivors' devices (docs/elastic.md).
-        metrics.ELASTIC_WORLD_SIZE.set(
-            len({d.process_index for d in devices}))
-        _record_elastic_restarts()
-        _record_elastic_resize()
-
-        _state.shutdown = False
-        _state.initialized = True
-        _logger.info("Started horovod_tpu with %d ranks over %d process(es); "
-                     "eager dispatch %s",
-                     _state.num_ranks, jax.process_count(),
-                     f"overlapped (pipeline depth {cfg.pipeline_depth})"
-                     if cfg.pipeline_depth > 0 else
-                     "synchronous (HOROVOD_PIPELINE_DEPTH=0)")
-        atexit.register(_shutdown_atexit)
+    _state.shutdown = False
+    _state.initialized = True
+    _logger.info("Started horovod_tpu with %d ranks over %d process(es); "
+                 "eager dispatch %s",
+                 _state.num_ranks, jax.process_count(),
+                 f"overlapped (pipeline depth {cfg.pipeline_depth})"
+                 if cfg.pipeline_depth > 0 else
+                 "synchronous (HOROVOD_PIPELINE_DEPTH=0)")
+    atexit.register(_shutdown_atexit)
 
 
 _elastic_restarts_recorded = False

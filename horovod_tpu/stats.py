@@ -31,7 +31,9 @@ def record_jit(op, nbytes, elapsed_s=0.0):
     always-on hot-path counters (reference: operations.cc:219-317,
     global_state.h:113-141): zero overhead at step time, and the shutdown
     dump (profiler.txt) shows every collective the program contains with its
-    wire bytes. Set ``HOROVOD_PROFILER_JIT_CALLBACKS=1`` to additionally
+    wire bytes; their DEVICE time is what a trace capture adds (the
+    ``*_xla`` rows, ``hvd.trace_steps``, diag/xla_trace.py). Set
+    ``HOROVOD_PROFILER_JIT_CALLBACKS=1`` to additionally
     count every *execution* via a host callback (precise, small per-step
     host-sync cost).
 
@@ -193,9 +195,16 @@ class CollectiveStats:
            "alltoall", "alltoall_jit", "reducescatter", "reducescatter_jit",
            "gather", "gatherv")
 
+    # What a device-trace capture found inside the compiled step
+    # (diag/xla_trace.py: calls, message size and DEVICE time per XLA
+    # collective). Labels of their own, dumped after the fork's list and
+    # not mirrored into hvd_collective_calls: those count host-issued ops.
+    XLA_OPS = ("allreduce_xla", "allgather_xla", "reducescatter_xla",
+               "alltoall_xla", "collectivepermute_xla")
+
     def __init__(self):
         self._lock = threading.Lock()
-        self._ops = {op: _OpStats() for op in self.OPS}
+        self._ops = {op: _OpStats() for op in self.OPS + self.XLA_OPS}
 
     def record(self, op, nbytes, elapsed_s):
         with self._lock:
@@ -239,7 +248,7 @@ class CollectiveStats:
         """Dump in the fork's profiler.txt CSV-ish layout
         (reference: operations.cc:219-317)."""
         lines = []
-        for op in self.OPS:
+        for op in self.OPS + self.XLA_OPS:
             s = self._ops[op]
             pretty = op.replace("_", " ")
             lines.append(f"Counter {pretty},{s.counter}")

@@ -9,7 +9,7 @@ with an elementwise optimizer like sgd, across the psum and zero2 tags;
 the guard-enabled bucketed program matches the guard-off build bitwise
 when no fault fires; the bucket count is part of the step-program cache
 signature (two counts never share a program) and an elastic re-init
-cold-starts the membership-scoped cache; parse_trace_dir folds
+cold-starts the membership-scoped cache; xla_trace.summarize folds
 hvd_exchange intervals against the compute-union into the ``exchange``
 block whose hidden_frac feeds the ``hvd_exchange_hidden_frac`` gauge and
 the autoscaler's min-fold policy signal.
@@ -229,45 +229,37 @@ def test_elastic_reinit_cold_starts_bucketed_cache():
 
 # ----------------------------------------------- trace fold + observability
 
-def _exchange_capture(tmp_path):
-    """Synthetic capture: backward compute 0-100us; exchange bucket A
-    50-110us (50us hidden under backward), exchange bucket B 200-240us
-    (fully exposed) -> hidden_frac = 50/100."""
-    import gzip
-    import json
-    import os
-
-    from horovod_tpu.diag.xla_trace import build_op_phase_map
-
-    hlo = """
-      %conv.1 = f32[4]{0} convolution(%a, %b), metadata={op_name="jit(step)/hvd_backward/conv"}
-      %ar.2 = f32[4]{0} add(%c, %d), metadata={op_name="jit(step)/hvd_exchange_bucket0/psum/add"}
-      %ar.3 = f32[4]{0} add(%e, %f), metadata={op_name="jit(step)/hvd_exchange_bucket1/psum/add"}
-      %app.4 = f32[4]{0} add(%g, %h), metadata={op_name="jit(step)/hvd_optimizer/hvd_apply_bucket0/add"}
-    """
-    op_map = build_op_phase_map(hlo)
-
-    def xev(op, ts, dur):
-        return {"ph": "X", "name": op, "ts": ts, "dur": dur,
-                "pid": 1, "tid": 1, "args": {"hlo_op": op}}
-
-    events = [xev("conv.1", 0, 100), xev("ar.2", 50, 60),
-              xev("ar.3", 200, 40), xev("app.4", 300, 10)]
-    os.makedirs(str(tmp_path), exist_ok=True)
-    with gzip.open(os.path.join(str(tmp_path), "host.trace.json.gz"),
-                   "wt", encoding="utf-8") as f:
-        f.write(json.dumps({"traceEvents": events}))
-    return op_map
+_EXCHANGE_HLO = """
+  %conv.1 = f32[4]{0} convolution(%a, %b), metadata={op_name="jit(step)/hvd_backward/conv"}
+  %ar.2 = f32[4]{0} add(%c, %d), metadata={op_name="jit(step)/hvd_exchange_bucket0/psum/add"}
+  %ar.3 = f32[4]{0} add(%e, %f), metadata={op_name="jit(step)/hvd_exchange_bucket1/psum/add"}
+  %app.4 = f32[4]{0} add(%g, %h), metadata={op_name="jit(step)/hvd_optimizer/hvd_apply_bucket0/add"}
+"""
 
 
-def test_parse_trace_dir_exchange_fold(tmp_path):
+def _exchange_capture():
+    """Synthetic capture, as ``read_capture`` hands it on (plain lists,
+    ns): backward compute 0-100us; exchange bucket A 50-110us (50us
+    hidden under backward), exchange bucket B 200-240us (fully exposed)
+    -> hidden_frac = 50/100. A lane per op stream, as the CPU backend
+    has them (a TPU's XLA Ops line is serial)."""
+    def lane(*ops):
+        return {"ops": [[name, ts * 1000, dur * 1000, None]
+                        for name, ts, dur in ops],
+                "modules": [], "async": []}
+
+    return {"lanes": {"cpu:0": lane(("conv.1", 0, 100), ("app.4", 300, 10)),
+                      "cpu:1": lane(("ar.2", 50, 60), ("ar.3", 200, 40))},
+            "host": [], "files": []}
+
+
+def test_summarize_exchange_fold():
     """The nested hvd_exchange_bucket{k} scopes attribute to 'exchange'
     (prefix match), hvd_apply_bucket{k} under hvd_optimizer stays
     compute, and the interval fold reports the hidden fraction."""
-    from horovod_tpu.diag.xla_trace import parse_trace_dir
+    from horovod_tpu.diag.xla_trace import build_op_table, summarize
 
-    op_map = _exchange_capture(tmp_path)
-    s = parse_trace_dir(str(tmp_path), op_map)
+    s = summarize(_exchange_capture(), build_op_table(_EXCHANGE_HLO))
     assert s["phases"]["exchange"] == pytest.approx(100e-6)
     assert s["phases"]["backward"] == pytest.approx(100e-6)
     assert s["phases"]["optimizer"] == pytest.approx(10e-6)
@@ -280,16 +272,18 @@ def test_parse_trace_dir_exchange_fold(tmp_path):
 def test_tracer_exports_hidden_frac_gauge(monkeypatch, tmp_path):
     """StepTracer.stop() exports the fold as hvd_exchange_hidden_frac —
     the gauge the autoscaler signal and observability docs point at."""
+    from horovod_tpu.diag import xla_trace
     from horovod_tpu.diag.xla_trace import StepTracer
 
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    monkeypatch.setattr(jax.profiler, "start_trace", lambda d, **kw: None)
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
+    monkeypatch.setattr(xla_trace, "read_capture",
+                        lambda d: _exchange_capture())
     tr = StepTracer(diag_dir=str(tmp_path))
     tr.arm(1)
     tr.tick()              # starts the window, creates last_dir
-    op_map = _exchange_capture(tr.last_dir)
-    tr._op_map.update({k: v for k, v in op_map.items()})
-    tr.tick()              # closes the window -> parse + export
+    tr.register_hlo(_EXCHANGE_HLO)
+    tr.tick()              # closes the window -> reduce + export
     assert not tr.active and tr.captures == 1
     assert tr.last_summary["exchange"]["hidden_frac"] == pytest.approx(0.5)
     snap = hvd.metrics_snapshot()

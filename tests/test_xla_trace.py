@@ -1,11 +1,14 @@
 """On-demand XLA device tracing (diag/xla_trace.py): the HLO op_name
-phase join, malformed-capture tolerance, the end-to-end compiled-step
-window, the inert-by-default contract, and the diag CLI --xla-trace
-merge (docs/diagnostics.md "Seeing inside the compiled step")."""
+phase join (first step-region label wins), the xplane reader's tolerance
+of missing and malformed captures, the reduction on plain event lists
+and on the v5e recording the benchmark keeps, the end-to-end
+compiled-step window, the inert-by-default contract, and the diag CLI
+--xla-trace merge (docs/diagnostics.md "Seeing inside the compiled
+step")."""
 
-import gzip
 import json
 import os
+import shutil
 
 import jax
 import jax.numpy as jnp
@@ -15,52 +18,85 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.diag import xla_trace
 from horovod_tpu.diag.xla_trace import (StepTracer, build_op_phase_map,
-                                        parse_trace_dir, phase_of_op_name,
-                                        stage_of_op_name)
+                                        build_op_table, clock_map,
+                                        kernel_of_op_name, parse_trace_dir,
+                                        phase_of_op_name, read_capture,
+                                        shape_bytes, stage_of_op_name,
+                                        summarize)
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+RECORDED = os.path.join(ROOT, "benchmark", "tests", "data")
 
 SYNTH_HLO = """
   %dot.1 = f32[4,4]{1,0} dot(%p0, %p1), metadata={op_name="jit(step)/jit(main)/hvd_forward/dot_general" source_file="m.py"}
-  %add.2 = f32[4]{0} add(%a, %b), metadata={op_name="jit(step)/hvd_optimizer/hvd_exchange/psum/add"}
+  %add.2 = f32[4]{0} add(%a, %b), metadata={op_name="jit(step)/hvd_exchange/hvd_ici/psum/add"}
   %mul.3 = f32[4]{0} multiply(%c, %d), metadata={op_name="jit(step)/hvd_exchange/hvd_dcn/psum-scatter"}
   %neg.4 = f32[4]{0} negate(%e), metadata={op_name="jit(step)/transpose/neg"}
+  %copy.5 = f32[4]{0} copy(%f)
+  %while.6 = (s32[], f32[4]{0}) while(%t), condition=%c, body=%b, metadata={op_name="jit(step)/hvd_forward/while"}
+  %kern.7 = bf16[2,8,128]{2,1,0} custom-call(%q), custom_call_target="tpu_custom_call", metadata={op_name="jit(step)/hvd_backward/transpose(jvp(hvd_forward))/hvd_flash_dq/pallas_call"}
+  %ar.8 = f32[1024]{0} all-reduce(%g), replica_groups={}, to_apply=%sum, metadata={op_name="jit(step)/hvd_exchange/psum"}
+  %ars.9 = (f32[256]{0}, f32[256]{0}) all-reduce-start(%h, %i), to_apply=%sum, metadata={op_name="jit(step)/hvd_exchange/psum"}
+  %ard.10 = (f32[256]{0}, f32[256]{0}) all-reduce-done(%ars.9), metadata={op_name="jit(step)/hvd_exchange/psum"}
 """
 
 
-def test_phase_of_op_name_last_label_wins():
+def _lane(ops, modules=(), asyncs=()):
+    """One lane of ``read_capture``'s plain lists; times given in us."""
+    def rows(evs):
+        out = [[name, ts * 1000, dur * 1000, None] for name, ts, dur in evs]
+        return sorted(out, key=lambda r: (r[1], -r[2]))
+    return {"ops": rows(ops), "async": rows(asyncs),
+            "modules": [[n, ts * 1000, dur * 1000] for n, ts, dur in modules]}
+
+
+def _events(**lanes):
+    return {"lanes": lanes, "host": [], "files": []}
+
+
+def test_phase_of_op_name_first_region_wins():
     assert phase_of_op_name("jit(f)/hvd_forward/dot") == "forward"
-    # ZeRO collectives nested inside the optimizer attribute to exchange
+    # a custom-vjp kernel's backward and remat's recomputed forward carry
+    # the forward's label further down the path: both are backward time
     assert phase_of_op_name(
-        "jit(f)/hvd_optimizer/hvd_exchange/psum") == "exchange"
+        "jit(f)/hvd_backward/transpose(hvd_forward)/pallas_call") \
+        == "backward"
+    assert phase_of_op_name(
+        "jit(f)/hvd_backward/transpose(jvp(hvd_forward))/jvp()/checkpoint/"
+        "rematted_computation/hvd_flash_fwd/pallas_call") == "backward"
+    assert phase_of_op_name(
+        "jit(f)/hvd_optimizer/hvd_exchange/psum") == "optimizer"
+    # MoE sub-phases are leaves inside forward/backward: innermost wins
+    assert phase_of_op_name(
+        "jit(f)/hvd_forward/hvd_dispatch/all_to_all") == "dispatch"
+    assert phase_of_op_name(
+        "jit(f)/hvd_backward/transpose(hvd_forward)/hvd_expert/dot") \
+        == "expert"
+    assert phase_of_op_name("jit(f)/hvd_exchange_bucket3/psum") == "exchange"
     assert phase_of_op_name("jit(f)/transpose/neg") is None
     assert phase_of_op_name(None) is None
     assert stage_of_op_name("jit(f)/hvd_exchange/hvd_dcn/psum") == "dcn"
     assert stage_of_op_name("jit(f)/hvd_exchange/psum") is None
+    assert kernel_of_op_name(
+        "jit(f)/hvd_backward/transpose(hvd_forward)/hvd_flash_dq/"
+        "hvd_flash_dq/pallas_call") == "hvd_flash_dq"
+    assert kernel_of_op_name("jit(f)/hvd_forward/pallas_call") is None
 
 
 def test_build_op_phase_map_synthetic_hlo():
     m = build_op_phase_map(SYNTH_HLO)
     assert m["dot.1"].endswith("hvd_forward/dot_general")
-    assert set(m) == {"dot.1", "add.2", "mul.3", "neg.4"}
+    # an instruction the compiler made without metadata has no op_name
+    assert "copy.5" not in m and len(m) == 9
     assert build_op_phase_map("") == {}
-
-
-def _write_capture(dirpath, events, gz=True):
-    os.makedirs(dirpath, exist_ok=True)
-    name = "host.trace.json.gz" if gz else "host.trace.json"
-    doc = json.dumps({"traceEvents": events})
-    path = os.path.join(dirpath, name)
-    if gz:
-        with gzip.open(path, "wt", encoding="utf-8") as f:
-            f.write(doc)
-    else:
-        with open(path, "w", encoding="utf-8") as f:
-            f.write(doc)
-    return path
-
-
-def _xev(op, dur, ts=0, pid=1, tid=1):
-    return {"ph": "X", "name": op, "ts": ts, "dur": dur,
-            "pid": pid, "tid": tid, "args": {"hlo_op": op}}
+    table = build_op_table(SYNTH_HLO)
+    assert table["copy.5"] == ("copy", "f32[4]{0}", "")
+    assert table["kern.7"][0] == "custom-call"
+    assert table["ars.9"][0] == "all-reduce-start"
+    assert shape_bytes(table["ar.8"][1]) == 4096
+    assert shape_bytes("(f32[256]{0}, bf16[2,8]{1,0:T(8,128)})") \
+        == 1024 + 32
+    assert shape_bytes("(f32[8]{0}, f32[32]{0}, u32[])", largest=True) == 128
 
 
 def test_parse_trace_dir_missing_empty_malformed(tmp_path):
@@ -68,60 +104,183 @@ def test_parse_trace_dir_missing_empty_malformed(tmp_path):
     assert parse_trace_dir(str(tmp_path / "nope")) is None
     assert parse_trace_dir(str(tmp_path)) is None
     assert parse_trace_dir("") is None
-    # malformed JSON and a truncated gzip never raise
-    bad = tmp_path / "bad"
-    bad.mkdir()
-    (bad / "a.trace.json").write_text("this is not json")
-    (bad / "b.trace.json.gz").write_bytes(b"\x1f\x8b\x08garbage")
-    (bad / "c.trace.json").write_text('{"traceEvents": "not a list"}')
-    assert parse_trace_dir(str(bad)) is None
-    # events without an hlo_op arg (host-side python spans) don't count
-    _write_capture(str(bad / "sub"), [
-        {"ph": "X", "name": "py", "ts": 0, "dur": 5, "pid": 0, "tid": 0}])
-    assert parse_trace_dir(str(bad)) is None
+    # a truncated / garbage xplane file never raises
+    bad = tmp_path / "bad" / "plugins" / "profile" / "t"
+    bad.mkdir(parents=True)
+    (bad / "a.xplane.pb").write_bytes(b"\x1f\x8b\x08garbage")
+    (bad / "b.xplane.pb").write_bytes(b"")
+    assert parse_trace_dir(str(tmp_path / "bad")) is None
+    # a capture with host spans only (no device op ran) is "no data" too
+    host_only = {"lanes": {}, "host": [["hvd_step", 0, 5, None]],
+                 "files": ["x"]}
+    assert summarize(host_only) is None
+    assert summarize(_events(l0=_lane([]))) is None
+    assert summarize(None) is None
 
 
-def test_parse_trace_dir_joins_phases(tmp_path):
-    op_map = build_op_phase_map(SYNTH_HLO)
-    _write_capture(str(tmp_path), [
-        _xev("dot.1", 100, ts=0, tid=1),
-        _xev("add.2", 50, ts=120, tid=2),
-        _xev("mul.3", 30, ts=160, tid=1),
-        _xev("neg.4", 25, ts=200, tid=1),   # mapped, outside hvd_ scopes
-        _xev("fusion.9", 5, ts=230, tid=1),  # unmapped instruction
-        # numeric-suffix variant of a mapped instruction: joined when
-        # the suffix-stripped base is unambiguous
-        _xev("dot.7", 10, ts=240, tid=1),
-    ])
-    s = parse_trace_dir(str(tmp_path), op_map)
+def test_summarize_joins_phases():
+    s = summarize(_events(
+        l0=_lane([("dot.1", 0, 100), ("mul.3", 160, 30),
+                  ("neg.4", 200, 25),   # mapped, outside hvd_ scopes
+                  ("fusion.9", 230, 5),  # unmapped instruction
+                  ("copy.5", 240, 10)]),  # mapped, no metadata
+        l1=_lane([("add.2", 120, 50)])), build_op_table(SYNTH_HLO))
     us = 1e-6
-    assert s["phases"]["forward"] == pytest.approx((100 + 10) * us)
+    assert s["phases"]["forward"] == pytest.approx(100 * us)
     assert s["phases"]["exchange"] == pytest.approx((50 + 30) * us)
-    assert s["phases"]["other"] == pytest.approx((25 + 5) * us)
+    assert s["phases"]["other"] == pytest.approx((25 + 5 + 10) * us)
     assert s["stages"]["dcn"] == pytest.approx(30 * us)
-    assert s["stages"]["ici"] == 0.0
+    assert s["stages"]["ici"] == pytest.approx(50 * us)
     assert s["events"] == 6 and s["lanes"] == 2
     assert s["total_s"] == pytest.approx(sum(s["phases"].values()))
     assert s["ts_min_us"] == 0 and s["ts_max_us"] == 250
+    assert s["kernels"] == {} and s["collectives"] == []
+    assert s["host"] == {} and s["clock"] is None
+
+
+def test_summarize_self_time_kernels_and_collectives():
+    """An enclosing while counts only what its body does not cover; a
+    custom call is filed under its kernel name; a synchronous collective
+    is all exposed, an asynchronous one only where no compute ran
+    between its start and its done."""
+    s = summarize(_events(l0=_lane(
+        [("while.6", 0, 100), ("dot.1", 10, 30), ("dot.1", 50, 30),
+         ("kern.7", 100, 40), ("kern.7", 140, 40),
+         ("ar.8", 200, 50),
+         ("ars.9", 300, 1), ("dot.1", 301, 60), ("ard.10", 361, 19)],
+        modules=[("jit_step", 0, 400)],
+        asyncs=[("ars.9", 300, 80)])), build_op_table(SYNTH_HLO))
+    us = 1e-6
+    # while: 100 - 60 of body; three dots of 30, 30, 60
+    assert s["phases"]["forward"] == pytest.approx((40 + 120) * us)
+    assert s["phases"]["backward"] == pytest.approx(80 * us)
+    assert s["kernels"] == {"hvd_flash_dq": {"s": pytest.approx(80 * us),
+                                             "calls": 2}}
+    rows = {(r["op"], r["bytes"]): r for r in s["collectives"]}
+    sync = rows[("all-reduce", 4096)]
+    assert sync["calls"] == 1
+    assert sync["device_s"] == sync["exposed_s"] == pytest.approx(50 * us)
+    asyn = rows[("all-reduce", 2048)]
+    assert asyn["device_s"] == pytest.approx(80 * us)
+    assert asyn["exposed_s"] == pytest.approx(20 * us)
+    assert s["step_runs"] == 1
+
+
+def test_clock_map_on_synthetic_pairs():
+    """Ring spans (perf_counter s) against their annotations (profiler
+    ns): the offset is the median difference; a name whose counts differ
+    on the two sides is left out; the StepTraceAnnotation is no pair."""
+    off = 7_000_000_000.0
+    ring = [("step", 1.0, 1.5, 1, 1, 0, {}),
+            ("step.execute", 1.1, 1.4, 1, 2, 1, {}),
+            ("step", 2.0, 2.5, 1, 3, 0, {}),
+            ("step.execute", 2.1, 2.4, 1, 4, 3, {}),
+            ("data.fetch", 1.2, 1.3, 2, 5, 0, {}),
+            ("data.fetch", 9.0, 9.1, 2, 6, 0, {})]   # after the window
+    host = [["hvd_step", 1.0e9 + off + 100, 5e8, None],
+            ["hvd_step.execute", 1.1e9 + off - 100, 3e8, None],
+            ["hvd_step", 1.1e9 + off, 3e8, 7],      # StepTraceAnnotation
+            ["hvd_step", 2.0e9 + off + 300, 5e8, None],
+            ["hvd_step.execute", 2.1e9 + off, 3e8, None],
+            ["hvd_step", 2.1e9 + off, 3e8, 8],
+            ["hvd_data.fetch", 1.2e9 + off, 1e8, None]]
+    cm = clock_map(ring, host, window=(0.5, 3.0))
+    assert cm["pairs"] == 5
+    assert cm["offset_ns"] == pytest.approx(off, abs=1.0)
+    assert cm["spread_ns"] == pytest.approx(400.0, abs=1.0)
+    # without the window the two data.fetch spans meet one annotation:
+    # the name is left out, the others still pair
+    assert clock_map(ring, host)["pairs"] == 4
+    assert clock_map(ring, []) is None and clock_map([], host) is None
+    # host self time and the skew bound ride the same spans
+    s = summarize(
+        {"lanes": {"l0": _lane([("dot.1", 0, 10)],
+                               modules=[("jit_step", 8_100_200, 100),
+                                        ("jit_step", 9_100_050, 100)])},
+         "host": host, "files": []},
+        build_op_table(SYNTH_HLO), ring, (0.5, 3.0))
+    assert s["host"]["hvd_step"] == pytest.approx(2 * (0.5 - 0.3))
+    assert s["host"]["hvd_step.execute"] == pytest.approx(0.6)
+    assert s["clock"]["pairs"] == 5
+    # device starts 200 us and 50 us after the mapped step.execute starts
+    assert s["clock"]["host_device_skew_bound_us"] == pytest.approx(
+        50.0, abs=0.01)
+    # idle gaps: filed under the innermost span open at their middle,
+    # unless shorter than the skew bound
+    ops = [("dot.1", 8_100_200, 100),      # runs inside step.execute 1
+           ("dot.1", 8_100_330, 100),      # 30 us later: under the bound
+           ("dot.1", 8_300_000, 100),      # 199,570 us later, still inside
+           ("dot.1", 8_700_000, 100)]      # the gap's middle: no span open
+    s = summarize({"lanes": {"l0": _lane(ops, modules=[
+        ("jit_step", 8_100_200, 100), ("jit_step", 9_100_050, 100)])},
+        "host": host, "files": []},
+        build_op_table(SYNTH_HLO), ring, (0.5, 3.0))
+    by = s["idle"]["by"]
+    assert by["under_skew_bound"] == pytest.approx(30e-6, rel=1e-3)
+    assert by["hvd_step.execute"] == pytest.approx(199_570e-6, rel=1e-3)
+    assert by["no_span"] == pytest.approx(399_900e-6, rel=1e-3)
+    assert s["idle"]["idle_s"] == pytest.approx(sum(by.values()))
+    # without a clock nothing can be given to a span
+    s = summarize({"lanes": {"l0": _lane(ops)}, "host": [], "files": []})
+    assert set(s["idle"]["by"]) == {"unmapped"}
+
+
+def test_reader_on_recorded_v5e_trace_agrees_with_benchmark_reducer(
+        tmp_path):
+    """The xplane reader and the reduction on the v5e recording kept
+    under benchmark/tests/data agree with the benchmark's own reducer
+    (benchmark/lib/trace_reduce.py) per phase to rounding."""
+    from benchmark.lib import trace_reduce as tr
+    cap = tmp_path / "plugins" / "profile" / "t"
+    cap.mkdir(parents=True)
+    shutil.copy(os.path.join(RECORDED, "tiny_step.xplane.pb"), cap)
+    with open(os.path.join(RECORDED, "tiny_step.hlo.txt"),
+              encoding="utf-8") as f:
+        hlo = f.read()
+    events = read_capture(str(tmp_path))
+    lane = events["lanes"]["tpu:0"]
+    assert len(lane["modules"]) == 3 and lane["modules"][0][0] == "jit_step"
+    # instruction names parsed from the events' HLO text
+    assert any(op[0] == "convolution_tanh_fusion" for op in lane["ops"])
+    s = summarize(events, build_op_table(hlo))
+    red = tr.reduce_trace(tr.read_xplane(str(cap / "tiny_step.xplane.pb")),
+                          tr.scope_map(hlo), drop_first=0)
+    for phase, pattern in (
+            ("forward", "^(?!.*hvd_backward).*hvd_forward"),
+            ("backward", "hvd_backward"),
+            ("optimizer", "^(?!.*hvd_exchange).*hvd_optimizer"),
+            ("other", "^(?!.*hvd_)")):
+        want = tr.select(red, "scope", pattern)["0"] * 1e-9
+        assert s["phases"][phase] == pytest.approx(want, rel=1e-9)
+    assert s["step_runs"] == 3 and s["lanes"] == 1
+    # the recording's Pallas kernel predates the kernel names: it is
+    # filed under its instruction's
+    assert s["kernels"]["hvd_forward.1"]["calls"] == 3
 
 
 def test_tick_owner_locking_and_window(monkeypatch, tmp_path):
-    monkeypatch.setattr(jax.profiler, "start_trace", lambda d: None)
+    started = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda d, **kw: started.append(kw))
     monkeypatch.setattr(jax.profiler, "stop_trace", lambda: None)
     tr = StepTracer(diag_dir=str(tmp_path))
     a, b = object(), object()
-    tr.tick(owner=a)  # not armed: pure no-op
-    assert not tr.active and tr.captures == 0
+    drains = []
+    tr.tick(owner=a, drain=lambda: drains.append("idle"))  # not armed
+    assert not tr.active and tr.captures == 0 and not drains
     tr.arm(2)
-    tr.tick(owner=a)  # first tick starts the window
-    assert tr.active
+    tr.tick(owner=a, drain=lambda: drains.append("start"))
+    assert tr.active and drains == ["start"]
+    # the python tracer is off in the capture's options
+    assert started[0]["profiler_options"].python_tracer_level == 0
     tr.tick(owner=b)  # foreign ticker: owner lock ignores it
     assert tr._seen == 0
-    tr.tick(owner=a)
-    assert tr._seen == 1 and tr.active
-    tr.tick(owner=a)  # second counted step closes the window
+    tr.tick(owner=a, drain=lambda: drains.append("mid"))
+    assert tr._seen == 1 and tr.active and drains == ["start"]
+    tr.tick(owner=a, drain=lambda: drains.append("stop"))
     assert not tr.active and tr.captures == 1
-    # empty capture dir parses to None, recorded as a summary-less window
+    assert drains == ["start", "stop"]   # only at the capture's two ends
+    # empty capture dir reduces to None, recorded as a summary-less window
     assert tr.last_summary is None
     meta = xla_trace.load_meta(tr.last_dir)
     assert meta["steps"] == 2 and meta["summary"] is None
@@ -166,6 +325,14 @@ def test_trace_steps_compiled_end_to_end(hvd_init, tmp_path):
         # device-busy time per lane fits inside the capture wall window
         # (generous bound: CPU trace timestamps are coarse)
         assert s["total_s"] / s["lanes"] <= meta["wall_elapsed_s"] * 1.5
+        # the gradient all-reduce is in the collectives, by message size
+        assert any(r["op"] == "all-reduce" and r["bytes"] > 0
+                   for r in s["collectives"])
+        # the program's own spans: in the window's host self time, and
+        # paired with their annotations for the clock
+        assert s["host"]["hvd_step.execute"] > 0.0
+        assert s["clock"]["pairs"] >= 4
+        assert meta["mono_start"] > 0.0
         snap = hvd.metrics_snapshot()
         caps = snap["hvd_xla_trace_captures_total"]["values"].get("", 0.0)
         assert caps >= 1.0
@@ -173,6 +340,11 @@ def test_trace_steps_compiled_end_to_end(hvd_init, tmp_path):
         assert phases['phase="exchange"'] > 0.0
         flops = snap["hvd_step_flops_total"]["values"].get("", 0.0)
         assert flops > 0.0 and step.flops_per_step > 0.0
+        # the collectives reach the per-collective profile (profiler.txt)
+        # under a label of their own, with device time
+        stats = hvd.state().stats
+        assert stats.counter("allreduce_xla") >= 1
+        assert stats.total_time_us("allreduce_xla") >= 0
     finally:
         xla_trace.uninstall()
 
@@ -204,25 +376,43 @@ def test_env_knob_installs_armed_tracer(monkeypatch, tmp_path):
     assert xla_trace.get() is None
 
 
+def _recorded_capture(tdir):
+    cap = tdir / "plugins" / "profile" / "t"
+    cap.mkdir(parents=True)
+    shutil.copy(os.path.join(RECORDED, "tiny_step.xplane.pb"), cap)
+    with open(os.path.join(RECORDED, "tiny_step.hlo.txt"),
+              encoding="utf-8") as f:
+        return build_op_table(f.read())
+
+
 def test_cli_xla_trace_merge(tmp_path, capsys):
+    """The merger lays the device events of a capture on the flight
+    dumps' wall clock through the sidecar's clock mapping: profiler ns
+    -> perf_counter (summary.clock.offset_ns) -> wall (mono_start /
+    wall_start)."""
     from horovod_tpu.diag.__main__ import main
     tdir = tmp_path / "xla-trace-001"
-    _write_capture(str(tdir), [
-        _xev("dot.1", 100, ts=1000), _xev("add.2", 50, ts=1200)])
-    summary = {"phases": {"forward": 100e-6, "backward": 0.0,
-                          "exchange": 50e-6, "optimizer": 0.0,
-                          "guard": 0.0, "other": 0.0},
-               "stages": {"ici": 0.0, "dcn": 0.0}, "total_s": 150e-6,
-               "events": 2, "lanes": 1, "ts_min_us": 1000,
-               "ts_max_us": 1250, "files": []}
+    table = _recorded_capture(tdir)
+    summary = summarize(read_capture(str(tdir)), table)
+    # the recording's first device op starts at 46,179,537 ns on the
+    # profiler's clock; say that instant was perf_counter 50.0 s, and
+    # that the capture started at perf_counter 49.9 s = wall 100.0 s
+    summary["clock"] = {"offset_ns": 46_179_537.0 - 50.0e9, "pairs": 12,
+                        "spread_ns": 900.0,
+                        "host_device_skew_bound_us": 271.0}
     (tdir / xla_trace.META_FILENAME).write_text(json.dumps(
-        {"version": 1, "rank": 0, "steps": 2, "wall_start": 100.0,
-         "wall_stop": 101.0, "wall_elapsed_s": 1.0, "summary": summary,
-         "op_phases": {"dot.1": ["forward", None],
-                       "add.2": ["exchange", None]}}))
+        {"version": 2, "rank": 0, "steps": 3, "wall_start": 100.0,
+         "wall_stop": 101.0, "wall_elapsed_s": 1.0, "mono_start": 49.9,
+         "summary": summary,
+         "op_phases": {k: [phase_of_op_name(v[2]), None]
+                       for k, v in table.items() if v[2]}}))
     (tmp_path / "flight-rank0.json").write_text(json.dumps(
-        {"rank": 0, "events": [{"seq": 0, "t": 0.0, "wall": 100.2,
-                                "ev": "step", "dt": 0.1, "step": 1}]}))
+        {"rank": 0, "events": [
+            {"seq": 0, "t": 50.0, "wall": 100.2, "ev": "step", "dt": 0.1,
+             "step": 1},
+            {"seq": 1, "t": 50.0, "wall": 100.1, "ev": "span",
+             "name": "step.execute", "t0": 49.95, "tid": 1, "id": 2,
+             "parent": 1}]}))
     merged = tmp_path / "merged.json"
     rep_path = tmp_path / "report.json"
     rc = main([str(tmp_path), "--xla-trace", str(tdir),
@@ -230,22 +420,32 @@ def test_cli_xla_trace_merge(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "forward=" in out and "exchange=" in out and "optimizer=" in out
+    assert "kernels ms/step/lane" in out and "12 span pairs" in out
     rep = json.loads(rep_path.read_text())
-    assert rep["xla"]["phases"]["exchange"] > 0.0
+    assert rep["xla"]["phases"]["forward"] > 0.0
     assert rep["xla"]["aligned"] is True
     doc = json.loads(merged.read_text())
     evs = doc["traceEvents"] if isinstance(doc, dict) else doc
-    xla_evs = [e for e in evs if e.get("cat") in ("forward", "exchange")]
-    assert len(xla_evs) == 2
-    assert all(e["ts"] >= 0 for e in xla_evs)
+    xla_evs = [e for e in evs if e.get("cat") in
+               ("forward", "backward", "optimizer", "other")]
+    assert len(xla_evs) == 45
     # the device events landed phase-labeled, joined via the sidecar map
-    assert {e["cat"] for e in xla_evs} == {"forward", "exchange"}
+    assert {"forward", "backward", "optimizer"} <= {e["cat"]
+                                                    for e in xla_evs}
+    # t=0 of the merged trace is the earliest start: the span's, at wall
+    # 100.05 s; the first device op ran at wall 100.1 s, 50 ms later
+    assert min(e["ts"] for e in xla_evs) == pytest.approx(50_000, abs=2)
+    span = [e for e in evs if e.get("cat") == "span"]
+    assert len(span) == 1 and span[0]["dur"] == pytest.approx(50_000, abs=2)
 
 
 def test_cli_xla_trace_without_flight_dumps(tmp_path, capsys):
+    """No sidecar: the capture is re-reduced (everything 'other' without
+    the HLO) and reported as not clock-aligned."""
     from horovod_tpu.diag.__main__ import main
     tdir = tmp_path / "xla-trace-001"
-    _write_capture(str(tdir), [_xev("dot.1", 10, ts=0)])
+    _recorded_capture(tdir)
     rc = main([str(tmp_path), "--xla-trace", str(tdir)])
     assert rc == 0
-    assert "xla device trace" in capsys.readouterr().out
+    out = capsys.readouterr().out
+    assert "xla device trace" in out and "not clock-aligned" in out
