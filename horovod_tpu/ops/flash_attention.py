@@ -10,8 +10,29 @@ streams the opposing side's blocks sequentially (TPU grids execute in
 order on a core), with the online-softmax state (running max m, normalizer
 l, accumulator acc) held in VMEM scratch that persists across the inner
 axis. Block-level causal pruning wraps each body in ``pl.when``: pruned
-cells do no compute. q/k tiles hit the MXU via ``jnp.dot`` with f32
-accumulation; everything else stays on the VPU.
+cells do no compute.
+
+The forward's tile body (``_tile_scores`` / ``_softmax_update``, shared
+with the band forward):
+
+- *State in one layout.* m and l are (block, 128) f32, every lane of a
+  row holding the same value (as in JAX's own TPU flash kernel). The row
+  max and row sum keep their dimension and broadcast into that state;
+  ``s - m`` and the rescale of acc read it back as whole-vreg copies
+  (``_lanes``). A rank-1 (block,) statistic would lie along lanes and
+  cost a lane <-> sublane re-layout at every use, five or six per tile;
+  the only rank-1 value left is the lse row written once per query row.
+- *Operands at the input type.* q (scaled in f32, rounded back once), k
+  and v go to the MXU in the type they arrive in (bf16 in one pass; f32
+  is not narrowed), k contracted over its last dimension without a
+  materialised transpose, p cast to v's type for the second matmul; both
+  accumulate in f32, and m, l, acc and lse are f32 throughout. For bf16
+  these are the scores the backward kernels recompute: their f32
+  ``q * scale`` crosses the MXU rounded to bf16 as well.
+
+Every live causal tile runs the mask; the backward kernels still cast
+their tiles to f32 (PERF.md section 5 has the per-tile costs of all
+three, section 6 what masking only the edge tiles measured).
 
 Backward (FlashAttention-2 style): the forward additionally saves the
 per-row log-sum-exp L = m + log(l); the backward recomputes P = exp(S - L)
@@ -60,6 +81,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+_LANES = 128  # minor dimension of the forward's softmax state: one vreg row
 
 
 def _window_blocks(window, block):
@@ -69,6 +91,79 @@ def _window_blocks(window, block):
     return -(-window // block)
 
 
+def _tile_mask(off, qi, kj, block, window):
+    """(block, block) causal keep-mask of tile (qi, kj): q row r sits at
+    global position off + qi*block + r relative to the kv origin — 0 for
+    the static kernels, a traced SMEM scalar for a band tile."""
+    q_pos = off + qi * block + jax.lax.broadcasted_iota(
+        jnp.int32, (block, 1), 0)
+    k_pos = kj * block + jax.lax.broadcasted_iota(
+        jnp.int32, (1, block), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    return keep
+
+
+def _tile_scores(q_ref, k_ref, scale):
+    """(block_q, block_k) f32 scores of one tile. q and k cross the MXU in
+    the type they arrive in (bf16 in one pass, f32 unnarrowed), contracted
+    over their last dimension with no transpose materialised, accumulated
+    in f32. The scale goes on q, in f32 and rounded back to q's type once:
+    for bf16 that is what the MXU makes of the backward kernels' f32
+    ``q * scale``, so forward and backward see the same scores."""
+    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+    return jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _lanes(x, n):
+    """The lane-replicated (rows, _LANES) statistic ``x`` as an operand
+    against (rows, n): whole-vreg copies where n is a multiple of _LANES
+    (the tile path: no lane broadcast at all), else its first column
+    (blocks and head sizes off the 128 lanes)."""
+    if n % _LANES:
+        return x[:, :1]
+    return x if n == _LANES else jnp.tile(x, (1, n // _LANES))
+
+
+def _softmax_init(m_scr, l_scr, acc_scr):
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+
+def _softmax_update(s, v_ref, m_scr, l_scr, acc_scr):
+    """One online-softmax step over the (masked) f32 scores ``s`` of a
+    tile. The running max and normaliser are (block, _LANES) f32, every
+    lane of a row holding the same value; the row reductions keep their
+    dimension and broadcast into them, so no value changes between the
+    lane and the sublane layout per tile (see :func:`_lanes`)."""
+    m_prev = m_scr[...]
+    m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_next)
+    p = jnp.exp(s - _lanes(m_next, s.shape[-1]))
+    m_scr[...] = m_next
+    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[0]
+    acc_scr[...] = acc_scr[...] * _lanes(alpha, v.shape[-1]) + jnp.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+
+def _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
+    l = jnp.maximum(l_scr[...], 1e-30)
+    o_ref[0] = (acc_scr[...] / _lanes(l, acc_scr.shape[-1])).astype(
+        o_ref.dtype)
+    # the one rank-1 value: a row of lse per query block, once per row
+    lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+
+
+def _softmax_scratch(block, d):
+    return [pltpu.VMEM((block, _LANES), jnp.float32),
+            pltpu.VMEM((block, _LANES), jnp.float32),
+            pltpu.VMEM((block, d), jnp.float32)]
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
                 *, block, num_kv, scale, causal, window=None):
     qi = pl.program_id(1)
@@ -76,9 +171,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     # Causal block pruning: kv blocks strictly above the diagonal
     # contribute nothing — skip their compute entirely. A sliding window
@@ -90,36 +183,16 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
 
     @pl.when(live)
     def _body():
-        q = q_ref[0].astype(jnp.float32) * scale      # (block, D)
-        k = k_ref[0].astype(jnp.float32)              # (block, D)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
+        s = _tile_scores(q_ref, k_ref, scale)
         if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0)
-            k_pos = kj * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep = jnp.logical_and(keep, q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
-        m = m_scr[...]
-        bm = jnp.max(s, axis=-1)
-        new_m = jnp.maximum(m, bm)
-        p = jnp.exp(s - new_m[:, None])
-        alpha = jnp.exp(m - new_m)
-        m_scr[...] = new_m
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+            s = jnp.where(_tile_mask(0, qi, kj, block, window), s, NEG_INF)
+        _softmax_update(s, v_ref, m_scr, l_scr, acc_scr)
 
     last = qi if causal else num_kv - 1
 
     @pl.when(kj == last)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+        _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
@@ -215,19 +288,6 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[0] = dv_scr[...].astype(dv_ref.dtype)
 
 
-def _band_mask(off, qi, kj, block, window):
-    """(block, block) keep-mask for a band tile: q row r sits at global
-    position off + qi*block + r relative to the kv tile origin."""
-    q_pos = off + qi * block + jax.lax.broadcasted_iota(
-        jnp.int32, (block, 1), 0)
-    k_pos = kj * block + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block), 1)
-    keep = q_pos >= k_pos
-    if window is not None:
-        keep = jnp.logical_and(keep, q_pos - k_pos < window)
-    return keep
-
-
 def _band_live(off, qi, kj, block, window):
     """Block-level pruning for a band tile: live iff some (q, k) pair has
     0 <= q_pos - k_pos [< window]. off is a traced SMEM scalar."""
@@ -252,32 +312,17 @@ def _band_fwd_kernel(off_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
     @pl.when(kj == 0)
     def _init():
-        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        acc_scr[...] = jnp.zeros_like(acc_scr)
+        _softmax_init(m_scr, l_scr, acc_scr)
 
     @pl.when(_band_live(off, qi, kj, block, window))
     def _body():
-        q = q_ref[0].astype(jnp.float32) * scale
-        k = k_ref[0].astype(jnp.float32)
-        v = v_ref[0].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = jnp.where(_band_mask(off, qi, kj, block, window), s, NEG_INF)
-        m = m_scr[...]
-        bm = jnp.max(s, axis=-1)
-        new_m = jnp.maximum(m, bm)
-        p = jnp.exp(s - new_m[:, None])
-        alpha = jnp.exp(m - new_m)
-        m_scr[...] = new_m
-        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, axis=-1)
-        acc_scr[...] = acc_scr[...] * alpha[:, None] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32)
+        s = _tile_scores(q_ref, k_ref, scale)
+        s = jnp.where(_tile_mask(off, qi, kj, block, window), s, NEG_INF)
+        _softmax_update(s, v_ref, m_scr, l_scr, acc_scr)
 
     @pl.when(kj == num_kv - 1)
     def _finalize():
-        l = jnp.maximum(l_scr[...], 1e-30)
-        o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
-        lse_ref[0, 0] = m_scr[...] + jnp.log(l)
+        _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
 
 
 def _band_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
@@ -303,7 +348,7 @@ def _band_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = jnp.where(_band_mask(off, qi, kj, block, window), s, NEG_INF)
+        s = jnp.where(_tile_mask(off, qi, kj, block, window), s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
@@ -335,7 +380,7 @@ def _band_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
         lse = lse_ref[0, 0]
         delta = delta_ref[0, 0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-        s = jnp.where(_band_mask(off, qi, ki, block, window), s, NEG_INF)
+        s = jnp.where(_tile_mask(off, qi, ki, block, window), s, NEG_INF)
         p = jnp.exp(s - lse[:, None])
         dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
@@ -499,11 +544,7 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block, d), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(block, d),
         interpret=interpret,
     )(qs, ks, vs)
     return _from_slab(out, b, h), lse
@@ -737,11 +778,7 @@ def _band_tile_fwd(q, k, v, off, window, block_size, interpret):
             jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
-        scratch_shapes=[
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block,), jnp.float32),
-            pltpu.VMEM((block, d), jnp.float32),
-        ],
+        scratch_shapes=_softmax_scratch(block, d),
         interpret=interpret,
     )(off_arr, qs, ks, vs)
     return _from_slab(out, b, h), lse.reshape(b, h, s)

@@ -10,11 +10,12 @@ from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.parallel.ring_attention import dense_attention
 
 
-def _dense_with_lse(q, k, v, causal):
-    """Unfused attention that also returns the per-row log-sum-exp —
+def _dense_with_lse(q, k, v, causal, window=None):
+    """Unfused f32 attention that also returns the per-row log-sum-exp —
     the numerics reference for flash_attention_with_lse."""
     from horovod_tpu.parallel.ring_attention import _tile_fwd_math
-    return _tile_fwd_math(q, k, v, 0, causal, None,
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    return _tile_fwd_math(q, k, v, 0, causal, window,
                           1.0 / (q.shape[3] ** 0.5))
 
 
@@ -445,3 +446,42 @@ def test_flash_noncausal_ragged_raises_not_dense(hvd_init):
         flash_attention_with_lse(q, q, q, False, 128, True)
     with pytest.raises(ValueError, match="no 128-multiple block"):
         _band_tile_fwd(q, q, q, jnp.int32(200), None, 128, True)
+
+
+# --- the forward's tile body: interior, edge and dead tiles ---------------
+
+@pytest.mark.parametrize("dtype,out_tol,lse_tol", [
+    (jnp.float32, 2e-5, 2e-5),
+    # bf16 operands cross the matmuls as they arrive (f32 accumulation,
+    # f32 statistics): q * scale and p are rounded to bf16 once each
+    (jnp.bfloat16, 2e-2, 2e-2),
+])
+@pytest.mark.parametrize("S,heads,kv_heads,window", [
+    (1024, 2, 2, 300),      # MHA: unmasked, diagonal, far-edge and dead tiles
+    (1024, 4, 2, 300),      # GQA over the same grid
+    (1024, 2, 2, 129),      # one past a block: a live tile with no kept pair
+    (1024, 2, 2, None),     # no window: unmasked + diagonal + dead
+    (1000, 4, 2, 300),      # ragged: padded to 1024, sliced back
+])
+def test_flash_forward_output_and_lse(hvd_init, S, heads, kv_heads,
+                                      window, dtype, out_tol, lse_tol):
+    """Forward output AND lse against dense attention on a grid that holds
+    every kind of tile (block 128: 8x8 tiles; window 300 reaches three
+    blocks back and cuts through the third)."""
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(31), 3)
+    q = jax.random.normal(kq, (1, S, heads, 32), jnp.float32)
+    k = jax.random.normal(kk, (1, S, kv_heads, 32), jnp.float32)
+    v = jax.random.normal(kv, (1, S, kv_heads, 32), jnp.float32)
+    q, k, v = (x.astype(dtype) for x in (q, k, v))
+    # f32 math on the same (rounded) inputs
+    ref_out, ref_lse = _dense_with_lse(q, k, v, True, window)
+    out, lse = flash_attention_with_lse(q, k, v, True, 128, True, window)
+    assert out.dtype == dtype and lse.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref_out), atol=out_tol)
+    np.testing.assert_allclose(np.asarray(lse), np.asarray(ref_lse),
+                               atol=lse_tol)
+    plain = flash_attention(q, k, v, True, 128, True, window)
+    np.testing.assert_array_equal(np.asarray(plain, dtype=np.float32),
+                                  np.asarray(out, dtype=np.float32))
