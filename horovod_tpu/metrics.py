@@ -666,15 +666,29 @@ WIRE_STAGE_SECONDS = _registry.histogram(
 # "Expert-parallel MoE")
 MOE_ROUTED_TOKENS = _registry.counter(
     "hvd_moe_routed_tokens_total",
-    "Token-slot assignments the capacity router kept (landed in an "
-    "expert's capacity buffer), summed over observed steps on this "
-    "rank's shard.")
+    "Token-slot assignments routed to an expert on this rank: those the "
+    "capacity router kept (landed in an expert's capacity buffer), or "
+    "every assignment the dropless layer sent to the experts held, "
+    "summed over observed steps.")
 MOE_DROPPED_TOKENS = _registry.counter(
     "hvd_moe_dropped_tokens_total",
     "Token-slot assignments lost to expert capacity overflow (the "
     "residual path carries the token instead); a high ratio against "
     "hvd_moe_routed_tokens_total means capacity_factor is too low "
     "(docs/troubleshooting.md \"my MoE step drops too many tokens\").")
+MOE_UNROUTED_TOKENS = _registry.counter(
+    "hvd_moe_unrouted_tokens_total",
+    "Tokens none of whose top-k experts is held on this chip, summed "
+    "over the dropless sparse layers and observed steps (models/moe.py "
+    "moe_dropless, a chip's share of the experts): their routed part "
+    "comes from other chips, only the shared expert runs here.")
+MOE_LOAD_MAX_OVER_MEAN = _registry.gauge(
+    "hvd_moe_load_max_over_mean",
+    "Largest held expert's assignment count over the mean of the "
+    "experts held, worst dropless sparse layer of the most recent "
+    "observed step; 1 = even routing. The grouped matmuls' rows follow "
+    "the loads, so this is the step's straggler factor, and past 2x the "
+    "usual total a second chunk of rows runs (moe.chunk_rows).")
 MOE_LOAD_BALANCE_LOSS = _registry.gauge(
     "hvd_moe_load_balance_loss",
     "Most recent Switch load-balancing aux loss (E * sum over experts "
@@ -724,6 +738,20 @@ def record_moe_step(routed, dropped, load_balance_loss, chunks):
     MOE_DROPPED_TOKENS.inc(float(dropped))
     MOE_LOAD_BALANCE_LOSS.set(float(load_balance_loss))
     MOE_CHUNKS.set(int(chunks))
+
+
+def record_moe_routing(stats):
+    """Host-side per-step accounting of the dropless sparse layers: feed
+    the hvd_moe_* families from the fetched aux of a compiled step whose
+    loss is ``transformer.loss_and_stats`` (``expert_load`` (layers,
+    experts held), ``unrouted_tokens`` (layers,)). Nothing is dropped, so
+    hvd_moe_dropped_tokens_total does not move."""
+    load = [[float(n) for n in layer] for layer in stats["expert_load"]]
+    MOE_ROUTED_TOKENS.inc(sum(map(sum, load)))
+    MOE_UNROUTED_TOKENS.inc(float(sum(stats["unrouted_tokens"])))
+    MOE_LOAD_MAX_OVER_MEAN.set(max(
+        (max(layer) * len(layer) / sum(layer) for layer in load
+         if sum(layer)), default=1.0))
 
 
 # Inference serving (serve/; docs/serving.md, docs/observability.md

@@ -40,6 +40,35 @@ from ..parallel.ring_attention import dense_attention, ring_attention
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """Rotary embedding of one kind of layer. The defaults are the plain
+    whole-head RoPE every ``positional="rope"`` layer had before layers
+    could differ."""
+    theta: float = 10000.0
+    # leading features of each head that are rotated (pairs i, i +
+    # rotary_dim / 2); None = the whole head
+    rotary_dim: Optional[int] = None
+    # YaRN (arXiv:2309.00071): frequencies below the correction range of
+    # beta_fast / beta_slow at the original length are divided by
+    # yarn_factor, those above are kept, a linear ramp between; cos and
+    # sin are scaled by attention_factor. None = no rescaling.
+    yarn_factor: Optional[float] = None
+    yarn_original_max_seq: Optional[int] = None
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerSpec:
+    """One layer of a model whose layers differ."""
+    n_heads: int                       # query heads (held on this chip)
+    window: Optional[int] = None       # None = full causal attention
+    rope: Optional[RopeSpec] = None    # None = no rotary embedding
+    mlp: str = "dense"                 # "dense" | "sparse" (MoE FFN)
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
     vocab_size: int = 32768
     d_model: int = 512
@@ -99,8 +128,54 @@ class TransformerConfig:
     moe_layers: tuple = ()
     moe_num_experts: int = 4
     moe_top_k: int = 2
+    # --- models whose layers differ, and a chip's share of a layer ---
+    # One LayerSpec per layer (n_layers of them): query heads, window,
+    # rotary embedding and kind of FFN of each. Set, it takes the place
+    # of n_heads / attention_window / moe_layers, which describe one kind
+    # of layer. Training only: the decode, serve and pipeline paths take
+    # one kind of layer.
+    layers: tuple = ()
+    # Width of a head where it is not d_model / n_heads.
+    head_size: Optional[int] = None
+    # Per-head output gate: a_head * sigmoid(h @ wg.T)[head] before wo
+    # (headwise gated attention, arXiv:2505.06708).
+    attn_gate: bool = False
+    # Gated SiLU FFNs, (silu(h w1) * (h w3)) w2, dense and expert alike;
+    # False = the two-matrix GELU FFN.
+    mlp_gated: bool = False
+    # Expert width (None = d_ff), the shared expert's width (0 = none),
+    # and the scale on the renormalised top-k probabilities.
+    moe_d_ff: Optional[int] = None
+    moe_shared_d_ff: int = 0
+    moe_routed_scale: float = 1.0
+    # The share of each layer this chip holds, where a layer is divided
+    # over more chips than the mesh has: n_heads / n_kv_heads / the
+    # LayerSpecs' n_heads and vocab_size count what is HELD, and
+    # moe_experts_held = (first index, count) names the routed experts
+    # held while moe_num_experts stays the router's published width. The
+    # layers compute their part of the result from what is held (a
+    # partial sum over heads before wo, over experts in the FFN) and no
+    # code stands in for the absent chips. Set, the sparse layers are
+    # models/moe.py moe_dropless (no capacity, no ep axis); None = the
+    # capacity layer moe_layer over the mesh's ep axis.
+    moe_experts_held: Optional[tuple] = None
 
     def __post_init__(self):
+        if self.layers:
+            if len(self.layers) != self.n_layers:
+                raise ValueError(
+                    f"layers describes {len(self.layers)} layers, "
+                    f"n_layers is {self.n_layers}")
+            if self.positional != "rope" or self.head_size is None:
+                raise ValueError(
+                    "a per-layer description needs positional='rope' "
+                    "(each LayerSpec carries its rotary embedding or "
+                    "None) and an explicit head_size")
+            if self.n_kv_heads and any(l.n_heads % self.n_kv_heads
+                                       for l in self.layers):
+                raise ValueError(
+                    "every layer's n_heads must be divisible by "
+                    f"n_kv_heads ({self.n_kv_heads})")
         if self.attention_impl not in ("dense", "flash"):
             raise ValueError(
                 f"unknown attention_impl {self.attention_impl!r}; "
@@ -109,7 +184,7 @@ class TransformerConfig:
             raise ValueError(
                 f"unknown sp_impl {self.sp_impl!r}; "
                 "expected 'ring' or 'ulysses'")
-        if self.n_kv_heads is not None \
+        if self.n_kv_heads is not None and not self.layers \
                 and self.n_heads % self.n_kv_heads != 0:
             raise ValueError(
                 f"n_heads ({self.n_heads}) must be divisible by "
@@ -132,15 +207,37 @@ class TransformerConfig:
 
     @property
     def head_dim(self):
-        return self.d_model // self.n_heads
+        return self.head_size or self.d_model // self.n_heads
+
+    def layer_spec(self, i=None):
+        """The description of layer ``i``. ``i=None`` is for the paths
+        that take one kind of layer (decode, serve, pipeline): the common
+        description, or an error where the layers differ."""
+        if self.layers:
+            if i is None:
+                raise ValueError(
+                    "this path takes one kind of layer; the "
+                    "configuration describes its layers one by one "
+                    "(TransformerConfig.layers)")
+            return self.layers[i]
+        return LayerSpec(
+            n_heads=self.n_heads, window=self.attention_window,
+            rope=RopeSpec() if self.positional == "rope" else None,
+            mlp="sparse" if i in self.moe_layers else "dense")
 
     @property
     def moe_cfg(self):
         from .moe import MoEConfig
-        return MoEConfig(d_model=self.d_model, d_ff=self.d_ff,
+        return MoEConfig(d_model=self.d_model,
+                         d_ff=self.moe_d_ff or self.d_ff,
                          num_experts=self.moe_num_experts,
                          top_k=self.moe_top_k, dtype=self.dtype,
-                         param_dtype=self.param_dtype)
+                         param_dtype=self.param_dtype,
+                         experts_held=self.moe_experts_held,
+                         gated=self.mlp_gated,
+                         routed_scale=self.moe_routed_scale,
+                         shared_d_ff=self.moe_shared_d_ff,
+                         interpret=self.flash_interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -158,7 +255,7 @@ def init_params(key, cfg):
     shard_map)."""
     keys = jax.random.split(key, 3 + cfg.n_layers)
     pd = cfg.param_dtype
-    d, h, hd, ff = cfg.d_model, cfg.n_heads, cfg.head_dim, cfg.d_ff
+    d, hd, ff = cfg.d_model, cfg.head_dim, cfg.d_ff
 
     def dense(k, shape, fan_in):
         return (jax.random.normal(k, shape, pd) / math.sqrt(fan_in))
@@ -166,6 +263,8 @@ def init_params(key, cfg):
     layers = []
     for i in range(cfg.n_layers):
         lk = jax.random.split(keys[3 + i], 4)
+        spec = cfg.layer_spec(i)
+        h = spec.n_heads
         layer = {
             "ln1": jnp.ones((d,), pd),
             "wo": dense(lk[1], (h, hd, d), d),
@@ -178,12 +277,19 @@ def init_params(key, cfg):
             layer["wkv"] = dense(qk[1], (d, 2, h_kv, hd), d)
         else:
             layer["wqkv"] = dense(lk[0], (d, 3, h, hd), d)
-        if i in cfg.moe_layers:
+        if cfg.attn_gate:
+            # (heads, d_model): a minor dimension of 6 or 9 heads would
+            # be padded to 128 lanes wherever XLA keeps the leaf 2-D
+            layer["wg"] = dense(jax.random.fold_in(lk[1], 1), (h, d), d)
+        if spec.mlp == "sparse":
             from .moe import init_moe_params
             layer["moe"] = init_moe_params(lk[2], cfg.moe_cfg)
         else:
             layer["w1"] = dense(lk[2], (d, ff), d)
             layer["w2"] = dense(lk[3], (ff, d), ff)
+            if cfg.mlp_gated:
+                layer["w3"] = dense(jax.random.fold_in(lk[2], 1), (d, ff),
+                                    d)
         layers.append(layer)
     out = {
         "embed": dense(keys[0], (cfg.vocab_size, d), d),
@@ -201,25 +307,32 @@ def param_specs(cfg, axes=ShardAxes()):
     their expert slices over the ep axis, models/moe.py:moe_specs)."""
     from jax.sharding import PartitionSpec as P
 
-    from .moe import moe_specs
+    from .moe import dropless_specs, moe_specs
     tp = axes.tp
     layers = []
     for i in range(cfg.n_layers):
+        spec = cfg.layer_spec(i)
         layer = {
             "ln1": P(),
             "wo": P(tp, None, None),           # row-parallel (psum after)
             "ln2": P(),
         }
-        if cfg.n_kv_heads is not None and cfg.n_kv_heads != cfg.n_heads:
+        if cfg.n_kv_heads is not None and cfg.n_kv_heads != spec.n_heads:
             layer["wq"] = P(None, tp, None)        # q heads sharded
             layer["wkv"] = P(None, None, tp, None)  # kv heads sharded
         else:
             layer["wqkv"] = P(None, None, tp, None)  # heads sharded
-        if i in cfg.moe_layers:
-            layer["moe"] = moe_specs(axes.ep)
+        if cfg.attn_gate:
+            layer["wg"] = P(tp, None)          # one gate per q head
+        if spec.mlp == "sparse":
+            layer["moe"] = (moe_specs(axes.ep)
+                            if cfg.moe_experts_held is None
+                            else dropless_specs(cfg.moe_cfg))
         else:
             layer["w1"] = P(None, tp)          # column-parallel
             layer["w2"] = P(tp, None)          # row-parallel (psum after)
+            if cfg.mlp_gated:
+                layer["w3"] = P(None, tp)      # column-parallel gate
         layers.append(layer)
     out = {
         "embed": P(tp, None),              # vocab-parallel
@@ -306,6 +419,48 @@ def _rope(x, positions, theta=10000.0):
     x1, x2 = x[..., :half], x[..., half:]
     out = jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
+    return out.astype(x.dtype)
+
+
+def rope_inv_freq(spec, head_dim):
+    """Rotation frequencies (numpy float64, ``rotary_dim / 2`` of them) of
+    a :class:`RopeSpec`: ``theta ** (-2 i / rotary_dim)``, under YaRN
+    blended with the same divided by ``yarn_factor``."""
+    import numpy as np
+    rot = spec.rotary_dim or head_dim
+    inv = spec.theta ** (-np.arange(0, rot, 2, dtype=np.float64) / rot)
+    if spec.yarn_factor is None:
+        return inv
+
+    def correction_dim(rotations):
+        return rot * math.log(spec.yarn_original_max_seq
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(spec.theta))
+
+    low = max(math.floor(correction_dim(spec.yarn_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(spec.yarn_beta_slow)), rot - 1)
+    ramp = np.clip((np.arange(rot // 2, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0, 1)
+    return inv / spec.yarn_factor * ramp + inv * (1 - ramp)
+
+
+def _rope_spec(x, positions, spec):
+    """:func:`_rope` as a :class:`RopeSpec` describes it: the plain spec
+    IS :func:`_rope`; otherwise the first ``rotary_dim`` features of each
+    head are rotated at :func:`rope_inv_freq` and the rest pass through."""
+    if spec.rotary_dim is None and spec.yarn_factor is None \
+            and spec.attention_factor == 1.0:
+        return _rope(x, positions, spec.theta)
+    d = x.shape[-1]
+    rot = spec.rotary_dim or d
+    half = rot // 2
+    freqs = jnp.asarray(rope_inv_freq(spec, d), jnp.float32)
+    ang = positions[:, None].astype(jnp.float32) * freqs[None]
+    cos = (jnp.cos(ang) * spec.attention_factor)[None, :, None, :]
+    sin = (jnp.sin(ang) * spec.attention_factor)[None, :, None, :]
+    x1, x2 = x[..., :half], x[..., half:rot]
+    out = jnp.concatenate(
+        [x1 * cos - x2 * sin, x1 * sin + x2 * cos, x[..., rot:]], axis=-1)
     return out.astype(x.dtype)
 
 
@@ -414,27 +569,48 @@ def _qkv_proj(p, h, cfg):
     return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
 
 
-def _attention_block(p, x, cfg, axes):
-    out, _, _ = _attention_block_kv(p, x, cfg, axes)
+def _attention_block(p, x, cfg, axes, spec=None):
+    out, _, _ = _attention_block_kv(p, x, cfg, axes, spec)
     return out
 
 
-def _attention_block_kv(p, x, cfg, axes):
+def _attention_block_kv(p, x, cfg, axes, spec=None):
     """:func:`_attention_block`, also returning the (post-rope) K/V this
     block computed — the serve prefill path (serve/engine.py) scatters
     them into the paged KV cache while keeping the trunk ops literally
     the ones the training forward runs (the prefill-vs-forward bitwise
     parity in tests/test_serving.py depends on this sharing, exactly
-    like test_decode_matches_forward depends on _qkv_proj)."""
+    like test_decode_matches_forward depends on _qkv_proj).
+
+    ``spec`` is this layer's :class:`LayerSpec` (``None``: the one kind
+    of layer the configuration has). The attention itself runs under the
+    device scope ``hvd_attn_window`` or ``hvd_attn_full``."""
+    spec = spec or cfg.layer_spec()
     h = _rmsnorm(x, p["ln1"])
     q, k, v = _qkv_proj(p, h, cfg)
-    if cfg.positional == "rope":
+    if spec.rope is not None:
         s_loc = x.shape[1]
         start = _axis_index(axes.sp) * s_loc
         positions = start + jnp.arange(s_loc)
-        q = _rope(q, positions)
-        k = _rope(k, positions)
-    win = cfg.attention_window
+        q = _rope_spec(q, positions, spec.rope)
+        k = _rope_spec(k, positions, spec.rope)
+    win = spec.window
+    with jax.named_scope("hvd_attn_window" if win else "hvd_attn_full"):
+        attn = _attend(q, k, v, win, cfg, axes)
+    if "wg" in p:
+        gate = jax.nn.sigmoid(jnp.einsum(
+            "bsd,hd->bsh", h, p["wg"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32))
+        attn = attn * gate[..., None].astype(cfg.dtype)
+    out = jnp.einsum("bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
+                     preferred_element_type=jnp.float32)
+    out = _psum(out, axes.tp).astype(cfg.dtype)
+    return x + out, k, v
+
+
+def _attend(q, k, v, win, cfg, axes):
+    """Causal attention of one layer by the configured implementation
+    (flash / dense; ring or ulysses under sp), keys within ``win``."""
     if axes.sp and cfg.sp_impl == "ulysses":
         # ulysses: all-to-all re-shards to (full seq, local heads); the
         # chosen kernel then runs whole over the global sequence (so a
@@ -454,28 +630,23 @@ def _attention_block_kv(p, x, cfg, axes):
                 return dense_attention(qg, kg, vg, causal=causal,
                                        scale=scale, window=win)
 
-        attn = ulysses_attention(q, k, v, axis_name=axes.sp, causal=True,
+        return ulysses_attention(q, k, v, axis_name=axes.sp, causal=True,
                                  attn_fn=attn_fn)
-    elif axes.sp:
+    if axes.sp:
         # ring x flash: the Pallas kernel computes each visiting tile when
         # attention_impl == "flash" (band-offset kernels under a window);
         # partials merge by log-sum-exp. With a window the ring runs
         # 1 + ceil((W-1)/S_local) rotations instead of sp_size — cost
         # follows the window, not the context.
-        attn = ring_attention(q, k, v, axis_name=axes.sp, causal=True,
+        return ring_attention(q, k, v, axis_name=axes.sp, causal=True,
                               impl=cfg.attention_impl,
                               interpret=cfg.flash_interpret,
                               window=win)
-    elif cfg.attention_impl == "flash":
+    if cfg.attention_impl == "flash":
         from ..ops.flash_attention import flash_attention
-        attn = flash_attention(q, k, v, True,
+        return flash_attention(q, k, v, True,
                                interpret=cfg.flash_interpret, window=win)
-    else:
-        attn = dense_attention(q, k, v, causal=True, window=win)
-    out = jnp.einsum("bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
-    out = _psum(out, axes.tp).astype(cfg.dtype)
-    return x + out, k, v
+    return dense_attention(q, k, v, causal=True, window=win)
 
 
 def _mlp_block(p, x, cfg, axes, moe_full_capacity=False):
@@ -487,20 +658,41 @@ def _mlp_block(p, x, cfg, axes, moe_full_capacity=False):
     the batch (continuous batching joins/evicts mid-stream; a capacity
     drop that depended on batch composition would make a sequence's
     tokens change when its neighbors change)."""
+    out, aux, _ = _mlp_block_stats(p, x, cfg, axes, moe_full_capacity)
+    return out, aux
+
+
+def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
+    """:func:`_mlp_block` plus the routing counters of a dropless sparse
+    layer (models/moe.py ``moe_dropless`` ``stats``; ``None`` for every
+    other layer). The parameters pick the FFN: ``moe`` a sparse layer —
+    dropless when the configuration states the experts held, else the
+    capacity layer over ``axes.ep`` —, ``w3`` the gated SiLU FFN."""
     h = _rmsnorm(x, p["ln2"])
+    zero = jnp.zeros((), jnp.float32)
+    if "moe" in p and cfg.moe_experts_held is not None:
+        from .moe import moe_dropless
+        y, stats = moe_dropless(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg)
+        return x + y.astype(cfg.dtype), zero, stats
     if "moe" in p:
         from .moe import moe_layer
         y, aux = moe_layer(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg,
                            ep_axis=axes.ep,
                            full_capacity=moe_full_capacity)
-        return x + y.astype(cfg.dtype), aux
+        return x + y.astype(cfg.dtype), aux, None
     u = jnp.einsum("bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
                    preferred_element_type=jnp.float32)
-    u = jax.nn.gelu(u).astype(cfg.dtype)
-    out = jnp.einsum("bsf,fd->bsd", u, p["w2"].astype(cfg.dtype),
+    if "w3" in p:
+        u = jax.nn.silu(u) * jnp.einsum(
+            "bsd,df->bsf", h, p["w3"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32)
+    else:
+        u = jax.nn.gelu(u)
+    out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
+                     p["w2"].astype(cfg.dtype),
                      preferred_element_type=jnp.float32)
     out = _psum(out, axes.tp).astype(cfg.dtype)
-    return x + out, jnp.zeros((), jnp.float32)
+    return x + out, zero, None
 
 
 MOE_AUX_COEF = 0.01  # Switch-style load-balance coefficient
@@ -508,20 +700,33 @@ MOE_AUX_COEF = 0.01  # Switch-style load-balance coefficient
 
 def trunk_with_aux(params, tokens, cfg, axes=None):
     """Pre-head activations (B, S_loc, d) + total MoE aux loss."""
+    return trunk_with_stats(params, tokens, cfg, axes)[:2]
+
+
+def trunk_with_stats(params, tokens, cfg, axes=None):
+    """:func:`trunk_with_aux` plus the dropless sparse layers' routing
+    counters, stacked over those layers in order (``{}`` when the model
+    has none): ``expert_load`` (layers, experts held), ``unrouted_tokens``
+    (layers,)."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 
-    def one_layer(p, x):
-        x = _attention_block(p, x, cfg, axes)
-        return _mlp_block(p, x, cfg, axes)
+    def one_layer(p, x, spec):
+        x = _attention_block(p, x, cfg, axes, spec)
+        return _mlp_block_stats(p, x, cfg, axes)
 
     if cfg.remat:
-        one_layer = jax.checkpoint(one_layer)
-    for p in params["layers"]:
-        x, aux = one_layer(p, x)
+        one_layer = jax.checkpoint(one_layer, static_argnums=(2,))
+    routing = []
+    for i, p in enumerate(params["layers"]):
+        x, aux, stats = one_layer(p, x, cfg.layer_spec(i))
         aux_total = aux_total + aux
-    return x, aux_total
+        if stats is not None:
+            routing.append(stats)
+    stats = jax.tree.map(lambda *a: jnp.stack(a), *routing) if routing \
+        else {}
+    return x, aux_total, stats
 
 
 def forward_with_aux(params, tokens, cfg, axes=None):
@@ -602,11 +807,20 @@ def _head(params, x, cfg):
 
 def loss_fn(params, tokens, targets, cfg, axes=None):
     """Mean causal-LM cross entropy with vocab-parallel logits (+ the
-    Switch load-balancing aux term when the model has MoE layers).
-    With cfg.loss_chunk set, the head + CE run per sequence chunk and
-    full logits never materialize."""
+    Switch load-balancing aux term when the model has capacity MoE
+    layers). With cfg.loss_chunk set, the head + CE run per sequence chunk
+    and full logits never materialize."""
+    return loss_and_stats(params, tokens, targets, cfg, axes)[0]
+
+
+def loss_and_stats(params, tokens, targets, cfg, axes=None):
+    """``(loss_fn, routing counters)``: the ``has_aux=True`` form of the
+    loss for ``hvd.compiled_train_step``, whose aux then carries
+    :func:`trunk_with_stats`'s per-layer ``expert_load`` and
+    ``unrouted_tokens`` out of the step (feed them to
+    ``hvd.metrics.record_moe_routing``)."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
-    x, aux = trunk_with_aux(params, tokens, cfg, axes)
+    x, aux, stats = trunk_with_stats(params, tokens, cfg, axes)
     # Head matmul + cross entropy under a device name of their own
     # (docs/diagnostics.md: the trace readers' `hvd_head_ce`), forward
     # and backward alike.
@@ -616,7 +830,7 @@ def loss_fn(params, tokens, targets, cfg, axes=None):
         else:
             nll = _cross_entropy(_head(params, x, cfg), targets, axes)
     loss = nll + MOE_AUX_COEF * aux
-    return _pmean(loss, (axes.dp, axes.sp))
+    return _pmean(loss, (axes.dp, axes.sp)), stats
 
 
 def _pipeline_is_mixed(cfg):
@@ -818,6 +1032,7 @@ def _check_pipeline_moe(cfg, num_stages=None, interleave=1):
     refusal). Kind patterns that differ across units (e.g. all the MoE
     layers in the first stage) would need per-stage programs, which SPMD
     cannot express. Returns whether MoE is active."""
+    cfg.layer_spec()  # the stage program takes one kind of attention layer
     if not cfg.moe_layers:
         return False
     if set(cfg.moe_layers) == set(range(cfg.n_layers)):
@@ -975,6 +1190,7 @@ def init_cache(cfg, batch, max_len, axes=None):
     at serving time). With ``axes.tp`` set (inside shard_map), each shard
     caches only its local K/V heads — serving shares training's
     head-sharded layout."""
+    cfg.layer_spec()  # decoding takes one kind of layer
     h_kv = cfg.n_kv_heads or cfg.n_heads
     if axes is not None and axes.tp:
         tp_size = lax.axis_size(axes.tp)

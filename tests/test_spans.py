@@ -260,8 +260,9 @@ def test_kernel_and_head_names_in_the_step_jaxpr():
     # under backward first
     assert any(re.match(r"[^/]*/hvd_forward/.*hvd_flash_fwd", p)
                for p in paths)
+    # (each kernel inside its layer's attention scope, hvd_attn_full here)
     assert any(re.match(r"[^/]*/hvd_backward/.*rematted_computation/"
-                        r"hvd_flash_fwd", p) for p in paths)
+                        r"hvd_attn_full/hvd_flash_fwd", p) for p in paths)
     for name in ("hvd_flash_dq", "hvd_flash_dkv"):
         assert any(re.match(rf"[^/]*/hvd_backward/.*{name}", p)
                    for p in paths)
@@ -270,7 +271,10 @@ def test_kernel_and_head_names_in_the_step_jaxpr():
     from horovod_tpu.diag.xla_trace import phase_of_op_name
     for name in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv",
                  "hvd_flash_band_fwd", "hvd_flash_band_dq",
-                 "hvd_flash_band_dkv", "hvd_head_ce"):
+                 "hvd_flash_band_dkv", "hvd_head_ce", "hvd_attn_full",
+                 "hvd_attn_window", "hvd_gmm", "hvd_moe", "hvd_moe_route",
+                 "hvd_moe_dispatch", "hvd_moe_experts", "hvd_moe_combine",
+                 "hvd_moe_shared"):
         assert phase_of_op_name(f"jit(f)/{name}/x") is None
 
 
