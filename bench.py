@@ -101,8 +101,8 @@ DEVICE_RESIDENT = os.environ.get(
 
 # Bucketed backward/exchange overlap (docs/performance.md "Bucketed
 # backward/exchange overlap"): the compiled profile runs with this tuned
-# bucket count and A/Bs it against buckets=1 (today's single fused
-# exchange). 8 keeps margin above the CI overlap gate's 0.3 floor — the
+# bucket count and A/Bs it against buckets=1 (one psum call over all
+# leaves). 8 keeps margin above the CI overlap gate's 0.3 floor — the
 # PR 13 lesson (moe chunks=4 sat at 0.31 against the same gate).
 EXCHANGE_BUCKETS = max(
     int(os.environ.get("HOROVOD_EXCHANGE_BUCKETS", "8") or 8), 1)
@@ -621,7 +621,7 @@ def _compiled_step_profile(batch_per_chip, n, mesh, model, variables,
     ``exchange_buckets`` tunes the bucketed backward/exchange overlap
     (docs/performance.md "Bucketed backward/exchange overlap"): the
     profile runs at the tuned count, then A/Bs a fresh ``buckets=1``
-    step (today's single fused tail exchange) with the same blocked
+    step (one psum call over all leaves) with the same blocked
     measurement protocol and reports both sides under ``overlap_ab`` —
     the with/without-overlap delta plus each side's trace-measured
     ``exchange_hidden_frac``."""
@@ -719,7 +719,7 @@ def _compiled_step_profile(batch_per_chip, n, mesh, model, variables,
 
     # Overlap A/B (docs/performance.md "Bucketed backward/exchange
     # overlap"): same loss, same blocked per-step protocol on BOTH sides
-    # — buckets=1 (today's single fused tail exchange) vs the tuned
+    # — buckets=1 (one psum call over all leaves) vs the tuned
     # count — so the with/without-overlap delta is apples-to-apples even
     # though the headline loop above paces on PIPELINE_DEPTH. Each side
     # also traces its own exchange_hidden_frac.

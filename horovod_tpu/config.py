@@ -290,14 +290,15 @@ class Config:
     # bit-identical at every setting. Capacity must divide evenly —
     # non-dividing values fall back to the largest divisor below.
     moe_chunks: int = 1
-    # How many layer-ordered buckets the compiled step's fused gradient
-    # exchange is split into (ops/step_program.py): bucket L's psum
-    # dispatches while bucket L-1's backward still computes, hiding wire
-    # time behind backprop inside one donated XLA program. 1 = today's
-    # single fused exchange, bit-identical (the pinned default); every
-    # setting is bit-identical for the exchange itself (per-element
-    # reductions are unaffected by bucket boundaries). docs/performance.md
-    # "Bucketed backward/exchange overlap".
+    # How many layer-ordered buckets the compiled step's gradient
+    # exchange is split into (ops/step_program.py): one psum call over
+    # each bucket's leaves, so bucket L's psum can dispatch while bucket
+    # L-1's backward still computes inside one donated XLA program. 1 =
+    # one psum call over all leaves (the default), where XLA's
+    # all-reduce combiner decides what travels together; every setting
+    # gives the same values (per-element reductions are unaffected by
+    # bucket boundaries). docs/performance.md "Bucketed
+    # backward/exchange overlap".
     exchange_buckets: int = 1
     # Jit-path reduce-scatter/allgather bucket size in bytes
     # (ops/collectives.py bucketed_reducescatter_allgather): the fusion-

@@ -511,11 +511,12 @@ def tree_health(leaves):
     """Per-leaf ``[finite, l2]`` float32 health rows for a list of
     already-exchanged tensors — the :func:`segment_health` analog for
     exchange modes whose reduction happens inside the optimizer
-    transform (ZeRO-1 / inline-chained transforms), where no fused wire
-    row exists for the compiled step program (ops/step_program.py) to
-    digest. Same row layout and fold semantics; computed on values that
-    are bit-identical across ranks (post-allgather updates), so every
-    rank's guard verdict agrees without coordination."""
+    transform (ZeRO-1 / inline-chained transforms), and for the compiled
+    step program's psum over gradient leaves (ops/step_program.py),
+    where no fused wire row exists to digest. Same row layout and fold
+    semantics; computed on values that are bit-identical across ranks
+    (reduced leaves, post-allgather updates), so every rank's guard
+    verdict agrees without coordination."""
     rows = []
     for leaf in leaves:
         x = leaf.reshape(-1).astype(jnp.float32)
@@ -742,12 +743,11 @@ def exchange_bucket_plan(leaves, buckets):
     ordered so the *last* leaves of the tree — produced first by
     backprop — form the first bucket. XLA schedules each bucket's
     collective as soon as its leaves' data dependencies resolve, so the
-    traced order is a hint, not a barrier; what matters is that no
-    bucket waits on the whole tree the way the single fused concat does.
+    traced order is a hint, not a barrier.
 
-    ``buckets=1`` returns the identity plan — all indices, ascending —
-    so the caller's unbucketed path traces in exactly today's order
-    (the bit-identity pin on HOROVOD_EXCHANGE_BUCKETS=1). Byte balancing
+    ``buckets=1`` returns the identity plan — all indices, ascending:
+    one psum call over the whole tree, which XLA's all-reduce combiner
+    splits and schedules as it sees fit. Byte balancing
     is greedy over cumulative equal-bytes boundaries; a cut is forced
     when the leaves remaining would otherwise leave a bucket empty.
     """

@@ -3,7 +3,7 @@
 An eager training step pays per-step Python orchestration and a blocking
 device->host readback that a fully in-graph step does not — the gap is
 orchestration, not the wire. This module closes it by
-compiling the *whole* training step — forward, backward, fused gradient
+compiling the *whole* training step — forward, backward, in-graph gradient
 exchange, optimizer apply, and (opt-in) the guard health matrix — into
 ONE jitted program with donated parameter/optimizer-state buffers, so a
 steady-state step costs one Python dispatch and zero host readbacks
@@ -67,8 +67,7 @@ from ..diag import xla_trace
 from ..diag.recorder import span
 from ..runtime import AXIS
 from ..stats import record_jit_traced
-from .collectives import (_nbytes, exchange_bucket_plan, segment_health,
-                          tree_health, unfuse_segments)
+from .collectives import _nbytes, exchange_bucket_plan, tree_health
 from .compression import Compression
 from .engine import register_wire_program_builder
 
@@ -170,7 +169,7 @@ def _contains_inline_exchange(fn, depth=0):
     """True when ``fn``'s closure (recursively, shallow-bounded) holds a
     transform tagged as exchanging gradients inside its own update — a
     hand-rolled optax.chain around DistributedGradientTransform. The
-    compiled step must not stack its fused psum on top of that."""
+    compiled step must not stack its own psum on top of that."""
     if depth > 4:
         return False
     if getattr(fn, "_hvd_exchange", None) is not None:
@@ -190,32 +189,32 @@ def _contains_inline_exchange(fn, depth=0):
 
 # -------------------------------------------------------- in-graph exchange
 
-def _fused_psum_exchange(grads, axis, average, comp, with_health,
-                         denom=None, buckets=1):
-    """Fused in-graph gradient exchange: flatten the gradient tree into
-    one wire row per wire dtype (compression is the dtype round-trip,
-    ops/compression.py), ONE ``lax.psum`` per row, then
-    ``unfuse_segments`` — identical slice/cast/average arithmetic to the
-    device-resident eager wire program, so the two paths agree within
-    dtype tolerance. Returns ``(exchanged_tree, health)`` where
-    ``health`` (guard builds only) is one ``[finite, l2]`` float32 row
-    per gradient leaf in ORIGINAL leaf order, computed on the reduced
-    pre-average rows via ``segment_health`` — bit-identical across ranks
-    by construction.
+def _psum_exchange(grads, axis, average, comp, with_health,
+                   denom=None, buckets=1):
+    """In-graph gradient exchange: ONE ``lax.psum`` over the tuple of
+    gradient leaves, each at its wire dtype (compression is the dtype
+    round-trip, ops/compression.py), then the per-element finish the
+    device-resident eager wire program gives a segment
+    (``collectives.unfuse_segments``): cast back from the wire dtype,
+    float-divide / integer-floor-divide by the world size when
+    ``average``, cast back — so the two paths agree within dtype
+    tolerance. No flat wire row is built: the leaves go to the psum in
+    their own shapes and XLA's all-reduce combiner decides what travels
+    together; on axes of size 1 the psum is nothing and the gradients
+    flow from the backward straight into the optimizer. Returns
+    ``(exchanged_tree, health)`` where ``health`` (guard builds only) is
+    one ``[finite, l2]`` float32 row per gradient leaf in ORIGINAL leaf
+    order, computed on the reduced pre-average leaves via
+    ``tree_health`` — bit-identical across ranks by construction.
 
     ``buckets > 1`` splits the exchange into that many layer-ordered
-    buckets (``collectives.exchange_bucket_plan``): one concat/psum per
-    (bucket x wire dtype) instead of one per dtype, each traced under
+    buckets (``collectives.exchange_bucket_plan``): one psum call per
+    bucket over that bucket's leaves, each traced under
     ``hvd_exchange_bucket{k}``, the last-produced leaves of backprop
-    first. No bucket's row depends on leaves outside the bucket, so XLA
-    dispatches bucket L's psum while bucket L-1's backward compute is
-    still running — the reference's background-thread overlap, expressed
-    as dataflow inside one donated program. Per-element reduction math is
-    untouched by bucket boundaries, so results are bit-identical at
-    every setting, and ``buckets=1`` traces today's exact single-fused
-    sequence (the pinned HOROVOD_EXCHANGE_BUCKETS=1 contract). Health
-    rows are reassembled into ORIGINAL leaf order either way, so the
-    in-graph skip gate's verdict never depends on the bucket count.
+    first. Per-element reduction math is untouched by bucket boundaries,
+    so results are bit-identical at every setting. Health rows are
+    reassembled into ORIGINAL leaf order either way, so the in-graph
+    skip gate's verdict never depends on the bucket count.
 
     ``axis`` may be an axis-name tuple (one psum over the product of
     axes — the 2-D MoE mesh's dense-leaf exchange). ``denom`` overrides
@@ -227,54 +226,37 @@ def _fused_psum_exchange(grads, axis, average, comp, with_health,
     if not leaves:
         health = jnp.zeros((0, 2), jnp.float32) if with_health else None
         return grads, health
-    if comp is None:
-        wire_dts = [np.dtype(g.dtype).str for g in leaves]
-    else:
-        # one compression probe per distinct dtype, not per leaf
-        probe = {d: np.dtype(comp.compress(jnp.zeros((), d))[0].dtype).str
-                 for d in {g.dtype for g in leaves}}
-        wire_dts = [probe[g.dtype] for g in leaves]
     axes = (axis,) if isinstance(axis, str) else tuple(axis)
     n = 1
     for a in axes:
         n *= int(lax.axis_size(a))
     if denom is not None:
         n = int(denom)
-    out = [None] * len(leaves)
-    hrows = [None] * len(leaves)
+    summed = [None] * len(leaves)
     plan = exchange_bucket_plan(leaves, buckets)
-    for b, bucket_idxs in enumerate(plan):
-        groups = {}
-        for i in bucket_idxs:
-            groups.setdefault(wire_dts[i], []).append(i)
+    for b, idxs in enumerate(plan):
         scope = (jax.named_scope(f"hvd_exchange_bucket{b}")
                  if len(plan) > 1 else contextlib.nullcontext())
         with scope:
-            for dstr in sorted(groups):
-                idxs = groups[dstr]
-                flats, segs, off = [], [], 0
-                for i in idxs:
-                    g = leaves[i]
-                    w = g if comp is None else comp.compress(g)[0]
-                    flat = w.reshape(-1).astype(dstr)
-                    cnt = int(flat.shape[0])
-                    segs.append((off, cnt, tuple(g.shape),
-                                 np.dtype(g.dtype).str, bool(average), None))
-                    flats.append(flat)
-                    off += cnt
-                segs = tuple(segs)
-                row = flats[0] if len(flats) == 1 else jnp.concatenate(flats)
-                record_jit_traced("allreduce_jit", _nbytes(row), axes)
-                row = lax.psum(row, axes)
-                res = unfuse_segments(row, segs, n)
-                hr = segment_health(row, segs) if with_health else None
-                for k, i in enumerate(idxs):
-                    out[i] = res[k]
-                    if with_health:
-                        hrows[i] = hr[k]
-    exchanged = jax.tree.unflatten(treedef, out)
-    health = jnp.stack(hrows) if with_health else None
-    return exchanged, health
+            wire = tuple(leaves[i] if comp is None
+                         else comp.compress(leaves[i])[0] for i in idxs)
+            record_jit_traced("allreduce_jit",
+                              sum(_nbytes(w) for w in wire), axes)
+            for i, s in zip(idxs, lax.psum(wire, axes)):
+                summed[i] = s
+    out = []
+    for g, s in zip(leaves, summed):
+        res = s.astype(g.dtype)
+        if average:
+            # unfuse_segments' branch, on the STATIC dtype
+            if jnp.issubdtype(g.dtype, jnp.floating):
+                res = res / n
+            else:
+                res = res // n
+            res = res.astype(g.dtype)
+        out.append(res)
+    health = tree_health(summed) if with_health else None
+    return jax.tree.unflatten(treedef, out), health
 
 
 # ------------------------------------------------------------ the builder
@@ -284,7 +266,8 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
                         comp, with_health, donate, has_aux, zmeta=None,
                         buckets=1, spec=None):
     """Build ONE jitted step program: per-shard forward + backward, the
-    fused in-graph gradient exchange, optimizer apply, and (guard
+    in-graph gradient exchange (a psum over the gradient leaves — no
+    flat wire row, ``_psum_exchange``), optimizer apply, and (guard
     builds) the health matrix plus the in-graph skip gate. Every
     argument is static and hashable — the lru tier dedupes construction
     per process the way engine._jit_psum_unfuse does, and the engine's
@@ -307,12 +290,12 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
 
     - **decomposed** (``exchange="psum"`` or a stage-0 non-DCN spec):
       gradients group by their per-leaf ``(reduce, denom)`` recipe —
-      fully-reduced groups take the fused bucketed psum, sharded groups
-      (expert/model leaves) sum over their reduce axes and divide by
-      their denominator, with health stats reduced over the missing
-      axes so every rank gates identically. The pure-dense 1-D case is
-      the original psum trace bit-for-bit; the pure-MoE 2-D case is the
-      original per-axis MoE trace bit-for-bit.
+      fully-reduced groups take the bucketed psum over their leaves,
+      sharded groups (expert/model leaves) sum over their reduce axes
+      and divide by their denominator, with health stats reduced over
+      the missing axes so every rank gates identically. The values are
+      those of the eager engine's flat wire row, bit for bit
+      (tests/test_exchange_leaves.py).
     - **whole** (``spec=None`` zero1/zero2/inline/none, or a striped /
       DCN-linked spec): ``tx.update`` owns the exchange; health comes
       from the post-exchange updates, reduced over any non-data spec
@@ -330,14 +313,13 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
       stripe, and returns the NEW STRIPE — full parameters and
       gradients are XLA temporaries that never persist between steps.
 
-    ``buckets`` (HOROVOD_EXCHANGE_BUCKETS) pipelines the psum exchange
-    against backprop: the fused exchange splits into layer-ordered
-    buckets (``_fused_psum_exchange``) and the parameter apply runs
-    bucket-at-a-time (``optimizers.bucketed_apply_updates``), so the
-    first-ready bucket's wire and apply overlap later buckets' backward
-    compute inside the one program. 1 (the default) is bit-identical to
-    the single-fused trace; it is part of the lru key and the engine
-    cache signature, so bucketed and unbucketed programs never collide.
+    ``buckets`` (HOROVOD_EXCHANGE_BUCKETS) splits the psum exchange
+    into layer-ordered buckets, one psum call per bucket
+    (``_psum_exchange``), and runs the parameter apply bucket-at-a-time
+    (``optimizers.bucketed_apply_updates``). 1 (the default) is one psum
+    call over all leaves; every count gives the same values. It is part
+    of the lru key and the engine cache signature, so bucketed and
+    unbucketed programs never collide.
     zero2/zero3 builds take their bucketing from the optimizer's
     ``_ZeroCore.chunk_layout`` instead (same knob, chunk-major stripe)."""
     from ..optimizers import _LeafSpec, _axes_size_prod, _spec_pre_reduce
@@ -433,10 +415,9 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
                     missing = tuple(a for a in mesh_axes
                                     if a not in ls.reduce)
                     if not missing:
-                        # fully-reduced leaves: the plain fused
-                        # exchange, bucketed/health'd exactly like the
-                        # original 1-D psum trace
-                        res, hr = _fused_psum_exchange(
+                        # fully-reduced leaves: the plain exchange,
+                        # bucketed and health'd
+                        res, hr = _psum_exchange(
                             sub, ls.reduce, average, comp, with_health,
                             buckets=buckets)
                         for k, i in enumerate(idxs):
@@ -448,7 +429,7 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
                         # reduce axes, then the denominator finish —
                         # the health rows below want the pre-average
                         # sums.
-                        summed, _ = _fused_psum_exchange(
+                        summed, _ = _psum_exchange(
                             sub, ls.reduce, False, comp, False)
                         dn = _axes_size_prod(ls.denom)
                         res = ([(g / dn).astype(g.dtype)
@@ -518,8 +499,8 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
                 outs += (health,)
             return outs
         if with_health and health is None:
-            # whole-transform modes reduce inside tx.update — no fused
-            # wire row exists, so the health rows come from the
+            # whole-transform modes reduce inside tx.update, so the
+            # health rows come from the
             # post-exchange updates (allgathered, hence bit-identical
             # across ranks for a pure data-axis spec).
             with jax.named_scope("hvd_guard"):
@@ -714,13 +695,13 @@ class CompiledTrainStep:
     are consumed in place.
 
     ``exchange``: ``"auto"`` (default) inspects the optimizer —
-    a ``DistributedOptimizer`` is decomposed so the fused in-graph psum
-    replaces its ``DistributedGradientTransform`` and only the base
-    optimizer runs in the program; its ZeRO-1 mode runs whole (the
-    reduce-scatter IS the update transform); its MoE and sharding-spec
-    forms (``expert_keys``/``model_keys``) decompose into per-group
-    fused exchanges over the runtime's N-D mesh per their per-leaf
-    spec; a plain optimizer gets the fused psum in front.
+    a ``DistributedOptimizer`` is decomposed so the in-graph psum over
+    the gradient leaves replaces its ``DistributedGradientTransform``
+    and only the base optimizer runs in the program; its ZeRO-1 mode
+    runs whole (the reduce-scatter IS the update transform); its MoE
+    and sharding-spec forms (``expert_keys``/``model_keys``) decompose
+    into per-group exchanges over the runtime's N-D mesh per their
+    per-leaf spec; a plain optimizer gets the psum in front.
     ``"psum"``/``"none"`` force those layouts; ``"reduce_scatter"``
     wraps a plain optimizer in the ZeRO-1 transform here. A hand-rolled
     ``optax.chain`` around ``DistributedGradientTransform`` is detected
@@ -779,7 +760,7 @@ class CompiledTrainStep:
         if exchange == "auto":
             if tag == "psum" and getattr(update, "_hvd_base",
                                          None) is not None:
-                # DistributedOptimizer(chain): the fused in-graph psum
+                # DistributedOptimizer(chain): the in-graph psum
                 # replaces DistributedGradientTransform; only the base
                 # optimizer's math runs in the program.
                 self._exchange = "psum"
@@ -805,7 +786,7 @@ class CompiledTrainStep:
                         "compiled_train_step(exchange='auto'): the "
                         "optimizer embeds a gradient-exchanging transform "
                         "(DistributedGradientTransform inside a chain) — "
-                        "adding the fused psum would exchange twice. Pass "
+                        "adding the step's psum would exchange twice. Pass "
                         "exchange='none', or use hvd.DistributedOptimizer "
                         "which auto-decomposes.")
                 self._exchange = "psum"
@@ -839,7 +820,7 @@ class CompiledTrainStep:
                     "expert_keys=...) transform (the per-axis layout "
                     "lives in its _hvd_moe_core)")
             # Decompose like psum: the core's per-axis layout becomes a
-            # per-leaf sharding spec, the fused per-group exchange
+            # per-leaf sharding spec, the per-group exchange
             # replaces the inline per-axis exchange, and only the base
             # optimizer's math runs in the program (same init — the moe
             # wrapper's init IS the base init).
@@ -860,8 +841,8 @@ class CompiledTrainStep:
                     "layout lives in its _hvd_spec)")
             self._spec = spec
             if spec.zero_stage == 0 and not spec.dcn_link:
-                # stage-0 non-DCN: decompose into fused per-group wire
-                # rows; only the base optimizer runs in the program.
+                # stage-0 non-DCN: decompose into per-group psums;
+                # only the base optimizer runs in the program.
                 self._average = self._tx.update._hvd_average
                 self._compression = self._tx.update._hvd_compression
                 self._tx = self._fallback_tx = self._tx.update._hvd_base
@@ -1227,16 +1208,16 @@ def compiled_train_step(loss_fn, optimizer, *, axis_name=AXIS,
                         has_aux=False, name="hvd.step",
                         exchange_buckets=None):
     """Build a :class:`CompiledTrainStep` — the compiled hot loop
-    (docs/performance.md "Compiled hot loop"): forward, backward, fused
+    (docs/performance.md "Compiled hot loop"): forward, backward,
     in-graph gradient exchange, optimizer apply (and, under
     HOROVOD_GUARD=1, the health matrix + in-graph skip gate) as ONE
     jitted, buffer-donated XLA program, signature-cached through the
     engine's membership-scoped step-program cache.
 
     ``exchange_buckets`` (default: HOROVOD_EXCHANGE_BUCKETS, 1) splits
-    the fused exchange into layer-ordered buckets pipelined against
-    backprop — docs/performance.md "Bucketed backward/exchange
-    overlap". 1 is bit-identical to the single fused exchange."""
+    the exchange into layer-ordered buckets, one psum call each —
+    docs/performance.md "Bucketed backward/exchange overlap". Every
+    count gives the same values."""
     return CompiledTrainStep(loss_fn, optimizer, axis_name=axis_name,
                              exchange=exchange, average=average,
                              compression=compression, donate=donate,

@@ -115,7 +115,7 @@ def DistributedGradientTransform(axis_name=AXIS, average=True,
 
     # Tag for hvd.compiled_train_step (ops/step_program.py): this
     # transform exchanges gradients INSIDE update(), so a compiled step
-    # wrapping it must not add its own fused psum on top.
+    # wrapping it must not add its own psum on top.
     update_fn._hvd_exchange = "inline"
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -369,7 +369,7 @@ def _zero1(base, axis_name, average, compression):
         return jax.tree.unflatten(treedef, out), Zero1State(base=new_base)
 
     # Tag for hvd.compiled_train_step: the reduce-scatter IS the update
-    # transform, so the compiled step runs it whole (no fused psum).
+    # transform, so the compiled step runs it whole (no psum of its own).
     update_fn._hvd_exchange = "zero1"
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -759,7 +759,7 @@ def _dcn_grad_exchange(axis_name, average, dcn_compression, dcn_local_size,
                 DcnExchangeState(residual=new_residual))
 
     # inline: the exchange happens inside update(), the compiled step
-    # must run the chain whole and add no fused psum of its own.
+    # must run the chain whole and add no psum of its own.
     update_fn._hvd_exchange = "inline"
     return optax.GradientTransformation(init_fn, update_fn)
 
@@ -820,7 +820,7 @@ class _MoECore:
     def exchange_tree(self, updates, comp=None):
         """Inline per-axis exchange (standalone use inside a caller's own
         shard_map over both axes). The compiled step never calls this —
-        it builds the fused per-axis wire rows itself
+        it psums each axis group's leaves itself
         (ops/step_program.py)."""
         import jax.lax as lax
         paths_leaves, treedef = jax.tree_util.tree_flatten_with_path(
@@ -1039,8 +1039,8 @@ def _spec_grad_exchange(spec, compression=Compression.none,
     (dense), :meth:`_MoECore.exchange_tree` (expert) and
     :func:`_dcn_grad_exchange` (staged DCN) in one transform. Standalone
     it exchanges inside ``update()`` within a shard_map over
-    ``spec.known_axes``; the compiled step decomposes it into fused
-    per-group wire rows unless the DCN residual forces running whole
+    ``spec.known_axes``; the compiled step decomposes it into
+    per-group psums unless the DCN residual forces running whole
     (``spec.dcn_link``).
 
     With ``dcn_compression`` set, every leaf is pre-reduced over its
@@ -1310,7 +1310,7 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
                 optimizer,
             )
             # inline: the chain's first link exchanges inside update();
-            # the compiled step runs the whole chain, no fused psum.
+            # the compiled step runs the whole chain, no psum of its own.
             tx.update._hvd_exchange = "inline"
         else:
             tx = optax.chain(
@@ -1320,8 +1320,8 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
                 optimizer,
             )
             # Tags for hvd.compiled_train_step (ops/step_program.py): the
-            # compiled path decomposes this wrapper — its fused in-graph
-            # psum replaces the DistributedGradientTransform link and only
+            # compiled path decomposes this wrapper — its in-graph psum
+            # replaces the DistributedGradientTransform link and only
             # the base optimizer's math runs inside the program.
             tx.update._hvd_exchange = "psum"
             tx.update._hvd_base = optimizer

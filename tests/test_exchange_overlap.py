@@ -1,10 +1,10 @@
 """Bucketed backward/exchange overlap (ISSUE-18): the compiled step's
-fused gradient exchange split into layer-ordered buckets pipelined
-against backprop inside the same donated XLA program.
+gradient exchange split into layer-ordered buckets — one psum call over
+each bucket's leaves — inside the same donated XLA program.
 
-Acceptance surface: HOROVOD_EXCHANGE_BUCKETS=1 is bit-identical to the
-fused exchange (the pin) and — because psum is a per-element reduction
-unaffected by concat/slice boundaries — ANY bucket count is bit-identical
+Acceptance surface: because psum is a per-element reduction unaffected
+by bucket boundaries, ANY bucket count is bit-identical to
+HOROVOD_EXCHANGE_BUCKETS=1 (one psum call over all leaves)
 with an elementwise optimizer like sgd, across the psum and zero2 tags;
 the guard-enabled bucketed program matches the guard-off build bitwise
 when no fault fires; the bucket count is part of the step-program cache
@@ -86,9 +86,9 @@ def _assert_tree_bitwise(got, want):
 # -------------------------------------------------------------- the plan
 
 def test_bucket_plan_identity_and_edge_cases():
-    """buckets=1 is the identity plan in ORIGINAL leaf order — the traced
-    sequence must be exactly today's fused exchange (the bit-identity
-    pin); empty and singleton trees degrade sanely."""
+    """buckets=1 is the identity plan in ORIGINAL leaf order — one psum
+    call over the whole tree; empty and singleton trees degrade
+    sanely."""
     leaves = [np.zeros((8,)), np.zeros((4, 4)), np.zeros((2,))]
     assert exchange_bucket_plan(leaves, 1) == ((0, 1, 2),)
     assert exchange_bucket_plan(leaves, 0) == ((0, 1, 2),)
@@ -124,9 +124,9 @@ def test_bucket_plan_balances_bytes():
 # ------------------------------------------------------------ bit parity
 
 def test_psum_bit_identity_across_bucket_counts():
-    """sgd at buckets 3 and 8 vs the default fused build: BIT-identical
-    losses and params — psum is per-element, so concat boundaries cannot
-    change a single ulp."""
+    """sgd at buckets 3 and 8 vs the default one-call build:
+    BIT-identical losses and params — psum is per-element, so bucket
+    boundaries cannot change a single ulp."""
     _reinit()
     params = _make_params()
     want, losses_w = _run(
@@ -193,8 +193,8 @@ def test_guard_program_bitwise_with_buckets(monkeypatch):
 
 def test_bucket_count_is_part_of_cache_signature():
     """Two step objects differing only in exchange_buckets compile two
-    distinct programs — one miss each, hits thereafter; a fused program
-    can never be served where a bucketed one was requested."""
+    distinct programs — one miss each, hits thereafter; an unbucketed
+    program can never be served where a bucketed one was requested."""
     eng = _reinit()
     params = _make_params()
     s1 = hvd.compiled_train_step(_loss_fn, optax.sgd(0.05),

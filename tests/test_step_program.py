@@ -151,7 +151,7 @@ def test_disabled_env_forces_fallback(monkeypatch):
 # --------------------------------------------- optimizer integration modes
 
 def test_distributed_optimizer_auto_decomposes():
-    """DistributedOptimizer(chain) under exchange='auto': the fused
+    """DistributedOptimizer(chain) under exchange='auto': the step's
     in-graph psum replaces DistributedGradientTransform, only the base
     optimizer runs in the program — numbers match the eager reference."""
     _reinit()
@@ -167,7 +167,7 @@ def test_distributed_optimizer_auto_decomposes():
 def test_zero1_reduce_scatter_matches_allreduce_math():
     """DistributedOptimizer(reduce_scatter=True) compiles whole (the
     reduce-scatter IS the update transform) and, for a stateless-per-shard
-    optimizer like sgd, agrees with the fused-psum build."""
+    optimizer like sgd, agrees with the psum build."""
     _reinit()
     params = _make_params()
     z = hvd.DistributedOptimizer(optax.sgd(0.05), reduce_scatter=True)
