@@ -471,9 +471,10 @@ def unfuse_segments(row, segs, world_size):
     for off, cnt, shape, dtype, average, postscale in segs:
         out = row[off:off + cnt].astype(dtype)
         if average:
-            # Same branch the host unfuse takes (np.issubdtype on the
-            # STATIC dtype — the decision constant-folds at trace time).
-            if np.issubdtype(np.dtype(dtype), np.floating):
+            # Same branch the host unfuse takes, on the STATIC dtype —
+            # the decision constant-folds at trace time. jnp's
+            # issubdtype: numpy's does not count bfloat16 as floating.
+            if jnp.issubdtype(jnp.dtype(dtype), jnp.floating):
                 out = out / world_size
             else:
                 out = out // world_size

@@ -1960,8 +1960,8 @@ class EagerEngine:
             for r, handle, shape, average, postscale in reqs:
                 out = seg.astype(dtype, copy=True).reshape(shape)
                 if average:
-                    out = out / self.num_ranks if np.issubdtype(
-                        dtype, np.floating) else out // self.num_ranks
+                    out = out / self.num_ranks if jnp.issubdtype(
+                        dtype, jnp.floating) else out // self.num_ranks
                     out = out.astype(dtype, copy=False)
                 if postscale is not None:
                     out = (out * postscale).astype(dtype, copy=False)

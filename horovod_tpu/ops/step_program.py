@@ -28,13 +28,13 @@ compiled for a dead membership can never run again; the builder lru tier
 below registers with ``engine.register_wire_program_builder`` so elastic
 aborts clear its Mesh-keyed executables too.
 
-Composable parallelism: the builder no longer forks per exchange tag.
-ONE spec-driven body (``_spec_shard``) covers the flat psum, the
-expert-parallel MoE layout, the ZeRO stripe ladder, the staged DCN hop
-and tensor parallelism — each parameter leaf carries a per-leaf
-``(reduce, denom)`` recipe from ``optimizers._ShardingSpec``, so
-previously mutually-exclusive combinations (moe x zero, moe x dcn,
-model-parallel x any) compile into the same single donated program
+Composable parallelism: ONE spec-driven body (``_spec_shard``) covers
+the flat psum, the expert-parallel MoE layout, the ZeRO stripe ladder,
+the staged DCN hop and tensor parallelism. What a leaf reduces over,
+what it divides by, whether state is striped and whether a DCN link
+carries a residual is said by an ``optimizers._ShardingSpec`` and by
+nothing else, so every combination (moe x zero, moe x dcn,
+model-parallel x any) compiles into the same single donated program
 (docs/performance.md "Composable parallelism").
 
 Guard integration (PR 8): with ``HOROVOD_GUARD=1`` the program gains a
@@ -221,7 +221,7 @@ def _psum_exchange(grads, axis, average, comp, with_health,
     the averaging divisor: the MoE expert leaves psum over the data
     axes only but still divide by the FULL world size (their gradients
     already carry the expert-axis contributions via the backward
-    alltoall — see optimizers._MoECore)."""
+    alltoall — see optimizers._LeafSpec)."""
     leaves, treedef = jax.tree.flatten(grads)
     if not leaves:
         health = jnp.zeros((0, 2), jnp.float32) if with_health else None
@@ -284,34 +284,39 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
     (caller rebinds the returns; the stale inputs are dead buffers).
     jit is lazy: compilation happens at first execution, not here.
 
-    ONE body serves every exchange layout (docs/performance.md
-    "Composable parallelism") in three trace-time modes driven by
-    ``spec`` (an :class:`optimizers._ShardingSpec`) and ``zmeta``:
+    ``exchange`` is ``"psum"`` (the program exchanges as ``spec`` — an
+    :class:`optimizers._ShardingSpec` — says; ``spec=None`` means the
+    keyless stage-0 spec over the mesh's axes) or ``"none"`` (``tx``
+    exchanges inside its own update and the program adds nothing).
 
-    - **decomposed** (``exchange="psum"`` or a stage-0 non-DCN spec):
-      gradients group by their per-leaf ``(reduce, denom)`` recipe —
-      fully-reduced groups take the bucketed psum over their leaves,
-      sharded groups (expert/model leaves) sum over their reduce axes
-      and divide by their denominator, with health stats reduced over
-      the missing axes so every rank gates identically. The values are
-      those of the eager engine's flat wire row, bit for bit
+    ONE body serves every exchange layout (docs/performance.md
+    "Composable parallelism") in three trace-time modes, each a
+    property of the spec:
+
+    - **decomposed** (``"psum"`` with ``zero_stage == 0`` and no DCN
+      link): gradients group by their per-leaf ``(reduce, denom)``
+      recipe — fully-reduced groups take the bucketed psum over their
+      leaves, sharded groups (expert/model leaves) sum over their
+      reduce axes and divide by their denominator, with health stats
+      reduced over the missing axes so every rank gates identically.
+      ``tx`` is the base optimizer. The values are those of the eager
+      engine's flat wire row, bit for bit
       (tests/test_exchange_leaves.py).
-    - **whole** (``spec=None`` zero1/zero2/inline/none, or a striped /
-      DCN-linked spec): ``tx.update`` owns the exchange; health comes
-      from the post-exchange updates, reduced over any non-data spec
-      axes.
-    - **resident** (``zmeta`` set — legacy zero3 or a stage-3 spec):
-      the first argument is this rank's flat parameter STRIPE
-      (``CompiledTrainStep.shard_params``), not the full tree. ``zmeta
-      = (treedef, shapes, dtype-strs, acc-dtype-str)`` carries the
-      static full-tree layout; per step the program allgathers the
-      stripe into full params just-in-time (full precision — forward
-      numerics never ride the lossy hop), takes grads, pre-reduces each
-      leaf over its non-stripe axes per the spec, reduce-scatters down
-      to the stripe (optionally DCN-compressed with the error-feedback
-      residual from opt_state), applies the base optimizer to the
-      stripe, and returns the NEW STRIPE — full parameters and
-      gradients are XLA temporaries that never persist between steps.
+    - **whole** (stage 1/2, a stage-0 DCN link, or ``"none"``):
+      ``tx.update`` owns the exchange; health comes from the
+      post-exchange updates, reduced over any non-data spec axes.
+    - **resident** (stage 3; ``zmeta`` set): the first argument is this
+      rank's flat parameter STRIPE (``CompiledTrainStep.shard_params``),
+      not the full tree. ``zmeta = (treedef, shapes, dtype-strs,
+      acc-dtype-str)`` carries the static full-tree layout; per step
+      the program allgathers the stripe into full params just-in-time
+      (full precision — forward numerics never ride the lossy hop),
+      takes grads, pre-reduces each leaf over its non-stripe axes per
+      the spec, reduce-scatters down to the stripe (optionally
+      DCN-compressed with the error-feedback residual from opt_state),
+      applies the base optimizer to the stripe, and returns the NEW
+      STRIPE — full parameters and gradients are XLA temporaries that
+      never persist between steps.
 
     ``buckets`` (HOROVOD_EXCHANGE_BUCKETS) splits the psum exchange
     into layer-ordered buckets, one psum call per bucket
@@ -322,19 +327,26 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
     unbucketed programs never collide.
     zero2/zero3 builds take their bucketing from the optimizer's
     ``_ZeroCore.chunk_layout`` instead (same knob, chunk-major stripe)."""
-    from ..optimizers import _LeafSpec, _axes_size_prod, _spec_pre_reduce
+    from ..optimizers import (_ShardingSpec, _axes_size_prod,
+                              _spec_pre_reduce)
     mesh_axes = tuple(mesh.axis_names)
-    model_axis = getattr(spec, "model_axis", None)
-    batch_axes = tuple(a for a in mesh_axes if a != model_axis)
+    if spec is None:
+        spec = _ShardingSpec(data_axes=mesh_axes, average=average)
+    if exchange not in ("psum", "none"):
+        raise ValueError(
+            f"unknown exchange {exchange!r}: the step program takes "
+            "'psum' (exchange as the sharding spec says) or 'none' (tx "
+            "exchanges inside its own update)")
+    batch_axes = tuple(a for a in mesh_axes if a != spec.model_axis)
     resident = zmeta is not None
-    decomposed = (exchange == "psum"
-                  or (spec is not None and not resident
-                      and spec.zero_stage == 0 and not spec.dcn_link))
-    if spec is not None and not decomposed and not resident:
-        # Whole-transform spec modes (striped stage 1/2, stage-0 DCN
-        # chain) reduce inside tx.update over spec.known_axes only — a
-        # mesh axis of size > 1 the spec doesn't know about would be
-        # silently under-reduced, so reject it at build time.
+    decomposed = (exchange == "psum" and spec.zero_stage == 0
+                  and not spec.dcn_link)
+    if not decomposed and not resident:
+        # Whole-transform modes (striped stage 1/2, stage-0 DCN chain,
+        # an inline transform) reduce inside tx.update over
+        # spec.known_axes only — a mesh axis of size > 1 the spec
+        # doesn't know about would be silently under-reduced, so reject
+        # it at build time.
         for name, size in mesh.shape.items():
             if size > 1 and name not in spec.known_axes:
                 raise ValueError(
@@ -387,26 +399,20 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
                                    aux)
             loss = lax.pmean(loss, batch_axes)
             if resident:
-                g_leaves = jax.tree.leaves(grads)
-                if spec is not None:
-                    # combos: each leaf first reduces over its
-                    # non-stripe axes and pre-divides, then rides the
-                    # flat data-axis stripe like any dense leaf
-                    lspecs = spec.leaf_specs(grads, mesh_axes)
-                    g_leaves = [
-                        _spec_pre_reduce(g.astype(acc_str), ls,
-                                         core.axis, spec.average)
-                        for g, ls in zip(g_leaves, lspecs)]
+                # each leaf first reduces over its non-stripe axes and
+                # pre-divides (nothing to do for a dense leaf on a 1-D
+                # mesh), then rides the flat data-axis stripe
+                g_leaves = [
+                    _spec_pre_reduce(g.astype(acc_str), ls, core.axis,
+                                     spec.average)
+                    for g, ls in zip(jax.tree.leaves(grads),
+                                     spec.leaf_specs(grads, mesh_axes))]
                 flat_g, _ = core.flatten_pad(g_leaves, acc_str, n)
                 g_stripe, new_res = core.scatter(flat_g,
                                                  opt_state.residual, n)
             elif decomposed:
                 g_leaves, gdef = jax.tree.flatten(grads)
-                lspecs = (spec.leaf_specs(grads, mesh_axes)
-                          if spec is not None
-                          else [_LeafSpec(mesh_axes, mesh_axes)]
-                          * len(g_leaves))
-                for i, ls in enumerate(lspecs):
+                for i, ls in enumerate(spec.leaf_specs(grads, mesh_axes)):
                     groups.setdefault(ls, []).append(i)
                 out = [None] * len(g_leaves)
                 hrows = [None] * len(g_leaves)
@@ -504,9 +510,8 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
             # post-exchange updates (allgathered, hence bit-identical
             # across ranks for a pure data-axis spec).
             with jax.named_scope("hvd_guard"):
-                extra = (() if spec is None else
-                         tuple(a for a in mesh_axes
-                               if a not in spec.data_axes))
+                extra = tuple(a for a in mesh_axes
+                              if a not in spec.data_axes)
                 u_leaves = jax.tree.leaves(updates)
                 if not extra:
                     health = tree_health(u_leaves)
@@ -592,7 +597,7 @@ def engine_cached_program(signature, build):
 
 
 def _zmeta_of(params):
-    """Static full-tree layout carried by the zero3 program signature:
+    """Static full-tree layout carried by the stage-3 program signature:
     ``(treedef, shapes, dtype-strs, accumulation-dtype-str)`` — all
     hashable, so it rides the lru/cache keys directly."""
     leaves, treedef = jax.tree.flatten(params)
@@ -673,9 +678,8 @@ def _chaos_perturb(tree):
 # ----------------------------------------------------------- the entry point
 
 class CompiledTrainStep:
-    """The shared compiled-step entry point (ISSUE-11 tentpole):
-    ``DistributedOptimizer`` (both the allreduce chain and the ZeRO-1
-    reduce-scatter mode), plain optax optimizers, and future decode
+    """The shared compiled-step entry point: ``DistributedOptimizer``
+    in every configuration, plain optax optimizers, and the serving
     paths all route through this one builder + cache.
 
     ::
@@ -694,30 +698,34 @@ class CompiledTrainStep:
     loss return is an unfetched device scalar, and the donated inputs
     are consumed in place.
 
-    ``exchange``: ``"auto"`` (default) inspects the optimizer —
-    a ``DistributedOptimizer`` is decomposed so the in-graph psum over
-    the gradient leaves replaces its ``DistributedGradientTransform``
-    and only the base optimizer runs in the program; its ZeRO-1 mode
-    runs whole (the reduce-scatter IS the update transform); its MoE
-    and sharding-spec forms (``expert_keys``/``model_keys``) decompose
-    into per-group exchanges over the runtime's N-D mesh per their
-    per-leaf spec; a plain optimizer gets the psum in front.
-    ``"psum"``/``"none"`` force those layouts; ``"reduce_scatter"``
-    wraps a plain optimizer in the ZeRO-1 transform here. A hand-rolled
-    ``optax.chain`` around ``DistributedGradientTransform`` is detected
-    and rejected under auto — pass ``exchange="none"`` (the chain
-    already exchanges) instead of silently exchanging twice.
+    ``exchange``: ``"auto"`` (default) reads the optimizer's sharding
+    spec (``optimizers._ShardingSpec``, carried by every
+    ``DistributedOptimizer`` product) and compiles the layout it says:
+    stage 0 without a DCN link is **decomposed** — per-group psums over
+    the gradient leaves replace the product's exchange link and only
+    the base optimizer runs in the program, over the runtime's N-D mesh
+    when the spec names expert/model leaves; stage 1/2 and a stage-0
+    DCN link run the transform **whole** (the stripe / residual state
+    IS the update transform); stage 3 is **resident** (parameters live
+    as stripes, see :meth:`shard_params`). A plain optimizer gets the
+    keyless stage-0 spec over ``axis_name`` — the psum in front. A
+    transform that exchanges inside its own update (a bare
+    ``DistributedGradientTransform``) runs as it is. ``"none"`` says
+    the optimizer already exchanges and the program adds nothing: a
+    hand-rolled ``optax.chain`` around ``DistributedGradientTransform``
+    is detected and rejected under auto — pass ``exchange="none"``
+    instead of silently exchanging twice.
 
     Fallback (``hvd_step_fallback_total`` by reason): the eager engine
     remains the negotiation-parity path — ``HOROVOD_DEVICE_RESIDENT=0``
     (``host_mode``), ``HOROVOD_STEP_PROGRAM=0`` (``disabled``), or more
     distinct shape signatures than HOROVOD_STEP_PROGRAM_CHURN_LIMIT
-    (``shape_churn``) run the step as host value_and_grad +
-    ``exchange_gradients`` + ``guarded_apply_updates``. Exchange modes
-    whose reduction lives inside the update transform (ZeRO-1/inline)
-    have no host decomposition; their fallback is the same per-shard
-    program built undonated via the builder tier, bypassing the engine
-    cache."""
+    (``shape_churn``) run a decomposed step on the flat mesh as host
+    value_and_grad + ``exchange_gradients`` +
+    ``guarded_apply_updates``. Layouts whose reduction lives inside the
+    update transform or spans expert/model axes have no host
+    decomposition; their fallback is the same per-shard program built
+    undonated via the builder tier, bypassing the engine cache."""
 
     def __init__(self, loss_fn, optimizer, *, axis_name=AXIS,
                  exchange="auto", average=True,
@@ -753,125 +761,73 @@ class CompiledTrainStep:
         self.compiled_steps = 0
         self.fallback_steps = 0
 
+        if exchange not in ("auto", "none"):
+            raise ValueError(
+                f"unknown exchange mode {exchange!r}: expected 'auto' "
+                "(exchange as the optimizer's sharding spec says; a "
+                "plain optimizer gets the psum in front) or 'none' (the "
+                "optimizer exchanges inside its own update). ZeRO, "
+                "expert and model layouts are options of "
+                "hvd.DistributedOptimizer, not of the step")
+        from ..optimizers import _ShardingSpec
         update = getattr(optimizer, "update", None)
-        tag = getattr(update, "_hvd_exchange", None)
-        self._spec = None
-        self._decomposed = False
-        if exchange == "auto":
-            if tag == "psum" and getattr(update, "_hvd_base",
-                                         None) is not None:
-                # DistributedOptimizer(chain): the in-graph psum
-                # replaces DistributedGradientTransform; only the base
-                # optimizer's math runs in the program.
-                self._exchange = "psum"
-                self._average = update._hvd_average
-                self._compression = update._hvd_compression
-                self._tx = self._fallback_tx = update._hvd_base
-            elif tag in ("zero1", "zero2", "zero3", "moe", "spec"):
-                # zero1/zero2 run whole (the reduce-scatter IS the
-                # update transform); zero3 switches the program to the
-                # stripe-resident layout; moe/spec carry a per-leaf
-                # sharding layout over the runtime's N-D mesh —
-                # resolved below (see _build_step_program).
-                self._exchange = tag
-                self._tx = self._fallback_tx = optimizer
-            elif tag == "inline":
-                # bare DistributedGradientTransform-style transform: it
-                # exchanges inside update(), the program adds nothing.
-                self._exchange = "none"
-                self._tx = self._fallback_tx = optimizer
-            else:
-                if update is not None and _contains_inline_exchange(update):
-                    raise ValueError(
-                        "compiled_train_step(exchange='auto'): the "
-                        "optimizer embeds a gradient-exchanging transform "
-                        "(DistributedGradientTransform inside a chain) — "
-                        "adding the step's psum would exchange twice. Pass "
-                        "exchange='none', or use hvd.DistributedOptimizer "
-                        "which auto-decomposes.")
-                self._exchange = "psum"
-                self._tx = self._fallback_tx = optimizer
-        elif exchange == "reduce_scatter":
-            from ..optimizers import _zero1
-            self._exchange = "zero1"
-            self._tx = self._fallback_tx = _zero1(
-                optimizer, axis_name=axis_name, average=average,
-                compression=compression)
-        elif exchange in ("psum", "none", "zero1", "zero2", "zero3",
-                          "moe", "spec"):
-            self._exchange = exchange
-            self._tx = self._fallback_tx = optimizer
-        else:
-            raise ValueError(
-                f"unknown exchange mode {exchange!r} (expected 'auto', "
-                "'psum', 'reduce_scatter', 'zero1', 'zero2', 'zero3', "
-                "'moe', 'spec' or 'none')")
-        if self._exchange == "zero3" and getattr(
-                self._tx.update, "_hvd_zero_core", None) is None:
-            raise ValueError(
-                "exchange='zero3' needs a DistributedOptimizer("
-                "zero_stage=3) transform (the stripe layout lives in "
-                "its _hvd_zero_core)")
-        if self._exchange == "moe":
-            core = getattr(self._tx.update, "_hvd_moe_core", None)
-            if core is None:
+        spec = (getattr(update, "_hvd_spec", None)
+                if exchange == "auto" else None)
+        # a transform that exchanges inside update() and carries no
+        # spec (bare DistributedGradientTransform): the program adds
+        # nothing
+        self._inline = exchange == "none" or (
+            spec is None
+            and getattr(update, "_hvd_exchange", None) == "inline")
+        if spec is None:
+            if not self._inline and _contains_inline_exchange(update):
                 raise ValueError(
-                    "exchange='moe' needs a DistributedOptimizer("
-                    "expert_keys=...) transform (the per-axis layout "
-                    "lives in its _hvd_moe_core)")
-            # Decompose like psum: the core's per-axis layout becomes a
-            # per-leaf sharding spec, the per-group exchange
-            # replaces the inline per-axis exchange, and only the base
-            # optimizer's math runs in the program (same init — the moe
-            # wrapper's init IS the base init).
-            from ..optimizers import _ShardingSpec
-            self._average = self._tx.update._hvd_average
-            self._compression = self._tx.update._hvd_compression
-            self._spec = _ShardingSpec(
-                data_axes=core.data_axes, expert_axis=core.expert_axis,
-                expert_keys=core.expert_keys, average=core.average)
-            self._tx = self._fallback_tx = self._tx.update._hvd_base
-            self._decomposed = True
-        elif self._exchange == "spec":
-            spec = getattr(self._tx.update, "_hvd_spec", None)
-            if spec is None:
-                raise ValueError(
-                    "exchange='spec' needs a DistributedOptimizer("
-                    "expert_keys/model_keys) transform (the per-leaf "
-                    "layout lives in its _hvd_spec)")
-            self._spec = spec
-            if spec.zero_stage == 0 and not spec.dcn_link:
-                # stage-0 non-DCN: decompose into per-group psums;
-                # only the base optimizer runs in the program.
-                self._average = self._tx.update._hvd_average
-                self._compression = self._tx.update._hvd_compression
-                self._tx = self._fallback_tx = self._tx.update._hvd_base
-                self._decomposed = True
-            # striped (stage>=1) and DCN-linked specs run the transform
-            # whole — the stripe/residual state IS the update transform.
-        elif self._exchange == "psum":
-            self._decomposed = True
+                    "compiled_train_step(exchange='auto'): the "
+                    "optimizer embeds a gradient-exchanging transform "
+                    "(DistributedGradientTransform inside a chain) — "
+                    "adding the step's psum would exchange twice. Pass "
+                    "exchange='none', or use hvd.DistributedOptimizer "
+                    "which auto-decomposes.")
+            spec = _ShardingSpec(data_axes=axis_name, average=average)
+        self._spec = spec
+        self._tx = optimizer
+        if self._decomposed and getattr(update, "_hvd_base",
+                                        None) is not None:
+            # a DistributedOptimizer stage-0 chain: the program's
+            # per-group psums replace its exchange link
+            self._average = update._hvd_average
+            self._compression = update._hvd_compression
+            self._tx = update._hvd_base
         self._comp = (None if self._compression is Compression.none
                       else self._compression)
 
     # ------------------------------------------------------------- plumbing
 
     @property
+    def _decomposed(self):
+        """True when the program exchanges the gradient leaves itself
+        (per-group psums) and ``tx`` is the base optimizer."""
+        return (not self._inline and self._spec.zero_stage == 0
+                and not self._spec.dcn_link)
+
+    @property
     def _resident(self):
-        """True when the program runs the stripe-resident layout: the
-        legacy zero3 tag, or a sharding spec striped at stage 3."""
-        return (self._exchange == "zero3"
-                or (self._spec is not None
-                    and self._spec.zero_stage == 3))
+        """True when the program runs the stripe-resident layout."""
+        return not self._inline and self._spec.zero_stage == 3
+
+    @property
+    def _exchange(self):
+        """The layout's short label (metrics, ``perf_signature``, the
+        cache signature)."""
+        return "none" if self._inline else self._spec.label
 
     def init(self, params):
-        """Optimizer-state init for the transform the program runs
-        (after auto decomposition: the base optimizer for psum/moe/spec
-        modes, the ZeRO stripe state for reduce_scatter/zero modes).
-        For the stripe-resident layout (zero3, or a spec at stage 3),
-        pass the FULL parameter tree here (it also fixes the static
-        stripe layout); then convert with :meth:`shard_params` and feed
-        the step stripes."""
+        """Optimizer-state init for the transform the program runs (the
+        base optimizer when decomposed, else the whole transform with
+        its stripe / residual state). For the stripe-resident layout
+        (stage 3), pass the FULL parameter tree here (it also fixes the
+        static stripe layout); then convert with :meth:`shard_params`
+        and feed the step stripes."""
         if self._resident:
             self._zmeta = _zmeta_of(params)
         return self._tx.init(params)
@@ -942,25 +898,13 @@ class CompiledTrainStep:
         3-D (data, expert, model) mesh (HOROVOD_MODEL_PARALLEL), both
         fixed at init time."""
         spec = self._spec
-        if spec is None or (spec.expert_axis is None
-                            and spec.model_axis is None):
+        if not spec.shard_axes:
             return st.mesh
         req = spec.required_axes()
         for mesh in (st.mesh, getattr(st, "expert_mesh", None),
                      getattr(st, "model_mesh", None)):
             if mesh is not None and req.issubset(mesh.axis_names):
                 return mesh
-        if self._exchange == "moe":
-            mesh = getattr(st, "expert_mesh", None)
-            if mesh is None:
-                raise ValueError(
-                    "exchange='moe' needs the 2-D expert mesh: set "
-                    "HOROVOD_EXPERT_PARALLEL (or Config.expert_parallel)"
-                    " to a degree > 1 dividing the world size before "
-                    "hvd.init()")
-            raise ValueError(
-                f"MoE exchange axes {spec.known_axes} not all present "
-                f"in the expert mesh axes {mesh.axis_names}")
         raise ValueError(
             f"no runtime mesh provides the sharding-spec axes "
             f"{tuple(sorted(req))}: set HOROVOD_EXPERT_PARALLEL and/or "
@@ -989,11 +933,11 @@ class CompiledTrainStep:
     def _resolve_buckets(self, cfg):
         """Effective exchange-bucket count for this call: the explicit
         constructor pin, else HOROVOD_EXCHANGE_BUCKETS. Only the
-        decomposed layouts (psum/moe/stage-0 spec) trace the bucketed
-        exchange; every other mode normalizes to 1 so the knob can't
-        churn their cache signatures (zero2/zero3 bucketing rides the
-        optimizer's _ZeroCore, which is already part of the signature
-        via its object token)."""
+        decomposed layout traces the bucketed exchange; every other
+        mode normalizes to 1 so the knob can't churn their cache
+        signatures (zero2/zero3 bucketing rides the optimizer's
+        _ZeroCore, which is already part of the signature via its
+        object token)."""
         if not self._decomposed:
             return 1
         b = (self._buckets if self._buckets is not None
@@ -1010,8 +954,7 @@ class CompiledTrainStep:
             self._exchange, bool(self._average), comp_tag, int(buckets),
             _callable_digest(self._tx.update), _obj_token(self._tx.update),
             _callable_digest(self._loss_fn), _obj_token(self._loss_fn),
-            bool(donate), bool(self._has_aux), self._zmeta,
-            None if self._spec is None else _obj_token(self._spec),
+            bool(donate), bool(self._has_aux), self._zmeta, self._spec,
             _tree_avals_digest(params), _tree_avals_digest(opt_state),
             # batch avals stay explicit (not digested) so shape churn is
             # visible in the key and debuggable from a cache dump
@@ -1027,9 +970,10 @@ class CompiledTrainStep:
     def _analyze(self, prog, params, opt_state, batch):
         """One-time per-signature program introspection, before the first
         execution (donation leaves the example buffers dead afterwards):
-        whole-program FLOPs from ``Lowered.cost_analysis`` (one more
-        lowering, no backend compile) for the MFU accounting, 0.0 when
-        it cannot be had. Span
+        whole-program FLOPs from ``Lowered.cost_analysis`` for the MFU
+        accounting, 0.0 when it cannot be had. This is where the
+        program is traced and lowered, once — the first execution
+        reuses both and adds only the backend compile. Span
         ``step.analyze``. The device-trace join needs no HLO from here:
         the tracer reads it from the executable that ran
         (diag/xla_trace.py ``live_hlo``)."""
@@ -1092,7 +1036,8 @@ class CompiledTrainStep:
                                       *batch)
             self._signatures.add(sig)
         mesh, loss_fn, tx = self._step_mesh(st), self._loss_fn, self._tx
-        exchange, average, comp = self._exchange, self._average, self._comp
+        exchange = "none" if self._inline else "psum"
+        average, comp = self._average, self._comp
         nbatch, has_aux = len(batch), self._has_aux
         if self._resident:
             self._zero3_layout()  # raises before caching a bad signature
@@ -1150,18 +1095,19 @@ class CompiledTrainStep:
         return self._eager_step(params, opt_state, *batch)
 
     def _eager_step(self, params, opt_state, *batch):
-        """Legacy/negotiation-parity step. psum mode decomposes onto the
-        eager engine (host value_and_grad on the full local batch ->
-        exchange_gradients -> guarded_apply_updates), matching the
-        compiled program's numbers for a mean-reduced loss over equal
-        shards. zero1/none modes reduce inside tx.update, which only has
-        meaning in a mapped program — their legacy form is the same
-        per-shard program built undonated via the builder tier (no
-        engine cache, no donation)."""
+        """Negotiation-parity step. A decomposed layout on the flat mesh
+        runs on the eager engine (host value_and_grad on the full local
+        batch -> exchange_gradients -> guarded_apply_updates), matching
+        the compiled program's numbers for a mean-reduced loss over
+        equal shards. Every other layout reduces inside tx.update or
+        over expert/model axes, which only has meaning in a mapped
+        program — its fallback is the same per-shard program built
+        undonated via the builder tier (no engine cache, no
+        donation)."""
         monitor = guard.get()
         scope = (jax.enable_x64() if _needs_x64(params, opt_state, batch)
                  else contextlib.nullcontext())
-        if self._exchange == "psum":
+        if self._decomposed and not self._spec.shard_axes:
             from ..optimizers import (exchange_gradients,
                                       guarded_apply_updates)
             if monitor is not None and self._guard_pending is not None:
@@ -1181,7 +1127,7 @@ class CompiledTrainStep:
                                        name_prefix=f"{self._name}.grads")
             with scope:
                 params, opt_state, _applied = guarded_apply_updates(
-                    params, opt_state, grads, self._fallback_tx)
+                    params, opt_state, grads, self._tx)
             if self._has_aux:
                 return params, opt_state, loss, aux
             return params, opt_state, loss
@@ -1192,7 +1138,8 @@ class CompiledTrainStep:
         if self._resident:
             self._zero3_layout()
         prog = _build_step_program(self._step_mesh(st), self._loss_fn,
-                                   self._tx, len(batch), self._exchange,
+                                   self._tx, len(batch),
+                                   "none" if self._inline else "psum",
                                    self._average, self._comp, False, False,
                                    self._has_aux,
                                    self._zmeta if self._resident else None,

@@ -217,161 +217,39 @@ def bucketed_apply_updates(params, updates, plan):
     return jax.tree.unflatten(treedef, out)
 
 
-class Zero1State(NamedTuple):
-    """Optimizer state of the ZeRO-1 sharded wrapper: the base optimizer's
-    state over THIS rank's flat 1/N parameter stripe — the whole point is
-    that no rank ever materializes the full-state pytree."""
-    base: Any
-
-
-def _zero1_axis_size(axis_name):
-    """Axis size inside a mapped program (constant-folds at trace time) or,
-    for host-side ``init`` calls, from the initialized runtime."""
-    import jax.lax as lax
-    try:
-        return int(lax.axis_size(axis_name))
-    except Exception:  # noqa: BLE001 — not inside a mapped program
-        from . import runtime
-        if runtime.is_initialized():
-            return runtime.size()
-        raise RuntimeError(
-            "DistributedOptimizer(reduce_scatter=True) needs the axis size "
-            "to lay out the sharded state: call init()/update() inside the "
-            f"mapped program over {axis_name!r}, or hvd.init() first.")
-
-
 def _stripe_axis_size(axis_name, spec=None):
     """Size of the stripe (data) axis for the sharded-state layout.
 
-    Inside a mapped program this is the binding's extent, same as
-    :func:`_zero1_axis_size`. Host-side (a ``step.init`` call before the
-    program is traced) a multi-axis ``spec`` must NOT fall back to the
-    world size: the compiled step maps over the smallest runtime mesh
-    providing every spec axis (``_StepProgram._step_mesh``), where the
-    data axis spans world / (expert * model) devices — sizing the base
-    optimizer's state or the DCN residual by the world instead would lay
-    out 1/world stripes against the program's 1/axis_size scatter."""
+    Inside a mapped program this is the binding's extent (constant-folds
+    at trace time). Host-side (a ``step.init`` call before the program
+    is traced) it comes from the initialized runtime — and a multi-axis
+    ``spec`` must NOT take the world size there: the compiled step maps
+    over the smallest runtime mesh providing every spec axis
+    (``CompiledTrainStep._step_mesh``), where the data axis spans
+    world / (expert * model) devices — sizing the base optimizer's state
+    or the DCN residual by the world instead would lay out 1/world
+    stripes against the program's 1/axis_size scatter."""
     import jax.lax as lax
     try:
         return int(lax.axis_size(axis_name))
     except Exception:  # noqa: BLE001 — not inside a mapped program
         pass
-    if spec is not None and (spec.expert_axis is not None
-                             or spec.model_axis is not None):
-        from . import runtime
-        if runtime.is_initialized():
-            st = runtime.state()
-            req = spec.required_axes()
-            for mesh in (st.mesh, getattr(st, "expert_mesh", None),
-                         getattr(st, "model_mesh", None)):
-                if (mesh is not None and req.issubset(mesh.axis_names)
-                        and axis_name in mesh.axis_names):
-                    return int(dict(mesh.shape)[axis_name])
-    return _zero1_axis_size(axis_name)
-
-
-def _zero1(base, axis_name, average, compression):
-    """ZeRO-1 sharded-state wrapper: exchange gradients as
-    reduce-scatter, run the base optimizer on this rank's flat stripe
-    (1/N of the elements, 1/N of the state memory), allgather the
-    resulting *updates*. Wire volume per step equals one allreduce
-    (scatter half + gather half), but the reduction and the optimizer
-    math are each done once per element globally instead of N times,
-    and momenta/second-moments shard N-ways.
-
-    Constraints (documented in docs/performance.md): the base optimizer
-    must be elementwise over a flat parameter vector (sgd/momentum/adam
-    family — anything whose init is shape-driven zeros/counters), and the
-    gradients must genuinely vary over ``axis_name`` (the sharded-data
-    case; a VMA-typed pre-summed cotangent is rejected at trace time).
-    """
-    import jax.lax as lax
-
-    from .ops.collectives import _axes_tuple, _vma_checking
-    from .stats import record_jit_traced
-    comp = None if compression is Compression.none else compression
-    axes = _axes_tuple(axis_name)
-    if len(axes) != 1:
-        raise ValueError("reduce_scatter=True shards over exactly one mesh "
-                         f"axis; got {axis_name!r}")
-    axis = axes[0]
-
-    def _layout(leaves):
-        sizes = [int(np.prod(l.shape, dtype=np.int64)) for l in leaves]
-        return sizes, sum(sizes)
-
-    def init_fn(params):
-        leaves = jax.tree.leaves(params)
-        if not leaves:
-            return Zero1State(base=base.init(params))
-        _, total = _layout(leaves)
-        n = _zero1_axis_size(axis)
-        shard_len = -(-total // n)
-        acc_dt = jnp.result_type(*leaves)
-        # Stripe template: elementwise-optimizer inits are value-free
-        # (zeros_like momenta, scalar counts), so a zero stripe of the
-        # right length births the same state on every rank.
-        return Zero1State(base=base.init(jnp.zeros((shard_len,), acc_dt)))
-
-    def update_fn(updates, state, params=None):
-        leaves, treedef = jax.tree.flatten(updates)
-        if not leaves:
-            upd, new_base = base.update(updates, state.base, params)
-            return upd, Zero1State(base=new_base)
-        if _vma_checking(axis) and any(
-                axis not in jax.typeof(l).vma for l in leaves):
-            raise ValueError(
-                "DistributedOptimizer(reduce_scatter=True): some gradient "
-                "leaves are unvarying over the reduce axis (pre-psummed "
-                "cotangents of replicated params under check_vma=True). "
-                "The ZeRO-1 stripe layout needs uniformly varying "
-                "gradients; use DistributedGradientTransform("
-                "reduce_scatter=True) + an unsharded optimizer instead.")
-        sizes, total = _layout(leaves)
-        n = _zero1_axis_size(axis)
-        shard_len = -(-total // n)
-        padded = shard_len * n
-        acc_dt = jnp.result_type(*leaves)
-        flat_g = jnp.concatenate([l.reshape(-1).astype(acc_dt)
-                                  for l in leaves])
-        if padded != total:
-            flat_g = jnp.pad(flat_g, (0, padded - total))
-        ctx = None
-        if comp is not None:
-            flat_g, ctx = comp.compress(flat_g)
-        record_jit_traced("reducescatter_jit",
-                          int(flat_g.size) * jnp.dtype(flat_g.dtype).itemsize,
-                          axis_name)
-        g_shard = lax.psum_scatter(flat_g, axis, scatter_dimension=0,
-                                   tiled=True)
-        if comp is not None:
-            g_shard = comp.decompress(g_shard, ctx)
-        if average:
-            g_shard = (g_shard / n).astype(g_shard.dtype)
-        p_shard = None
-        if params is not None:
-            flat_p = jnp.concatenate([l.reshape(-1).astype(acc_dt)
-                                      for l in jax.tree.leaves(params)])
-            if padded != total:
-                flat_p = jnp.pad(flat_p, (0, padded - total))
-            p_shard = lax.dynamic_slice_in_dim(
-                flat_p, lax.axis_index(axis) * shard_len, shard_len)
-        u_shard, new_base = base.update(g_shard, state.base, p_shard)
-        record_jit_traced("allgather_jit",
-                          int(u_shard.size) * jnp.dtype(u_shard.dtype)
-                          .itemsize, axis_name)
-        flat_u = lax.all_gather(u_shard, axis, axis=0, tiled=True)
-        out, pos = [], 0
-        for leaf, sz in zip(leaves, sizes):
-            out.append(flat_u[pos:pos + sz].astype(leaf.dtype)
-                       .reshape(leaf.shape))
-            pos += sz
-        return jax.tree.unflatten(treedef, out), Zero1State(base=new_base)
-
-    # Tag for hvd.compiled_train_step: the reduce-scatter IS the update
-    # transform, so the compiled step runs it whole (no psum of its own).
-    update_fn._hvd_exchange = "zero1"
-    return optax.GradientTransformation(init_fn, update_fn)
+    from . import runtime
+    if not runtime.is_initialized():
+        raise RuntimeError(
+            "DistributedOptimizer(zero_stage>=1 / dcn_compression) needs "
+            "the axis size to lay out the sharded state: call "
+            "init()/update() inside the mapped program over "
+            f"{axis_name!r}, or hvd.init() first.")
+    if spec is not None and spec.shard_axes:
+        st = runtime.state()
+        req = spec.required_axes()
+        for mesh in (st.mesh, getattr(st, "expert_mesh", None),
+                     getattr(st, "model_mesh", None)):
+            if (mesh is not None and req.issubset(mesh.axis_names)
+                    and axis_name in mesh.axis_names):
+                return int(dict(mesh.shape)[axis_name])
+    return runtime.size()
 
 
 class ZeroShardState(NamedTuple):
@@ -428,7 +306,7 @@ class _ZeroCore:
     # ------------------------------------------------------------ layout
 
     def axis_size(self):
-        return _zero1_axis_size(self.axis)
+        return _stripe_axis_size(self.axis)
 
     def local_for(self, n):
         from .ops.collectives import normalize_dcn_local_size
@@ -590,39 +468,46 @@ class _ZeroCore:
         return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
 
-def _zero_sharded(base, axis_name, average, compression, zero_stage,
+def _zero_sharded(base, spec, compression=Compression.none,
                   dcn_compression="", dcn_local_size=0, bucket_bytes=None,
-                  exchange_buckets=None, spec=None):
-    """Generalized ZeRO sharded wrapper behind
-    ``DistributedOptimizer(zero_stage=...)``.
+                  exchange_buckets=None):
+    """The ZeRO-sharded product of ``DistributedOptimizer(zero_stage=1|2|3)``
+    for ``spec`` (a :class:`_ShardingSpec` with ``zero_stage >= 1``):
+    reduce-scatter the gradients over the data axis, run ``base`` on this
+    rank's flat 1/N stripe (1/N of the elements and of the state memory),
+    allgather the resulting *updates*. Wire volume per step equals one
+    allreduce, but the reduction and the optimizer math are each done
+    once per element globally instead of N times.
 
-    ``spec`` (a :class:`_ShardingSpec`) composes the stripe with
-    expert/model-sharded leaves: striping is orthogonal to the reduce
-    axes — every leaf is replicated across the data axis, so the flat
-    stripe layout is unchanged and each leaf is simply pre-reduced over
-    its remaining axes (and pre-divided by the rest of its averaging
-    denominator) before the flatten (:func:`_spec_pre_reduce`). With
-    ``spec=None`` (the 1-D ladder) the sequence is the legacy one,
-    byte-for-byte.
+    Striping is orthogonal to the reduce axes: every leaf is replicated
+    across the data axis, so one flat stripe layout serves dense, expert
+    and model leaves alike, and each leaf is pre-reduced over its
+    remaining axes (and pre-divided by the rest of its averaging
+    denominator) before the flatten (:func:`_spec_pre_reduce`; nothing
+    to do for a spec without expert/model keys).
 
-    zero_stage=1 is :func:`_zero1` numerics with the staged/bucketed
-    wire; zero_stage=2 adds bucket chunking (``bucket_bytes``) so
-    gradients only ever exist stripe-at-a-time between scatter and
-    apply; zero_stage=3 additionally tags the transform for parameter
-    sharding — USED STANDALONE (host path, or a user's own shard_map) it
-    behaves exactly like zero2 (full params in, full updates out; the
-    real stripe-resident parameter storage needs program-level buffer
-    control and lives in hvd.compiled_train_step, which detects the
-    ``zero3`` tag and compiles the gather-on-demand layout).
+    Stage 1 scatters the whole row at once; stage 2 scatters per bucket
+    (``bucket_bytes``) so gradients only ever exist stripe-at-a-time
+    between scatter and apply; stage 3 used STANDALONE (a user's own
+    shard_map) behaves exactly like stage 2 (full params in, full
+    updates out) — the stripe-resident parameter storage needs
+    program-level buffer control and lives in hvd.compiled_train_step,
+    which reads ``spec.zero_stage == 3`` and compiles the
+    gather-on-demand layout.
+
+    Constraints (docs/performance.md): ``base`` must be elementwise over
+    a flat parameter vector (sgd/momentum/adam family — anything whose
+    init is shape-driven zeros/counters), and the gradients must
+    genuinely vary over the data axis (a VMA-typed pre-summed cotangent
+    is rejected at trace time).
 
     ``dcn_compression`` ("bf16"/"int8") turns on the two-stage exchange:
     ICI at full precision, only the cross-host DCN hop compressed, with
     the error-feedback residual carried in :class:`ZeroShardState`.
     """
-    import jax.lax as lax
-
     from .ops.collectives import _vma_checking
-    core = _ZeroCore(axis_name, average, compression, dcn_compression,
+    zero_stage, average = spec.zero_stage, spec.average
+    core = _ZeroCore(spec.data_axes, average, compression, dcn_compression,
                      dcn_local_size, bucket_bytes,
                      chunked=zero_stage >= 2,
                      exchange_buckets=exchange_buckets)
@@ -679,12 +564,9 @@ def _zero_sharded(base, axis_name, average, compression, zero_stage,
                 "reduce_scatter=True) + an unsharded optimizer instead.")
         n = core.axis_size()
         acc_dt = jnp.result_type(*leaves)
-        pre = leaves
-        if spec is not None:
-            lspecs = spec.leaf_specs(updates, spec.known_axes)
-            pre = [_spec_pre_reduce(l.astype(acc_dt), ls, core.axis,
-                                    spec.average)
-                   for l, ls in zip(leaves, lspecs)]
+        lspecs = spec.leaf_specs(updates, spec.known_axes)
+        pre = [_spec_pre_reduce(l.astype(acc_dt), ls, core.axis, average)
+               for l, ls in zip(leaves, lspecs)]
         flat_g, total = core.flatten_pad(pre, acc_dt, n)
         g_stripe, new_residual = core.scatter(flat_g, state.residual, n)
         p_stripe = None
@@ -702,14 +584,10 @@ def _zero_sharded(base, axis_name, average, compression, zero_stage,
         return (jax.tree.unflatten(treedef, out),
                 ZeroShardState(base=new_base, residual=new_residual))
 
-    update_fn._hvd_exchange = ("spec" if spec is not None
-                               else f"zero{zero_stage}")
+    update_fn._hvd_exchange = "spec"
     update_fn._hvd_base = base
-    update_fn._hvd_average = average
-    update_fn._hvd_compression = compression
     update_fn._hvd_zero_core = core
-    if spec is not None:
-        update_fn._hvd_spec = spec
+    update_fn._hvd_spec = spec
     return optax.GradientTransformation(init_fn, update_fn)
 
 
@@ -717,163 +595,6 @@ class DcnExchangeState(NamedTuple):
     """State of the stage-0 DCN-compressed exchange transform: just the
     error-feedback residual (None when the DCN hop is lossless)."""
     residual: Any = None
-
-
-def _dcn_grad_exchange(axis_name, average, dcn_compression, dcn_local_size,
-                       bucket_bytes=None):
-    """Stage-0 form of the DCN-staged exchange: scatter + immediate
-    gather returns FULL exchanged gradients (an allreduce decomposition),
-    so any unsharded optimizer chains after it — this is how
-    ``dcn_compression`` toggles independently of the ZeRO ladder."""
-    core = _ZeroCore(axis_name, average, Compression.none, dcn_compression,
-                     dcn_local_size, bucket_bytes, chunked=True)
-
-    def init_fn(params):
-        leaves = jax.tree.leaves(params)
-        if not leaves:
-            return DcnExchangeState(residual=None)
-        total = sum(int(np.prod(l.shape, dtype=np.int64)) for l in leaves)
-        n = core.axis_size()
-        acc_dt = jnp.result_type(*leaves)
-        rlen = core.residual_len(total, n, jnp.dtype(acc_dt).itemsize)
-        return DcnExchangeState(
-            residual=jnp.zeros((rlen,), acc_dt) if rlen else None)
-
-    def update_fn(updates, state, params=None):
-        del params
-        leaves, treedef = jax.tree.flatten(updates)
-        if not leaves:
-            return updates, state
-        n = core.axis_size()
-        acc_dt = jnp.result_type(*leaves)
-        flat_g, total = core.flatten_pad(leaves, acc_dt, n)
-        stripe, new_residual = core.scatter(flat_g, state.residual, n)
-        flat = core.gather(stripe, int(flat_g.shape[0]), n)
-        out, pos = [], 0
-        for leaf in leaves:
-            sz = int(np.prod(leaf.shape, dtype=np.int64))
-            out.append(flat[pos:pos + sz].astype(leaf.dtype)
-                       .reshape(leaf.shape))
-            pos += sz
-        return (jax.tree.unflatten(treedef, out),
-                DcnExchangeState(residual=new_residual))
-
-    # inline: the exchange happens inside update(), the compiled step
-    # must run the chain whole and add no psum of its own.
-    update_fn._hvd_exchange = "inline"
-    return optax.GradientTransformation(init_fn, update_fn)
-
-
-class _MoECore:
-    """Static description of the expert-parallel (MoE) gradient exchange
-    over the 2-D ``(data, expert)`` mesh (docs/performance.md
-    "Expert-parallel MoE"). Hashable by identity — like
-    :class:`_ZeroCore` it rides lru-cache keys in the compiled-step
-    builder, and a new core (new optimizer) is a new program.
-
-    ``expert_keys`` name the expert-sharded leaves by tree-path
-    substring (matched against ``jax.tree_util.keystr``) — explicit, not
-    inferred, because dense towers reuse names like ``w1``/``w2``.
-    Expert leaves hold per-``expert_axis``-column shards (the
-    fake-replicated ``P()`` idiom under check_vma=False) and their
-    gradients are psummed over the DATA axes only; every other leaf is
-    replicated everywhere and psums over ALL axes. Averaging always
-    divides by the full world ``N = |data| * |expert|``: the backward
-    alltoall already delivered the row peers' cotangents into each
-    expert shard's gradient, so the data-axis psum completes the global
-    sum and 1/N finishes the same global mean the dense leaves get."""
-
-    def __init__(self, data_axes, expert_axis, expert_keys, average):
-        self.data_axes = ((data_axes,) if isinstance(data_axes, str)
-                          else tuple(data_axes))
-        self.expert_axis = str(expert_axis)
-        self.expert_keys = tuple(str(k) for k in expert_keys)
-        self.average = bool(average)
-        if not self.expert_keys:
-            raise ValueError(
-                "expert_keys must name at least one expert-sharded leaf "
-                "(tree-path substrings, e.g. ('moe',))")
-        if self.expert_axis in self.data_axes:
-            raise ValueError(
-                f"expert axis {self.expert_axis!r} collides with the data "
-                f"axes {self.data_axes!r}")
-        self.all_axes = self.data_axes + (self.expert_axis,)
-
-    def is_expert_path(self, path):
-        s = jax.tree_util.keystr(path)
-        return any(k in s for k in self.expert_keys)
-
-    def expert_mask(self, tree):
-        """Per-leaf expert/dense mask in tree-flatten order."""
-        return [self.is_expert_path(p)
-                for p, _ in jax.tree_util.tree_flatten_with_path(tree)[0]]
-
-    def world_size(self):
-        """Full 2-D world size (trace-time constant inside a mapped
-        program over all axes)."""
-        import jax.lax as lax
-        n = 1
-        for a in self.all_axes:
-            n *= int(lax.axis_size(a))
-        return n
-
-    def exchange_tree(self, updates, comp=None):
-        """Inline per-axis exchange (standalone use inside a caller's own
-        shard_map over both axes). The compiled step never calls this —
-        it psums each axis group's leaves itself
-        (ops/step_program.py)."""
-        import jax.lax as lax
-        paths_leaves, treedef = jax.tree_util.tree_flatten_with_path(
-            updates)
-        if not paths_leaves:
-            return updates
-        mask = [self.is_expert_path(p) for p, _ in paths_leaves]
-        leaves = [l for _, l in paths_leaves]
-        n = self.world_size()
-
-        def _reduce(g, axes):
-            ctx = None
-            if comp is not None:
-                g, ctx = comp.compress(g)
-            g = lax.psum(g, axes)
-            if comp is not None:
-                g = comp.decompress(g, ctx)
-            if self.average:
-                g = (g / n).astype(g.dtype)
-            return g
-
-        out = [_reduce(g, self.data_axes if m else self.all_axes)
-               for g, m in zip(leaves, mask)]
-        return jax.tree_util.tree_unflatten(treedef, out)
-
-
-def _moe_exchange(optimizer, axis_name=AXIS, expert_axis="ep",
-                  expert_keys=(), average=True,
-                  compression=Compression.none):
-    """Expert-parallel gradient exchange wrapper: chain the per-axis MoE
-    exchange (see :class:`_MoECore`) before ``optimizer``. Standalone it
-    exchanges inside ``update()`` and must run in a shard_map over both
-    mesh axes; ``hvd.compiled_train_step`` detects the ``"moe"`` tag,
-    runs the program over the runtime's expert mesh
-    (``hvd.expert_mesh()``), replaces the inline exchange with fused
-    per-axis psum rows, and reduces the guard health rows over
-    ``expert_axis`` so every rank gates identically."""
-    core = _MoECore(axis_name, expert_axis, expert_keys, average)
-    comp = None if compression is Compression.none else compression
-
-    def init_fn(params):
-        return optimizer.init(params)
-
-    def update_fn(updates, state, params=None):
-        exchanged = core.exchange_tree(updates, comp)
-        return optimizer.update(exchanged, state, params)
-
-    update_fn._hvd_exchange = "moe"
-    update_fn._hvd_base = optimizer
-    update_fn._hvd_average = average
-    update_fn._hvd_compression = compression
-    update_fn._hvd_moe_core = core
-    return optax.GradientTransformation(init_fn, update_fn)
 
 
 class _LeafSpec(NamedTuple):
@@ -897,35 +618,41 @@ def _axes_size_prod(axes):
 
 
 class _ShardingSpec:
-    """Per-leaf sharding spec: ONE description of how every parameter
-    leaf exchanges its gradient on an N-D mesh, unifying what used to be
-    five mutually-exclusive exchange tags (psum / zero1-3 / moe /
-    inline-dcn) into a single compile path (ops/step_program.py;
-    docs/performance.md "Composable parallelism").
+    """Per-leaf sharding spec: the ONE description of how every
+    parameter leaf exchanges its gradient on an N-D mesh — what it
+    reduces over, what it divides by, whether optimizer state (and
+    parameters) are striped, and whether a DCN link carries a residual.
+    ``DistributedOptimizer`` builds one for every configuration and
+    ``hvd.compiled_train_step`` compiles from it and from nothing else
+    (ops/step_program.py; docs/performance.md "Composable parallelism").
 
     For each leaf, derived from the key patterns against the ACTUAL
     program mesh axes (:meth:`leaf_specs`):
 
-    - expert leaves (``expert_keys`` tree-path substring match) reduce
-      over every axis except ``expert_axis`` and average by the full
-      world size (the backward alltoall pre-summed the expert peers);
+    - expert leaves (``expert_keys``, tree-path substrings matched
+      against ``jax.tree_util.keystr`` — explicit, not inferred, because
+      dense towers reuse names like ``w1``/``w2``) hold
+      per-``expert_axis``-column shards (the fake-replicated ``P()``
+      idiom under check_vma=False); they reduce over every axis except
+      ``expert_axis`` and average by the full world size (the backward
+      alltoall pre-summed the expert peers);
     - model/tensor-parallel leaves (``model_keys``) reduce over every
       axis except ``model_axis`` and average by the product of the axes
       they reduce over (their shards are genuinely distinct parameters);
     - dense leaves reduce over ALL mesh axes and average by the world.
 
-    ZeRO striping is orthogonal: every leaf — dense, expert, model — is
-    replicated across the data axis (expert/model leaves vary over their
-    own axis only), so one flat stripe over the data axis serves all of
-    them; the stripe scatter divides by the data-axis size and each leaf
-    is pre-reduced over its remaining axes and pre-divided by the rest
-    of its denominator first (:func:`_spec_pre_reduce`). On a 1-D mesh
-    both pre-steps vanish and the legacy single-axis sequences fall out
-    byte-for-byte.
+    ZeRO striping (``zero_stage`` 1-3) is orthogonal: every leaf —
+    dense, expert, model — is replicated across the data axis
+    (expert/model leaves vary over their own axis only), so one flat
+    stripe over the data axis serves all of them; the stripe scatter
+    divides by the data-axis size and each leaf is pre-reduced over its
+    remaining axes and pre-divided by the rest of its denominator first
+    (:func:`_spec_pre_reduce`). On a 1-D mesh both pre-steps vanish.
 
-    Instances are value objects hashable by identity — like
-    :class:`_ZeroCore`/:class:`_MoECore` they ride the compiled-step
-    builder's lru keys, so a new spec is a new program."""
+    Instances are value objects, fixed at construction (``label`` and
+    the hash key are computed there): equal fields compare and hash
+    equal, so two specs that say the same thing share one compiled
+    program in the step-program builder's lru and the engine cache."""
 
     def __init__(self, data_axes=AXIS, expert_axis=None, expert_keys=(),
                  model_axis=None, model_keys=(), average=True,
@@ -946,13 +673,16 @@ class _ShardingSpec:
             raise ValueError("expert_keys need an expert_axis")
         if self.model_keys and model_axis is None:
             raise ValueError("model_keys need a model_axis")
-        shard_axes = [a for a in (self.expert_axis, self.model_axis)
-                      if a is not None]
-        if len(set(shard_axes)) != len(shard_axes):
+        # The axes some leaves stay sharded over (empty: every leaf
+        # reduces over every axis and the flat data mesh serves).
+        self.shard_axes = tuple(a for a in (self.expert_axis,
+                                            self.model_axis)
+                                if a is not None)
+        if len(set(self.shard_axes)) != len(self.shard_axes):
             raise ValueError(
                 f"expert_axis and model_axis must differ, both are "
                 f"{self.expert_axis!r}")
-        for a in shard_axes:
+        for a in self.shard_axes:
             if a in self.data_axes:
                 raise ValueError(
                     f"sharded axis {a!r} collides with the data axes "
@@ -962,11 +692,25 @@ class _ShardingSpec:
         # exactly these axes). The compiled step classifies against the
         # actual step-mesh axes instead, which may include extra size-1
         # axes.
-        self.known_axes = (self.data_axes
-                           + ((self.expert_axis,) if self.expert_axis
-                              else ())
-                           + ((self.model_axis,) if self.model_axis
-                              else ()))
+        self.known_axes = self.data_axes + self.shard_axes
+        # Short name of the layout, for metrics, ``perf_signature`` and
+        # cache dumps (nothing branches on it): ``psum`` or ``zero<k>``,
+        # then ``+dcn`` / ``+ep`` / ``+tp`` for a stage-0 DCN link,
+        # expert leaves and model leaves.
+        self.label = "+".join(
+            ["psum" if self.zero_stage == 0 else f"zero{self.zero_stage}"]
+            + [tag for tag, on in (("dcn", self.dcn_link),
+                                   ("ep", self.expert_axis),
+                                   ("tp", self.model_axis)) if on])
+        self._key = (self.data_axes, self.expert_axis, self.expert_keys,
+                     self.model_axis, self.model_keys, self.average,
+                     self.zero_stage, self.dcn_link)
+
+    def __eq__(self, other):
+        return isinstance(other, _ShardingSpec) and self._key == other._key
+
+    def __hash__(self):
+        return hash(self._key)
 
     def required_axes(self):
         """Mesh axes a program running this spec must provide."""
@@ -1017,8 +761,7 @@ def _spec_pre_reduce(g, lf, stripe_axis, average):
     axis, and apply the part of the averaging divisor the stripe scatter
     won't (the scatter divides by the stripe-axis size uniformly, so the
     leaf arrives pre-divided by ``denom / |stripe_axis|``). On a 1-D
-    mesh both steps are no-ops — the legacy single-axis stripe sequence
-    is unchanged byte-for-byte."""
+    mesh both steps are no-ops."""
     import jax.lax as lax
     extra = tuple(a for a in lf.reduce if a != stripe_axis)
     if extra:
@@ -1034,21 +777,20 @@ def _spec_grad_exchange(spec, compression=Compression.none,
                         dcn_compression="", dcn_local_size=0,
                         bucket_bytes=None):
     """Stage-0 per-leaf spec exchange: psum each gradient leaf over its
-    spec'd reduce axes and divide by its spec'd denominator — the
-    composable generalization of :func:`DistributedGradientTransform`
-    (dense), :meth:`_MoECore.exchange_tree` (expert) and
-    :func:`_dcn_grad_exchange` (staged DCN) in one transform. Standalone
-    it exchanges inside ``update()`` within a shard_map over
+    spec'd reduce axes and divide by its spec'd denominator — the link
+    ``DistributedOptimizer`` chains before the base optimizer when the
+    spec names expert/model leaves or a DCN link. Standalone it
+    exchanges inside ``update()`` within a shard_map over
     ``spec.known_axes``; the compiled step decomposes it into
     per-group psums unless the DCN residual forces running whole
     (``spec.dcn_link``).
 
     With ``dcn_compression`` set, every leaf is pre-reduced over its
     non-data axes (:func:`_spec_pre_reduce`), then the whole tree rides
-    the staged scatter+gather over the data axis with the error-feedback
-    residual carried in :class:`DcnExchangeState` — the stage-0 DCN wire
-    of :func:`_dcn_grad_exchange`, now composable with expert/model
-    sharded leaves."""
+    the staged scatter + immediate gather over the data axis (an
+    allreduce decomposition: full exchanged gradients come out, so any
+    unsharded optimizer chains after it) with the error-feedback
+    residual carried in :class:`DcnExchangeState`."""
     import jax.lax as lax
     comp = None if compression is Compression.none else compression
     core = None
@@ -1109,10 +851,8 @@ def _spec_grad_exchange(spec, compression=Compression.none,
                 DcnExchangeState(residual=new_residual))
 
     # inline: standalone, the exchange happens inside update(); the
-    # spec-aware chain wrapper in DistributedOptimizer re-tags the chain
-    # as "spec" for the compiled step.
+    # chain DistributedOptimizer wraps around it carries the spec.
     update_fn._hvd_exchange = "inline"
-    update_fn._hvd_spec = spec
     return optax.GradientTransformation(init_fn, update_fn)
 
 
@@ -1167,8 +907,8 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
     - ``1`` — optimizer-state sharding: gradients ride a reduce-scatter,
       the base optimizer updates this rank's flat 1/N stripe (momenta and
       second moments shard N-ways), an allgather of the updates replaces
-      the allreduce's second half. ``reduce_scatter=True`` is the legacy
-      spelling of this stage.
+      the allreduce's second half. ``reduce_scatter=True`` is the
+      reference's spelling of this stage.
     - ``2`` — gradient sharding: same wire shape, but the scatter runs
       per bucket (``bucket_bytes``, default HOROVOD_REDUCE_SCATTER_BUCKET)
       so the full-gradient row never persists — inside the compiled step
@@ -1176,7 +916,7 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
     - ``3`` — parameter sharding: params live as stripes and are
       allgathered on demand. The transform used standalone behaves like
       zero2 (see :func:`_zero_sharded`); ``hvd.compiled_train_step``
-      detects the tag and compiles the true stripe-resident layout with
+      reads the stage and compiles the true stripe-resident layout with
       donated stripe buffers (its ``shard_params``/``unshard_params``
       convert between full and striped storage).
 
@@ -1196,7 +936,7 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
     ``(axis_name, expert_axis)`` mesh: the named expert leaves stay
     sharded over ``expert_axis`` and their gradients psum over the data
     axis only, everything else psums over both axes (see
-    :class:`_MoECore`; docs/performance.md "Expert-parallel MoE").
+    :class:`_ShardingSpec`; docs/performance.md "Expert-parallel MoE").
     Requires ``HOROVOD_EXPERT_PARALLEL > 1`` at ``hvd.init()`` so the
     expert mesh exists.
 
@@ -1209,10 +949,15 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
     except ``model_axis`` and average by the axes they reduce over.
 
     Expert keys, model keys, the ZeRO ladder and ``dcn_compression``
-    now COMPOSE: any combination builds one per-leaf
-    :class:`_ShardingSpec` that ``hvd.compiled_train_step`` compiles
-    into a single donated program (docs/performance.md "Composable
-    parallelism"). Striping runs over the data axis for every leaf —
+    COMPOSE: every configuration — none of them given included — builds
+    one per-leaf :class:`_ShardingSpec` (carried as ``_hvd_spec`` on the
+    returned transform's ``update``) that ``hvd.compiled_train_step``
+    compiles into a single donated program (docs/performance.md
+    "Composable parallelism"). Stage 0 returns ``optax.chain(<exchange
+    link>, optimizer)``, the link being
+    :func:`DistributedGradientTransform` unless the spec names
+    expert/model leaves or a DCN link; stages 1-3 return
+    :func:`_zero_sharded`. Striping runs over the data axis for every leaf —
     expert/model leaves are replicated across it — so e.g.
     ``expert_keys + zero_stage=2 + dcn_compression`` trains
     expert-parallel FFNs with ZeRO-striped state and a compressed DCN
@@ -1241,99 +986,37 @@ def DistributedOptimizer(optimizer, named_parameters=None, axis_name=AXIS,
         raise ValueError(
             "dcn_compression already defines the wire precision of the "
             "compressed hop — combine it with compression=Compression.none")
-    has_expert = bool(expert_keys)
-    has_model = bool(model_keys)
-    if has_expert and not has_model and zero_stage == 0 \
-            and not dcn_compression:
-        # Pure expert parallelism: the original MoE exchange, kept
-        # byte-identical (the spec path below generalizes it and lands
-        # on the same collectives, but this transform is pinned by
-        # tests/test_moe.py's bitwise step-program identity tests).
-        metrics.ZERO_STAGE.set(0)
-        tx = _moe_exchange(optimizer, axis_name=axis_name,
-                           expert_axis=expert_axis,
-                           expert_keys=expert_keys, average=average,
-                           compression=compression)
-        if backward_passes_per_step > 1:
-            tx = optax.MultiSteps(tx,
-                                  every_k_schedule=backward_passes_per_step)
-        return tx
-    if has_expert or has_model:
-        # Composable parallelism: one per-leaf spec covers every
-        # expert/model/ZeRO/DCN combination in a single exchange.
-        spec = _ShardingSpec(
-            data_axes=axis_name,
-            expert_axis=expert_axis if has_expert else None,
-            expert_keys=tuple(expert_keys or ()),
-            model_axis=model_axis if has_model else None,
-            model_keys=tuple(model_keys or ()),
-            average=average, zero_stage=zero_stage,
-            dcn_link=bool(dcn_compression) and zero_stage == 0)
-        metrics.ZERO_STAGE.set(zero_stage)
-        if zero_stage == 0:
-            tx = optax.chain(
-                _spec_grad_exchange(spec, compression=compression,
-                                    dcn_compression=dcn_compression,
-                                    dcn_local_size=dcn_local_size,
-                                    bucket_bytes=bucket_bytes),
-                optimizer,
-            )
-            # Tags for hvd.compiled_train_step: the compiled path
-            # decomposes this wrapper per the spec — fused per-group
-            # psums replace the exchange link and only the base
-            # optimizer's math runs inside the program (the staged DCN
-            # hop, when present, keeps the chain inline instead).
-            tx.update._hvd_exchange = "spec"
-            tx.update._hvd_base = optimizer
-            tx.update._hvd_average = average
-            tx.update._hvd_compression = compression
-            tx.update._hvd_spec = spec
-        else:
-            tx = _zero_sharded(optimizer, axis_name=axis_name,
-                               average=average, compression=compression,
-                               zero_stage=zero_stage,
-                               dcn_compression=dcn_compression,
-                               dcn_local_size=dcn_local_size,
-                               bucket_bytes=bucket_bytes,
-                               exchange_buckets=exchange_buckets,
-                               spec=spec)
-        if backward_passes_per_step > 1:
-            tx = optax.MultiSteps(tx,
-                                  every_k_schedule=backward_passes_per_step)
-        return tx
+    spec = _ShardingSpec(
+        data_axes=axis_name, expert_axis=expert_axis,
+        expert_keys=expert_keys, model_axis=model_axis,
+        model_keys=model_keys, average=average, zero_stage=zero_stage,
+        dcn_link=bool(dcn_compression) and zero_stage == 0)
     metrics.ZERO_STAGE.set(zero_stage)
     if zero_stage == 0:
-        if dcn_compression:
-            tx = optax.chain(
-                _dcn_grad_exchange(axis_name, average, dcn_compression,
-                                   dcn_local_size, bucket_bytes),
-                optimizer,
-            )
-            # inline: the chain's first link exchanges inside update();
-            # the compiled step runs the whole chain, no psum of its own.
-            tx.update._hvd_exchange = "inline"
+        if spec.shard_axes or spec.dcn_link:
+            link = _spec_grad_exchange(spec, compression=compression,
+                                       dcn_compression=dcn_compression,
+                                       dcn_local_size=dcn_local_size,
+                                       bucket_bytes=bucket_bytes)
         else:
-            tx = optax.chain(
-                DistributedGradientTransform(axis_name=axis_name,
-                                             average=average,
-                                             compression=compression),
-                optimizer,
-            )
-            # Tags for hvd.compiled_train_step (ops/step_program.py): the
-            # compiled path decomposes this wrapper — its in-graph psum
-            # replaces the DistributedGradientTransform link and only
-            # the base optimizer's math runs inside the program.
-            tx.update._hvd_exchange = "psum"
-            tx.update._hvd_base = optimizer
-            tx.update._hvd_average = average
-            tx.update._hvd_compression = compression
-    elif zero_stage == 1 and not dcn_compression and bucket_bytes is None:
-        # legacy ZeRO-1 path, byte-identical to reduce_scatter=True
-        tx = _zero1(optimizer, axis_name=axis_name, average=average,
-                    compression=compression)
+            # the reference's public transform: VMA-aware, which the
+            # spec link's plain psum is not (tests/test_vma_semantics.py)
+            link = DistributedGradientTransform(axis_name=axis_name,
+                                                average=average,
+                                                compression=compression)
+        tx = optax.chain(link, optimizer)
+        # Tags for hvd.compiled_train_step (ops/step_program.py): it
+        # reads the spec, replaces the link with per-group psums and
+        # runs only the base optimizer's math in the program (a DCN
+        # link's residual lives in the chain's state, so that chain
+        # runs whole).
+        tx.update._hvd_exchange = "spec"
+        tx.update._hvd_base = optimizer
+        tx.update._hvd_average = average
+        tx.update._hvd_compression = compression
+        tx.update._hvd_spec = spec
     else:
-        tx = _zero_sharded(optimizer, axis_name=axis_name, average=average,
-                           compression=compression, zero_stage=zero_stage,
+        tx = _zero_sharded(optimizer, spec, compression=compression,
                            dcn_compression=dcn_compression,
                            dcn_local_size=dcn_local_size,
                            bucket_bytes=bucket_bytes,

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 import jax
+import jax.numpy as jnp
 
 import horovod_tpu as hvd
 from horovod_tpu.autotune import ParameterManager
@@ -64,6 +65,19 @@ def test_device_resident_matches_host_path(hvd_init):
             np.testing.assert_allclose(got, host, rtol=rtol, atol=1e-6), tag
         else:
             np.testing.assert_array_equal(got, host), tag
+
+
+@pytest.mark.parametrize("to_host", [True, False])
+def test_bf16_average_is_a_float_mean(hvd_init, to_host):
+    """numpy does not count bfloat16 among its floating types; an
+    average that asked it floor-divided (2.5 -> 2.0). Both unfuse paths
+    ask jnp."""
+    data = jnp.asarray([2.5, -1.5, 0.75, 3.0], jnp.bfloat16)
+    out = hvd.allreduce(np.asarray(data), average=True,
+                        name=f"dr.bf16.avg.{to_host}", to_host=to_host)
+    assert out.dtype == jnp.bfloat16
+    np.testing.assert_array_equal(np.asarray(out, np.float32),
+                                  np.asarray(data, np.float32))
 
 
 def test_device_resident_per_rank_divergent(hvd_init):

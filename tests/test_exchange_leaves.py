@@ -89,20 +89,34 @@ def _row_exchange(leaves, axes, average, comp, n, buckets):
             group = [i for i in idxs if wire[i].dtype.name == name]
             segs, off = [], 0
             for i in group:
-                # numpy does not count bfloat16 among its floating types,
-                # so the row would floor-divide it: divided below instead
-                avg = average and leaves[i].dtype != jnp.bfloat16
                 segs.append((off, leaves[i].size, tuple(leaves[i].shape),
-                             leaves[i].dtype.name, avg, None))
+                             leaves[i].dtype.name, average, None))
                 off += leaves[i].size
             row = lax.psum(jnp.concatenate(
                 [wire[i].reshape(-1) for i in group]), axes)
             res, hr = unfuse_segments(row, segs, n), segment_health(row, segs)
             for k, i in enumerate(group):
                 out[i], hrows[i] = res[k], hr[k]
-                if average and not segs[k][4]:
-                    out[i] = (res[k] / n).astype(res[k].dtype)
     return out, jnp.stack(hrows)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float16", "float32",
+                                   "int32"])
+def test_unfuse_segments_average_rounds_once(dtype):
+    """A float segment's average is the mean rounded once to the
+    segment's type — bfloat16 too, which numpy's ``issubdtype`` does not
+    count as floating and the row used to floor-divide; an integer
+    segment floor-divides."""
+    rng = np.random.RandomState(3)
+    a, b = (jnp.asarray(rng.randn(12) * 7, dtype) for _ in range(2))
+    (got,) = unfuse_segments(a + b, ((0, 12, (3, 4), dtype, True, None),), 2)
+    if dtype == "int32":
+        want = (a + b) // 2
+    else:
+        want = ((a.astype(jnp.float32) + b.astype(jnp.float32)) / 2
+                ).astype(dtype)
+    assert got.dtype == want.dtype and got.shape == (3, 4)
+    np.testing.assert_array_equal(np.asarray(got).ravel(), np.asarray(want))
 
 
 def _leaves(kind, ranks):

@@ -353,7 +353,7 @@ def test_moe_compiled_step_cache_hit_rate(monkeypatch):
     tx = hvd.DistributedOptimizer(optax.sgd(0.05),
                                   expert_keys=("w1", "w2"))
     step = hvd.compiled_train_step(_moe_loss(cfg, chunks=2), tx)
-    assert step._exchange == "moe"
+    assert step._exchange == "psum+ep"
     params = _expert_params(cfg, hvd.expert_mesh())
     _, losses = _run_moe_compiled(step, params, 10, cfg)
     assert all(np.isfinite(l) for l in losses), losses
@@ -363,7 +363,7 @@ def test_moe_compiled_step_cache_hit_rate(monkeypatch):
 
 
 def test_moe_guard_program_identical_without_fault(monkeypatch):
-    """HOROVOD_GUARD=1 composes with exchange='moe': expert-leaf health
+    """HOROVOD_GUARD=1 composes with expert leaves: their health
     reduces over ep so every rank takes the same skip decision, and with
     no fault the guarded trajectory is BIT-IDENTICAL to the plain one;
     finish() folds the deferred verdict (ok, apply)."""
