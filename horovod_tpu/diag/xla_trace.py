@@ -177,15 +177,17 @@ def build_op_phase_map(hlo_text):
             if row[2]}
 
 
-def live_hlo(module_names=None):
+def live_hlo(module_names=None, executables=None):
     """``{module name: HLO text}`` of the executables this process holds
-    (``client.live_executables()``): the programs that RAN, so the
-    instruction names are the trace's and no further compile is paid.
-    ``module_names`` keeps only those (a big program's text is tens of
-    MB; only the traced ones are stringified)."""
+    (``client.live_executables()``, or just ``executables`` of them): the
+    programs that RAN, so the instruction names are the trace's and no
+    further compile is paid. ``module_names`` keeps only those (a big
+    program's text is MBs; only the ones asked for are stringified)."""
     import jax
     out = {}
-    for exe in jax.devices()[0].client.live_executables():
+    if executables is None:
+        executables = jax.devices()[0].client.live_executables()
+    for exe in executables:
         try:
             module = exe.hlo_modules()[0]
             if module_names is None or module.name in module_names:
@@ -193,6 +195,61 @@ def live_hlo(module_names=None):
                                     + module.to_string())
         except Exception:  # noqa: BLE001 - an executable without HLO
             continue
+    return out
+
+
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+# replica groups of one device each, listed or as an iota: the
+# all-reduce of an axis of size 1, which a backend may leave in the text
+_ALONE_RE = re.compile(r"replica_groups=(?:\{(?:\{\d+\},?)+\}|\[\d+,1\]<=)")
+
+
+def exchange_async(hlo_text):
+    """What the compiler made of a step program's all-reduces, read from
+    its optimized HLO: ``{"all_reduces", "async_all_reduces", "bytes",
+    "async_bytes", "async_bytes_share"}``. An all-reduce is asynchronous
+    where it can run beside compute: on a TPU inside an
+    ``async_collective_fusion`` computation (ONE all-reduce fused with a
+    compute fusion, ops/step_program.py), elsewhere as an
+    ``all-reduce-start``. Everything else — in ENTRY, a loop body or a
+    plain fusion — holds the core for as long as it travels. The
+    ``async-collective-start`` / ``-done`` fusions that bracket a fused
+    all-reduce repeat its instruction; they are not counted again, and
+    an all-reduce over groups of one device exchanges nothing and is not
+    counted at all. The share is 0.0 where nothing is all-reduced (one
+    device)."""
+    comp, rows, caller = None, [], {}
+    for line in (hlo_text or "").splitlines():
+        if not line.startswith(" "):
+            m = _COMPUTATION_RE.match(line)
+            comp = m.group(1) if m else comp
+            continue
+        inst = parse_instruction(line)
+        if inst is None:
+            continue
+        name, opcode, shape = inst
+        if opcode == "fusion":
+            m = _CALLS_RE.search(line)
+            if m:
+                caller[m.group(1)] = name
+        elif (_collective(opcode) == "all-reduce"
+              and not _ALONE_RE.search(line)):
+            rows.append((comp or "", opcode, shape_bytes(shape)))
+    out = {"all_reduces": 0, "async_all_reduces": 0, "bytes": 0,
+           "async_bytes": 0}
+    for comp, opcode, nbytes in rows:
+        if caller.get(comp, "").startswith(("async-collective-start",
+                                            "async-collective-done")):
+            continue
+        out["all_reduces"] += 1
+        out["bytes"] += nbytes
+        if (comp.startswith("async_collective_fusion")
+                or opcode == "all-reduce-start"):
+            out["async_all_reduces"] += 1
+            out["async_bytes"] += nbytes
+    out["async_bytes_share"] = (out["async_bytes"] / out["bytes"]
+                                if out["bytes"] else 0.0)
     return out
 
 

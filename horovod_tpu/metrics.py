@@ -622,6 +622,23 @@ STEP_FLOPS_TOTAL = _registry.counter(
     "Cumulative whole-program FLOPs executed by the compiled hot loop, "
     "from XLA cost_analysis on each step-program signature (all chips; "
     "divide by hvd_ranks for per-chip work).")
+EXCHANGE_ALL_REDUCES = _registry.gauge(
+    "hvd_exchange_all_reduces",
+    "All-reduces in the optimized HLO of the most recently compiled step "
+    "program (what XLA's combiner made of the gradient leaves, the loss "
+    "and the guard's health sums); 0 on one device.")
+EXCHANGE_ASYNC_ALL_REDUCES = _registry.gauge(
+    "hvd_exchange_async_all_reduces",
+    "Of hvd_exchange_all_reduces, those that can run beside compute: "
+    "inside an async_collective_fusion on a TPU (docs/performance.md "
+    "\"What the step asks of the compiler\"), all-reduce-start "
+    "elsewhere.")
+EXCHANGE_ASYNC_BYTES_SHARE = _registry.gauge(
+    "hvd_exchange_async_bytes_share",
+    "Share (0..1) of the all-reduced bytes of the most recently compiled "
+    "step program that travel in asynchronous all-reduces; the rest "
+    "holds the core for as long as it travels. 0.0 on one device, where "
+    "nothing is all-reduced.")
 STEP_MFU = _registry.gauge(
     "hvd_step_mfu",
     "Model FLOPs utilization of the most recent compiled step: "

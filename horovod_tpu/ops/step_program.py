@@ -575,10 +575,79 @@ def _build_step_program(mesh, loss_fn, tx, nbatch, exchange, average,
     fn = jax.shard_map(_spec_shard, mesh=mesh,
                        in_specs=(P(), P()) + (batch_spec,) * nbatch,
                        out_specs=P(), check_vma=False)
-    return jax.jit(fn, donate_argnums=(0, 1) if donate else ())
+    # compiler_options=None is the bare jit: a one-device or CPU step
+    # compiles exactly as it did without them
+    return jax.jit(fn, donate_argnums=(0, 1) if donate else (),
+                   compiler_options=(
+                       _exchange_compiler_options(mesh, exchange) or None))
 
 
 register_wire_program_builder(_build_step_program)
+
+
+# ------------------------------------------- what the step asks of XLA
+#
+# On a TPU an all-reduce runs beside compute only if BOTH of these are
+# set (libtpu 0.0.34: either alone leaves every all-reduce synchronous),
+# and then only as an `async_collective_fusion`: ONE all-reduce fused with
+# one compute fusion, in the step a weight-gradient matmul. The compiler
+# fuses single-operand all-reduces only, so whatever its combiner has
+# packed into a variadic all-reduce stays synchronous and exposed.
+_ASYNC_ALL_REDUCE = (
+    ("xla_enable_async_all_reduce", True),
+    ("xla_tpu_enable_async_collective_fusion_fuse_all_reduce", True),
+)
+# Hence the combiner's threshold: leaves above it travel alone and can
+# fuse, the small ones still share one all-reduce (each all-reduce has a
+# fixed cost). 32 MiB is the chip's answer (cgpt13b_dp4 on a v5e 2x2,
+# PERF.md section 6 PR 30): 63 % of the gradient's bytes fused and the
+# step 473.1 -> 453.5 ms; 64 MiB fuses 51 % (457.3 ms), 128 MiB and the
+# compiler's default 19-22 % (461.7 / 463.2 ms); 48 MiB fuses no more
+# than 32 and compiles to a higher peak, 24 and 16 MiB no longer fit the
+# chip. The name is internal to the compiler, so a libtpu may refuse it;
+# the step then compiles with the two public options alone
+# (``_compiler_accepts``).
+_COMBINER_THRESHOLD = ("xla_jf_crs_combiner_threshold_in_bytes", 32 << 20)
+
+
+@functools.lru_cache(maxsize=None)
+def _compiler_accepts(device, option):
+    """Whether ``device``'s compiler knows ``option`` (a ``(name,
+    value)`` pair): a one-scalar compile, once per process."""
+    from ..utils.logging import get_logger
+    x = jax.ShapeDtypeStruct((), jnp.float32,
+                             sharding=jax.sharding.SingleDeviceSharding(
+                                 device))
+    try:
+        jax.jit(lambda a: a,
+                compiler_options=dict([option])).lower(x).compile()
+    except Exception as e:  # noqa: BLE001 - any refusal means "no"
+        if "No such compile option" not in str(e):
+            raise
+        get_logger().warning(
+            "this libtpu has no compile option %r: the step's large "
+            "gradient all-reduces stay combined, and fewer of them "
+            "overlap the backward", option[0])
+        return False
+    return True
+
+
+def _exchange_compiler_options(mesh, exchange):
+    """The ``compiler_options`` of a step program's jit, derived from
+    what the builder can see and from nothing else: ``{}`` (the bare
+    jit) unless the program exchanges (``exchange="psum"``) over more
+    than one TPU device — on one device there is no all-reduce, and the
+    CPU backend knows none of these names. Dense leaves and the loss
+    reduce over every mesh axis, so "some reduced axis is larger than
+    one" is "the mesh has more than one device"."""
+    devices = mesh.devices
+    if (exchange != "psum" or devices.size < 2
+            or devices.flat[0].platform != "tpu"):
+        return {}
+    options = dict(_ASYNC_ALL_REDUCE)
+    if _compiler_accepts(devices.flat[0], _COMBINER_THRESHOLD):
+        options.update([_COMBINER_THRESHOLD])
+    return options
 
 
 def engine_cached_program(signature, build):
@@ -756,6 +825,8 @@ class CompiledTrainStep:
         self._flops = {}   # signature -> whole-program FLOPs
         self._calls = 0    # the `step` span's ordinal
         self.flops_per_step = 0.0
+        # xla_trace.exchange_async of the newest signature's executable
+        self.exchange_async = None
         self.cache_hits = 0
         self.cache_misses = 0
         self.compiled_steps = 0
@@ -984,6 +1055,27 @@ class CompiledTrainStep:
         except Exception:  # noqa: BLE001 - introspection is best-effort
             return 0.0
 
+    def _read_exchange_async(self, prog, client, before):
+        """Once per signature, after its first execution: what the
+        compiler made of the program's all-reduces
+        (``xla_trace.exchange_async``), read from the optimized HLO of
+        the executable that ran — the one ``before`` (the client's
+        executables before that execution) lacks — and published on the
+        step object and as the ``hvd_exchange_*`` gauges. No compile, no
+        device work; span ``step.read_hlo``."""
+        try:
+            live = client.live_executables()
+            new = [e for e in live if e not in before]
+            texts = xla_trace.live_hlo({f"jit_{prog.__name__}"},
+                                       new or live)
+            stats = xla_trace.exchange_async("".join(texts.values()))
+        except Exception:  # noqa: BLE001 - introspection is best-effort
+            return
+        self.exchange_async = stats
+        metrics.EXCHANGE_ALL_REDUCES.set(stats["all_reduces"])
+        metrics.EXCHANGE_ASYNC_ALL_REDUCES.set(stats["async_all_reduces"])
+        metrics.EXCHANGE_ASYNC_BYTES_SHARE.set(stats["async_bytes_share"])
+
     def _flush_guard(self, monitor):
         """Fold the PREVIOUS compiled step's in-graph health matrix and
         run its policy ladder (deferred-by-one so the readback happens
@@ -1062,11 +1154,14 @@ class CompiledTrainStep:
         tracer = xla_trace.get()
         scope = (jax.enable_x64() if _needs_x64(params, opt_state, batch)
                  else contextlib.nullcontext())
+        first = flops is None
         with scope:
-            if flops is None:
+            if first:
                 with span("step.analyze"):
                     flops = self._flops[sig] = self._analyze(
                         prog, params, opt_state, batch)
+                    client = mesh.devices.flat[0].client
+                    before = client.live_executables()
             if tracer is not None:
                 # `params` is the previous step's output: waiting for it
                 # drains the device (only at the two ends of a capture)
@@ -1074,6 +1169,9 @@ class CompiledTrainStep:
                     jax.block_until_ready, params))
             with span("step.execute", step_trace=self._calls):
                 outs = prog(params, opt_state, *batch)
+            if first:
+                with span("step.read_hlo"):
+                    self._read_exchange_async(prog, client, before)
         metrics.STEP_COMPILED_TOTAL.inc()
         self.compiled_steps += 1
         if flops:

@@ -1,6 +1,9 @@
 """The main path's kernels — flash attention and the sparse layers' grouped
 matmul — compile for a described v5e (Mosaic + the XLA TPU compiler, no chip
-attached) at the benchmark cells' shapes.
+attached) at the benchmark cells' shapes; and the compiled step, built for
+the four described chips of a v5e:2x2, comes out of the compiler with
+gradient all-reduces fused into the backward's matmuls (ops/step_program.py
+``_exchange_compiler_options``).
 
 Interpret mode cannot see what the chip's compiler refuses — a slice off
 the (8, 128) tiling, more VMEM than a kernel may take — and these compiles
@@ -9,19 +12,29 @@ nothing about results or times. One file, one fixture: only the worker that
 is handed this file loads libtpu.
 """
 
+import logging
 import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+import optax
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
+import horovod_tpu as hvd
+from horovod_tpu.diag import xla_trace
+from horovod_tpu.models import moe
+from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops import step_program
 from horovod_tpu.ops.flash_attention import flash_attention
 from horovod_tpu.ops.grouped_matmul import grouped_matmul
+from horovod_tpu.utils.logging import get_logger
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
     # libtpu logs to /tmp/tpu_logs unless told not to: nothing of a test
@@ -37,9 +50,14 @@ def one_chip():
     was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was_on)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 # (B, S, q heads, kv heads, D, window): what the benchmark's cells trace,
@@ -107,3 +125,142 @@ def test_grouped_matmul_vjp_compiles_for_v5e(one_chip, shape):
     calls = [l for l in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in l]
     assert len(calls) == 3 and all("hvd_gmm" in l for l in calls)
+
+
+# ------------------------------------------------ the step across four chips
+
+# Cerebras-GPT-1.3B's widths (cgpt13b_dp4), two layers, seq 512
+_STEP_CFG = tfm.TransformerConfig(
+    vocab_size=50257, d_model=2048, n_heads=16, n_layers=2, d_ff=8192,
+    max_seq=512, dtype=jnp.bfloat16, attention_impl="flash",
+    flash_interpret=False, positional="learned", loss_chunk=512)
+
+
+def _shaped(tree, sharding):
+    return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), tree)
+
+
+def _compile_step(mesh, tx, spec=None, state=None, cfg=_STEP_CFG):
+    """``_build_step_program``'s program for ``mesh``, lowered on shapes
+    and compiled for its described chips."""
+    axes = tfm.ShardAxes(dp=None, sp=None, tp=None)
+
+    def loss_fn(p, tokens, targets):
+        return tfm.loss_fn(p, tokens, targets, cfg, axes)
+
+    rep = NamedSharding(mesh, P())
+    params = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    if state is None:
+        state = jax.eval_shape(tx.init, params)
+    tok = jax.ShapeDtypeStruct((2 * mesh.size, 512), jnp.int32,
+                               sharding=NamedSharding(mesh, P("hvd")))
+    prog = step_program._build_step_program(
+        mesh, loss_fn, tx, 2, "psum", True, None, False, True, False, None,
+        1, spec)
+    return prog.lower(_shaped(params, rep), _shaped(state, rep), tok,
+                      tok).compile()
+
+
+def test_step_on_four_chips_fuses_all_reduces_with_the_backward(topo):
+    mesh = Mesh(np.array(topo.devices[:4]), ("hvd",))
+    options = step_program._exchange_compiler_options(mesh, "psum")
+    assert options == dict(step_program._ASYNC_ALL_REDUCE
+                           + (step_program._COMBINER_THRESHOLD,))
+    text = _compile_step(mesh, optax.adamw(3e-4)).as_text()
+    fused = [block for block in text.split("\n\n")
+             if block.lstrip("%").startswith("async_collective_fusion")
+             and " all-reduce(" in block]
+    assert fused
+    got = xla_trace.exchange_async(text)
+    assert got["async_all_reduces"] == len(fused)
+    assert 0.0 < got["async_bytes_share"] <= 1.0
+    grad_bytes = 4 * sum(int(np.prod(a.shape)) for a in jax.tree.leaves(
+        jax.eval_shape(lambda k: tfm.init_params(k, _STEP_CFG),
+                       jax.random.PRNGKey(0))))
+    assert got["bytes"] == grad_bytes + 4  # + the loss's pmean
+
+
+def test_step_on_one_chip_is_the_bare_jit(topo):
+    mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
+    assert step_program._exchange_compiler_options(mesh, "psum") == {}
+    text = _compile_step(mesh, optax.adamw(3e-4)).as_text()
+    assert " all-reduce" not in text
+    assert "async_collective_fusion" not in text
+    assert xla_trace.exchange_async(text)["all_reduces"] == 0
+
+
+def test_step_with_striped_state_compiles_on_four_chips(topo):
+    """``zero_stage=1``: the flat stripe's reduce-scatter and all-gather
+    carry the exchange, under the same options. A quarter of the width:
+    at Cerebras' the flat stripe takes XLA eight minutes to compile,
+    with or without the options (PERF.md section 6 PR 30)."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=8192, d_model=512, n_heads=4, n_layers=2, d_ff=2048,
+        max_seq=512, dtype=jnp.bfloat16, attention_impl="flash",
+        flash_interpret=False, positional="learned", loss_chunk=512)
+    hvd.init(num_ranks=4)
+    try:
+        tx = hvd.DistributedOptimizer(optax.adamw(3e-4), zero_stage=1)
+        state = jax.eval_shape(tx.init, jax.eval_shape(
+            lambda k: tfm.init_params(k, cfg), jax.random.PRNGKey(0)))
+    finally:
+        hvd.shutdown()
+    mesh = Mesh(np.array(topo.devices[:4]), ("hvd",))
+    assert step_program._exchange_compiler_options(mesh, "psum")
+    text = _compile_step(mesh, tx, tx.update._hvd_spec, state,
+                         cfg).as_text()
+    assert xla_trace.exchange_async(text)["all_reduces"] >= 1
+
+
+def test_step_with_expert_leaves_compiles_on_four_chips(topo):
+    """An expert-keys spec on the (data, expert) mesh: expert leaves
+    all-reduce over the data axis only, dense leaves over all four."""
+    cfg = moe.MoEConfig(d_model=1024, d_ff=4096, num_experts=8, top_k=2,
+                        capacity_factor=2.0, dtype=jnp.bfloat16)
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(2, 2), ("hvd", "ep"))
+
+    def loss_fn(p, x, y):
+        out, aux = moe.moe_layer(p, x, cfg, ep_axis="ep")
+        return jnp.mean((out.astype(jnp.float32) - y) ** 2) + 0.01 * aux
+
+    tx = hvd.DistributedOptimizer(optax.adamw(3e-4),
+                                  expert_keys=("w1", "w2"))
+    full = jax.eval_shape(lambda k: moe.init_moe_params(k, cfg),
+                          jax.random.PRNGKey(0))
+    held = {k: (jax.ShapeDtypeStruct((a.shape[0] // 2,) + a.shape[1:],
+                                     a.dtype) if k in ("w1", "w2") else a)
+            for k, a in full.items()}
+    rep = NamedSharding(mesh, P())
+    x = jax.ShapeDtypeStruct((16, 512, cfg.d_model), jnp.float32,
+                             sharding=NamedSharding(mesh, P(("hvd", "ep"))))
+    base = tx.update._hvd_base
+    prog = step_program._build_step_program(
+        mesh, loss_fn, base, 2, "psum", True, None, False, True, False,
+        None, 1, tx.update._hvd_spec)
+    text = prog.lower(_shaped(held, rep),
+                      _shaped(jax.eval_shape(base.init, held), rep), x,
+                      x).compile().as_text()
+    assert xla_trace.exchange_async(text)["all_reduces"] >= 2
+
+
+def test_compiler_is_asked_once_whether_it_knows_an_internal_option(topo):
+    """The combiner's threshold is an internal name: this libtpu knows
+    it; one it does not know is answered "no" with one line in the log,
+    and the step then compiles with the two public options alone."""
+    device = topo.devices[0]
+    assert step_program._compiler_accepts(
+        device, step_program._COMBINER_THRESHOLD)
+    said = []
+    handler = logging.Handler()
+    handler.emit = lambda record: said.append(record.getMessage())
+    logger = get_logger()
+    logger.addHandler(handler)
+    try:
+        for _ in range(2):
+            assert not step_program._compiler_accepts(
+                device, ("xla_jf_no_such_option_in_any_libtpu", 1))
+    finally:
+        logger.removeHandler(handler)
+    assert len(said) == 1 and "no compile option" in said[0]

@@ -204,6 +204,9 @@ def test_compiled_loop_leaves_step_data_bcast_spans(ring, monkeypatch):
         assert [s[5] for s in names[part]] == [s[4] for s in steps]
     (analyze,) = names["step.analyze"]          # once per signature
     assert analyze[5] == steps[0][4]
+    (read_hlo,) = names["step.read_hlo"]        # and after its first run
+    assert read_hlo[5] == steps[0][4]
+    assert read_hlo[1] >= names["step.execute"][0][2]
     # jax's own durations hang under whichever span was open: the trace
     # and the lowering under step.analyze (it lowers first), the backend
     # compile or cache load under the first step.execute
