@@ -771,6 +771,27 @@ def record_moe_routing(stats):
          if sum(layer)), default=1.0))
 
 
+# State-space layers (models/ssm.py; docs/observability.md)
+SSM_STATE_RMS = _registry.gauge(
+    "hvd_ssm_state_rms",
+    "Root mean square of a Mamba-2 layer's final recurrent state (batch, "
+    "heads, head features x state size) in the most recent observed "
+    "step, from the heads' own root mean squares; layer counts the "
+    "Mamba-2 layers in model order. A state that "
+    "grows from step to step says the decays (A_log, dt_bias) are "
+    "drifting towards 1.", labelnames=("layer",))
+
+
+def record_ssm_state(stats):
+    """Host-side per-step accounting of the Mamba-2 layers: set
+    hvd_ssm_state_rms{layer} from the fetched aux of a compiled step
+    whose loss is ``transformer.loss_and_stats`` (``ssm_state_rms``
+    (layers, heads))."""
+    for i, heads in enumerate(stats["ssm_state_rms"]):
+        mean_sq = sum(float(h) ** 2 for h in heads) / len(heads)
+        SSM_STATE_RMS.labels(layer=str(i)).set(mean_sq ** 0.5)
+
+
 # Inference serving (serve/; docs/serving.md, docs/observability.md
 # "Serving")
 SERVE_REQUESTS = _registry.counter(

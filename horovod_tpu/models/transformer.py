@@ -66,6 +66,9 @@ class LayerSpec:
     window: Optional[int] = None       # None = full causal attention
     rope: Optional[RopeSpec] = None    # None = no rotary embedding
     mlp: str = "dense"                 # "dense" | "sparse" (MoE FFN)
+    # "attention" | "mamba2" (models/ssm.py; n_heads / window / rope then
+    # say nothing, the ssm_* sizes of the configuration do)
+    mixer: str = "attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -159,6 +162,27 @@ class TransformerConfig:
     # models/moe.py moe_dropless (no capacity, no ep axis); None = the
     # capacity layer moe_layer over the mesh's ep axis.
     moe_experts_held: Optional[tuple] = None
+    # --- what a published configuration may state beside the widths ---
+    # Scale on q.k before the softmax (None = 1 / sqrt(head_dim)).
+    attention_scale: Optional[float] = None
+    # x0 = embedding_multiplier * embed[tokens]; every residual branch
+    # (mixer and FFN) is added times residual_multiplier; the logits are
+    # divided by logits_scaling.
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # The head reads ``embed`` transposed: one leaf, whose gradient is
+    # the sum of both uses, and no ``lm_head``.
+    tie_embeddings: bool = False
+    norm_eps: float = 1e-6
+    # Mamba-2 layers (LayerSpec.mixer == "mamba2"; models/ssm.py): heads,
+    # features a head, state size, convolution kernel, chunk of the scan,
+    # and how many chunks' decay blocks are live at once.
+    ssm_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    ssm_conv: int = 4
+    ssm_chunk: int = 256
 
     def __post_init__(self):
         if self.layers:
@@ -171,8 +195,15 @@ class TransformerConfig:
                     "a per-layer description needs positional='rope' "
                     "(each LayerSpec carries its rotary embedding or "
                     "None) and an explicit head_size")
+            if any(l.mixer not in ("attention", "mamba2")
+                   for l in self.layers):
+                raise ValueError(
+                    "a layer's mixer is 'attention' or 'mamba2'")
+            if self.has_ssm and self.ssm_heads < 1:
+                raise ValueError("a Mamba-2 layer needs ssm_heads")
             if self.n_kv_heads and any(l.n_heads % self.n_kv_heads
-                                       for l in self.layers):
+                                       for l in self.layers
+                                       if l.mixer == "attention"):
                 raise ValueError(
                     "every layer's n_heads must be divisible by "
                     f"n_kv_heads ({self.n_kv_heads})")
@@ -209,6 +240,10 @@ class TransformerConfig:
     def head_dim(self):
         return self.head_size or self.d_model // self.n_heads
 
+    @property
+    def has_ssm(self):
+        return any(l.mixer == "mamba2" for l in self.layers)
+
     def layer_spec(self, i=None):
         """The description of layer ``i``. ``i=None`` is for the paths
         that take one kind of layer (decode, serve, pipeline): the common
@@ -218,8 +253,18 @@ class TransformerConfig:
                 raise ValueError(
                     "this path takes one kind of layer; the "
                     "configuration describes its layers one by one "
-                    "(TransformerConfig.layers)")
+                    "(TransformerConfig.layers)" + (
+                        ", some of them Mamba-2 layers, whose state has "
+                        "no decode step or pipeline stage here"
+                        if self.has_ssm else ""))
             return self.layers[i]
+        if i is None and (self.attention_scale, self.embedding_multiplier,
+                          self.residual_multiplier,
+                          self.logits_scaling) != (None, 1.0, 1.0, 1.0):
+            raise ValueError(
+                "this path computes the plain block: attention_scale, "
+                "embedding_multiplier, residual_multiplier and "
+                "logits_scaling are applied by the training forward only")
         return LayerSpec(
             n_heads=self.n_heads, window=self.attention_window,
             rope=RopeSpec() if self.positional == "rope" else None,
@@ -238,6 +283,16 @@ class TransformerConfig:
                          routed_scale=self.moe_routed_scale,
                          shared_d_ff=self.moe_shared_d_ff,
                          interpret=self.flash_interpret)
+
+    @property
+    def ssm_cfg(self):
+        from .ssm import SSMConfig
+        return SSMConfig(d_model=self.d_model, n_heads=self.ssm_heads,
+                         head_dim=self.ssm_head_dim,
+                         d_state=self.ssm_state, d_conv=self.ssm_conv,
+                         chunk=self.ssm_chunk,
+                         norm_eps=self.norm_eps, dtype=self.dtype,
+                         param_dtype=self.param_dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -265,22 +320,25 @@ def init_params(key, cfg):
         lk = jax.random.split(keys[3 + i], 4)
         spec = cfg.layer_spec(i)
         h = spec.n_heads
-        layer = {
-            "ln1": jnp.ones((d,), pd),
-            "wo": dense(lk[1], (h, hd, d), d),
-            "ln2": jnp.ones((d,), pd),
-        }
+        layer = {"ln1": jnp.ones((d,), pd), "ln2": jnp.ones((d,), pd)}
         h_kv = cfg.n_kv_heads
-        if h_kv is not None and h_kv != h:
+        if spec.mixer == "mamba2":
+            from .ssm import init_ssm_params
+            layer["ssm"] = init_ssm_params(lk[0], cfg.ssm_cfg)
+        elif h_kv is not None and h_kv != h:
             qk = jax.random.split(lk[0])
             layer["wq"] = dense(qk[0], (d, h, hd), d)
             layer["wkv"] = dense(qk[1], (d, 2, h_kv, hd), d)
         else:
             layer["wqkv"] = dense(lk[0], (d, 3, h, hd), d)
-        if cfg.attn_gate:
-            # (heads, d_model): a minor dimension of 6 or 9 heads would
-            # be padded to 128 lanes wherever XLA keeps the leaf 2-D
-            layer["wg"] = dense(jax.random.fold_in(lk[1], 1), (h, d), d)
+        if spec.mixer == "attention":
+            layer["wo"] = dense(lk[1], (h, hd, d), d)
+            if cfg.attn_gate:
+                # (heads, d_model): a minor dimension of 6 or 9 heads
+                # would be padded to 128 lanes wherever XLA keeps the
+                # leaf 2-D
+                layer["wg"] = dense(jax.random.fold_in(lk[1], 1), (h, d),
+                                    d)
         if spec.mlp == "sparse":
             from .moe import init_moe_params
             layer["moe"] = init_moe_params(lk[2], cfg.moe_cfg)
@@ -295,8 +353,9 @@ def init_params(key, cfg):
         "embed": dense(keys[0], (cfg.vocab_size, d), d),
         "layers": layers,
         "ln_f": jnp.ones((d,), pd),
-        "lm_head": dense(keys[2], (d, cfg.vocab_size), d),
     }
+    if not cfg.tie_embeddings:
+        out["lm_head"] = dense(keys[2], (d, cfg.vocab_size), d)
     if cfg.positional == "learned":
         out["pos"] = dense(keys[1], (cfg.max_seq, d), d)
     return out
@@ -312,18 +371,20 @@ def param_specs(cfg, axes=ShardAxes()):
     layers = []
     for i in range(cfg.n_layers):
         spec = cfg.layer_spec(i)
-        layer = {
-            "ln1": P(),
-            "wo": P(tp, None, None),           # row-parallel (psum after)
-            "ln2": P(),
-        }
-        if cfg.n_kv_heads is not None and cfg.n_kv_heads != spec.n_heads:
+        layer = {"ln1": P(), "ln2": P()}
+        if spec.mixer == "mamba2":
+            from .ssm import ssm_specs
+            layer["ssm"] = ssm_specs()         # a layer whole on its chip
+        elif cfg.n_kv_heads is not None \
+                and cfg.n_kv_heads != spec.n_heads:
             layer["wq"] = P(None, tp, None)        # q heads sharded
             layer["wkv"] = P(None, None, tp, None)  # kv heads sharded
         else:
             layer["wqkv"] = P(None, None, tp, None)  # heads sharded
-        if cfg.attn_gate:
-            layer["wg"] = P(tp, None)          # one gate per q head
+        if spec.mixer == "attention":
+            layer["wo"] = P(tp, None, None)    # row-parallel (psum after)
+            if cfg.attn_gate:
+                layer["wg"] = P(tp, None)      # one gate per q head
         if spec.mlp == "sparse":
             layer["moe"] = (moe_specs(axes.ep)
                             if cfg.moe_experts_held is None
@@ -338,8 +399,9 @@ def param_specs(cfg, axes=ShardAxes()):
         "embed": P(tp, None),              # vocab-parallel
         "layers": layers,
         "ln_f": P(),
-        "lm_head": P(None, tp),            # vocab-parallel logits
     }
+    if not cfg.tie_embeddings:
+        out["lm_head"] = P(None, tp)       # vocab-parallel logits
     if cfg.positional == "learned":
         out["pos"] = P()
     return out
@@ -483,10 +545,10 @@ def _rope_b(x, positions, theta=10000.0):
     return out.astype(x.dtype)
 
 
-def _rmsnorm(x, scale):
+def _rmsnorm(x, scale, eps=1e-6):
     x32 = x.astype(jnp.float32)
     var = jnp.mean(x32 * x32, axis=-1, keepdims=True)
-    return (x32 * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (x32 * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 def _axis_index(axis):
@@ -541,6 +603,8 @@ def embed_tokens(params, tokens, cfg, axes):
     """Vocab-parallel embedding lookup + learned positions (training path:
     positions start at this sp shard's offset)."""
     x = _embed_rows(params, tokens, axes)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
 
     if cfg.positional != "learned":
         return x.astype(cfg.dtype)  # rope: rotation happens on q/k
@@ -586,7 +650,7 @@ def _attention_block_kv(p, x, cfg, axes, spec=None):
     of layer the configuration has). The attention itself runs under the
     device scope ``hvd_attn_window`` or ``hvd_attn_full``."""
     spec = spec or cfg.layer_spec()
-    h = _rmsnorm(x, p["ln1"])
+    h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
     q, k, v = _qkv_proj(p, h, cfg)
     if spec.rope is not None:
         s_loc = x.shape[1]
@@ -604,13 +668,39 @@ def _attention_block_kv(p, x, cfg, axes, spec=None):
         attn = attn * gate[..., None].astype(cfg.dtype)
     out = jnp.einsum("bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
                      preferred_element_type=jnp.float32)
-    out = _psum(out, axes.tp).astype(cfg.dtype)
-    return x + out, k, v
+    return _residual(x, _psum(out, axes.tp), cfg), k, v
+
+
+def _residual(x, branch, cfg):
+    """``x + residual_multiplier * branch``; the branch arrives as its
+    matmul accumulated it (float32) and is rounded once."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * cfg.residual_multiplier
+    return x + branch.astype(cfg.dtype)
+
+
+def _ssm_block(p, x, cfg, axes):
+    """The mixer half of a Mamba-2 layer (models/ssm.py): ``(x +
+    residual_multiplier * mixer(rmsnorm(x)), state_rms)``."""
+    if axes.sp:
+        raise ValueError(
+            "a Mamba-2 layer carries its state along the sequence; "
+            "sequence parallelism (axes.sp) would have to hand it from "
+            "shard to shard and is not supported")
+    from .ssm import mamba2_mixer
+    out, rms = mamba2_mixer(p["ssm"], _rmsnorm(x, p["ln1"], cfg.norm_eps),
+                            cfg.ssm_cfg)
+    return _residual(x, out, cfg), rms
 
 
 def _attend(q, k, v, win, cfg, axes):
     """Causal attention of one layer by the configured implementation
     (flash / dense; ring or ulysses under sp), keys within ``win``."""
+    scale = cfg.attention_scale
+    if axes.sp and scale is not None:
+        raise ValueError(
+            "attention_scale is not carried through the sequence-parallel "
+            "attention paths (ring / ulysses)")
     if axes.sp and cfg.sp_impl == "ulysses":
         # ulysses: all-to-all re-shards to (full seq, local heads); the
         # chosen kernel then runs whole over the global sequence (so a
@@ -645,8 +735,9 @@ def _attend(q, k, v, win, cfg, axes):
     if cfg.attention_impl == "flash":
         from ..ops.flash_attention import flash_attention
         return flash_attention(q, k, v, True,
-                               interpret=cfg.flash_interpret, window=win)
-    return dense_attention(q, k, v, causal=True, window=win)
+                               interpret=cfg.flash_interpret, window=win,
+                               scale=scale)
+    return dense_attention(q, k, v, causal=True, window=win, scale=scale)
 
 
 def _mlp_block(p, x, cfg, axes, moe_full_capacity=False):
@@ -668,18 +759,18 @@ def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
     other layer). The parameters pick the FFN: ``moe`` a sparse layer —
     dropless when the configuration states the experts held, else the
     capacity layer over ``axes.ep`` —, ``w3`` the gated SiLU FFN."""
-    h = _rmsnorm(x, p["ln2"])
+    h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
     zero = jnp.zeros((), jnp.float32)
     if "moe" in p and cfg.moe_experts_held is not None:
         from .moe import moe_dropless
         y, stats = moe_dropless(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg)
-        return x + y.astype(cfg.dtype), zero, stats
+        return _residual(x, y, cfg), zero, stats
     if "moe" in p:
         from .moe import moe_layer
         y, aux = moe_layer(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg,
                            ep_axis=axes.ep,
                            full_capacity=moe_full_capacity)
-        return x + y.astype(cfg.dtype), aux, None
+        return _residual(x, y, cfg), aux, None
     u = jnp.einsum("bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
                    preferred_element_type=jnp.float32)
     if "w3" in p:
@@ -691,8 +782,7 @@ def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
     out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
                      p["w2"].astype(cfg.dtype),
                      preferred_element_type=jnp.float32)
-    out = _psum(out, axes.tp).astype(cfg.dtype)
-    return x + out, zero, None
+    return _residual(x, _psum(out, axes.tp), cfg), zero, None
 
 
 MOE_AUX_COEF = 0.01  # Switch-style load-balance coefficient
@@ -704,28 +794,38 @@ def trunk_with_aux(params, tokens, cfg, axes=None):
 
 
 def trunk_with_stats(params, tokens, cfg, axes=None):
-    """:func:`trunk_with_aux` plus the dropless sparse layers' routing
-    counters, stacked over those layers in order (``{}`` when the model
-    has none): ``expert_load`` (layers, experts held), ``unrouted_tokens``
-    (layers,)."""
+    """:func:`trunk_with_aux` plus what the layers count, stacked over
+    the layers that count it, in order: the dropless sparse layers'
+    routing counters ``expert_load`` (layers, experts held) and
+    ``unrouted_tokens`` (layers,); the Mamba-2 layers' ``ssm_state_rms``
+    (layers, heads), the root mean square of each head's final state.
+    ``{}`` when the model has neither kind of layer."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 
     def one_layer(p, x, spec):
-        x = _attention_block(p, x, cfg, axes, spec)
-        return _mlp_block_stats(p, x, cfg, axes)
+        rms = None
+        if spec.mixer == "mamba2":
+            x, rms = _ssm_block(p, x, cfg, axes)
+        else:
+            x = _attention_block(p, x, cfg, axes, spec)
+        return _mlp_block_stats(p, x, cfg, axes) + (rms,)
 
     if cfg.remat:
         one_layer = jax.checkpoint(one_layer, static_argnums=(2,))
-    routing = []
+    routing, state_rms = [], []
     for i, p in enumerate(params["layers"]):
-        x, aux, stats = one_layer(p, x, cfg.layer_spec(i))
+        x, aux, stats, rms = one_layer(p, x, cfg.layer_spec(i))
         aux_total = aux_total + aux
         if stats is not None:
             routing.append(stats)
+        if rms is not None:
+            state_rms.append(rms)
     stats = jax.tree.map(lambda *a: jnp.stack(a), *routing) if routing \
         else {}
+    if state_rms:
+        stats["ssm_state_rms"] = jnp.stack(state_rms)
     return x, aux_total, stats
 
 
@@ -800,9 +900,18 @@ def _chunked_cross_entropy(params, x, targets, cfg, axes):
 def _head(params, x, cfg):
     """Final norm + (possibly vocab-sharded) LM head: (B, S, d) -> f32
     logits (B, S, V_loc)."""
-    x = _rmsnorm(x, params["ln_f"])
-    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
+    x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
+    if cfg.tie_embeddings:
+        logits = jnp.einsum("bsd,vd->bsv", x,
+                            params["embed"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+    else:
+        logits = jnp.einsum("bsd,dv->bsv", x,
+                            params["lm_head"].astype(cfg.dtype),
+                            preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def loss_fn(params, tokens, targets, cfg, axes=None):
@@ -1273,7 +1382,7 @@ def prefill_cache(params, cache, tokens, cfg, axes=None):
 
     new_layers = []
     for p, lc in zip(params["layers"], cache["layers"]):
-        h = _rmsnorm(x, p["ln1"])
+        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
         q, k_new, v_new = _qkv_proj(p, h, cfg)
         if cfg.positional == "rope":
             q = _rope(q, positions)
@@ -1319,7 +1428,7 @@ def decode_step(params, cache, token, cfg, axes=None):
 
     new_layers = []
     for p, lc in zip(params["layers"], cache["layers"]):
-        h = _rmsnorm(x, p["ln1"])
+        h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
         q, k_new, v_new = _qkv_proj(p, h, cfg)
         if cfg.positional == "rope":
             q = _rope(q, pos[None])

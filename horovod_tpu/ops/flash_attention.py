@@ -433,6 +433,13 @@ def _ragged_error(s, block_size):
         "unfused math (attention_impl='dense' / ring impl='dense')")
 
 
+def _softmax_scale(d, scale=None):
+    """The factor on q.k before the softmax: ``scale`` where the model
+    states one, else 1 / sqrt(head size). A Python float: it is folded
+    into the kernels as a constant."""
+    return 1.0 / (d ** 0.5) if scale is None else float(scale)
+
+
 def _to_slab(x):
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -443,9 +450,9 @@ def _from_slab(x, b, h):
     return x.reshape(b, h, s, d).transpose(0, 2, 1, 3)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, block_size=512, interpret=False,
-                    window=None):
+                    window=None, scale=None):
     """Fused attention. q/k/v: (B, S, H, D); returns (B, S, H, D).
 
     Same contract as ring_attention/dense_attention (parallel/
@@ -453,10 +460,11 @@ def flash_attention(q, k, v, causal=True, block_size=512, interpret=False,
     transformer. ``window`` (requires causal) restricts each query to the
     previous ``window`` positions (Mistral-style sliding window): both
     compute and K/V DMAs prune outside the band, so cost scales with
-    S * window instead of S^2.
+    S * window instead of S^2. ``scale`` multiplies q.k before the softmax
+    (None: 1 / sqrt(D)).
     """
     out, _ = _flash_fwd_impl(q, k, v, causal, block_size, interpret,
-                             window)
+                             window, scale)
     return out
 
 
@@ -476,7 +484,8 @@ def _pad_seq(x, s_pad):
     return jnp.pad(x, ((0, 0), (0, s_pad - s), (0, 0), (0, 0)))
 
 
-def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
+def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None,
+                    scale=None):
     """Returns (out, lse) with lse shaped (B*H, 1, S)."""
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
@@ -485,7 +494,7 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
             raise ValueError("window requires causal=True")
         if window < 1:
             raise ValueError(f"window must be >= 1, got {window}")
-    scale = 1.0 / (d ** 0.5)
+    scale = _softmax_scale(d, scale)
     block = _pick_block(s, block_size)
     if block is None and causal:
         # Ragged causal length: pad the sequence up to a block multiple
@@ -498,7 +507,7 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
         bs = max(block_size, 128)  # 128 is the minimum ragged tile
         out, lse = _flash_fwd_impl(
             _pad_seq(q, s_pad), _pad_seq(k, s_pad), _pad_seq(v, s_pad),
-            causal, bs, interpret, window)
+            causal, bs, interpret, window, scale)
         return out[:, :s], lse[:, :, :s]
     if block is None:
         # non-causal ragged tail: the kernel has no length concept to
@@ -550,9 +559,10 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None):
     return _from_slab(out, b, h), lse
 
 
-def _flash_fwd(q, k, v, causal, block_size, interpret, window=None):
+def _flash_fwd(q, k, v, causal, block_size, interpret, window=None,
+               scale=None):
     out, lse = _flash_fwd_impl(q, k, v, causal, block_size, interpret,
-                               window)
+                               window, scale)
     return out, (q, k, v, out, lse)
 
 
@@ -596,21 +606,21 @@ def _flash_lse_bwd(causal, block_size, interpret, window, res, g):
 flash_attention_with_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _flash_bwd(causal, block_size, interpret, window, res, g):
+def _flash_bwd(causal, block_size, interpret, window, scale, res, g):
     q, k, v, out, lse = res
     return _flash_bwd_impl(causal, block_size, interpret, q, k, v, out,
-                           lse, g, None, window)
+                           lse, g, None, window, scale=scale)
 
 
 def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
-                    g_lse, window=None, delta=None):
+                    g_lse, window=None, delta=None, scale=None):
     """``delta`` (B*H, 1, S) f32, when given, replaces the rowsum(dO*O)
     pass (``out`` may then be None) — ring attention computes one global
     delta and feeds every tile's backward from it."""
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
     h_kv = k.shape[2]
-    scale = 1.0 / (d ** 0.5)
+    scale = _softmax_scale(d, scale)
     block = _pick_block(s, block_size)
     if block is None:
         # ragged causal length: mirror the forward's pad-to-block path.
@@ -635,7 +645,8 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
             causal, bs, interpret, _pad_seq(q, s_pad),
             _pad_seq(k, s_pad), _pad_seq(v, s_pad),
             None if out is None else _pad_seq(out, s_pad),
-            lse_pad, _pad_seq(g, s_pad), g_lse_pad, window, delta_pad)
+            lse_pad, _pad_seq(g, s_pad), g_lse_pad, window, delta_pad,
+            scale)
         return dq[:, :s], dk[:, :s], dv[:, :s]
     n = s // block
 
@@ -750,7 +761,7 @@ def _band_tile_fwd(q, k, v, off, window, block_size, interpret):
     cannot be hidden by the mask)."""
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
-    scale = 1.0 / (d ** 0.5)
+    scale = _softmax_scale(d)
     block = _pick_block(s, block_size)
     if block is None:
         raise _ragged_error(s, block_size)
@@ -792,7 +803,7 @@ def _band_tile_bwd(q, k, v, g, lse, delta, off, window, block_size,
     b, s, h, d = q.shape
     group = _gqa_group(q, k, v)
     h_kv = k.shape[2]
-    scale = 1.0 / (d ** 0.5)
+    scale = _softmax_scale(d)
     block = _pick_block(s, block_size)
     if block is None:
         raise _ragged_error(s, block_size)

@@ -110,7 +110,7 @@ def _decode_core(params, k_pool, v_pool, tokens, lengths, page_tables,
         pages = page_tables[ar, lengths // page_size]
         offs = lengths % page_size
         for li, p in enumerate(params["layers"]):
-            h = tfm._rmsnorm(x, p["ln1"])
+            h = tfm._rmsnorm(x, p["ln1"], cfg.norm_eps)
             q, k_new, v_new = tfm._qkv_proj(p, h, cfg)
             if cfg.positional == "rope":
                 q = tfm._rope_b(q, lengths[:, None])
@@ -216,6 +216,7 @@ class ServeEngine:
                  max_pages_per_seq=None, batch_bin_floor=1,
                  page_bin_floor=1, len_bin_floor=1,
                  moe_full_capacity=True):
+        cfg.layer_spec()  # serving takes one kind of layer
         self.cfg = cfg
         self.mesh = mesh
         self.tp_axis = tp_axis if mesh is not None else None

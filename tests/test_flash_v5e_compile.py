@@ -70,7 +70,12 @@ CELLS = {
     # held, no window on the full layers, window 512 = one block
     "laguna_full": (2, 8192, 6, 1, 128, None),
     "laguna_window": (2, 8192, 9, 1, 128, 512),
+    # granite4h-micro_s16k: the one attention layer, heads of 64 (half
+    # the 128 lanes), 4 query heads a kv head, the scale the model states
+    "granite_full": (1, 16384, 32, 8, 64, None),
 }
+# the factor on q.k where it is not 1 / sqrt(D)
+SCALES = {"granite_full": 0.015625}
 
 
 def _shapes(cell, sharding):
@@ -85,7 +90,7 @@ def _shapes(cell, sharding):
 def test_flash_forward_compiles_for_v5e(one_chip, cell):
     q, kv, window = _shapes(cell, one_chip)
     text = jax.jit(lambda q, k, v: flash_attention(
-        q, k, v, True, 512, False, window)).lower(
+        q, k, v, True, 512, False, window, SCALES.get(cell))).lower(
             q, kv, kv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
@@ -97,7 +102,8 @@ def test_flash_vjp_compiles_for_v5e(one_chip, cell):
     q, kv, window = _shapes(cell, one_chip)
 
     def loss(q, k, v):
-        out = flash_attention(q, k, v, True, 512, False, window)
+        out = flash_attention(q, k, v, True, 512, False, window,
+                              SCALES.get(cell))
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
@@ -200,6 +206,7 @@ def test_step_with_striped_state_compiles_on_four_chips(topo):
         vocab_size=8192, d_model=512, n_heads=4, n_layers=2, d_ff=2048,
         max_seq=512, dtype=jnp.bfloat16, attention_impl="flash",
         flash_interpret=False, positional="learned", loss_chunk=512)
+    hvd.shutdown()  # a world an earlier file of this worker may have left
     hvd.init(num_ranks=4)
     try:
         tx = hvd.DistributedOptimizer(optax.adamw(3e-4), zero_stage=1)

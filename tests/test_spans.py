@@ -277,7 +277,9 @@ def test_kernel_and_head_names_in_the_step_jaxpr():
                  "hvd_flash_band_dkv", "hvd_head_ce", "hvd_attn_full",
                  "hvd_attn_window", "hvd_gmm", "hvd_moe", "hvd_moe_route",
                  "hvd_moe_dispatch", "hvd_moe_experts", "hvd_moe_combine",
-                 "hvd_moe_shared"):
+                 "hvd_moe_shared", "hvd_ssm", "hvd_ssm_in_proj",
+                 "hvd_ssm_conv", "hvd_ssm_scan", "hvd_ssm_norm",
+                 "hvd_ssm_out_proj"):
         assert phase_of_op_name(f"jit(f)/{name}/x") is None
 
 
