@@ -792,6 +792,16 @@ def record_ssm_state(stats):
         SSM_STATE_RMS.labels(layer=str(i)).set(mean_sq ** 0.5)
 
 
+# Dense gated FFNs (models/transformer.py _gated_ffn; docs/observability.md)
+FFN_GATED_LAYERS = _registry.gauge(
+    "hvd_ffn_gated_layers",
+    "Gated dense layers (_gated_ffn: the gate's cotangents stored once) "
+    "of the model that transformer.trunk_with_stats traced last; set "
+    "while it is traced, not per step, and not by the pipeline stages or "
+    "the serving engine, which leave it as it was. 0 for a model of GELU "
+    "or sparse FFNs only.")
+
+
 # Inference serving (serve/; docs/serving.md, docs/observability.md
 # "Serving")
 SERVE_REQUESTS = _registry.counter(

@@ -29,6 +29,7 @@ f32 accumulation in every matmul via ``preferred_element_type``.
 """
 
 import dataclasses
+import functools
 import math
 from typing import Any, Optional
 
@@ -243,6 +244,11 @@ class TransformerConfig:
     @property
     def has_ssm(self):
         return any(l.mixer == "mamba2" for l in self.layers)
+
+    @property
+    def has_sparse(self):
+        return bool(self.moe_layers) or any(
+            l.mlp == "sparse" for l in self.layers)
 
     def layer_spec(self, i=None):
         """The description of layer ``i``. ``i=None`` is for the paths
@@ -758,7 +764,8 @@ def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
     layer (models/moe.py ``moe_dropless`` ``stats``; ``None`` for every
     other layer). The parameters pick the FFN: ``moe`` a sparse layer —
     dropless when the configuration states the experts held, else the
-    capacity layer over ``axes.ep`` —, ``w3`` the gated SiLU FFN."""
+    capacity layer over ``axes.ep`` —, ``w3`` the gated SiLU FFN
+    (:func:`_gated_ffn`)."""
     h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
     zero = jnp.zeros((), jnp.float32)
     if "moe" in p and cfg.moe_experts_held is not None:
@@ -771,18 +778,145 @@ def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
                            ep_axis=axes.ep,
                            full_capacity=moe_full_capacity)
         return _residual(x, y, cfg), aux, None
-    u = jnp.einsum("bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
-                   preferred_element_type=jnp.float32)
     if "w3" in p:
-        u = jax.nn.silu(u) * jnp.einsum(
-            "bsd,df->bsf", h, p["w3"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32)
+        out = _gated_ffn(h.astype(jnp.float32), p["w1"].astype(cfg.dtype),
+                         p["w3"].astype(cfg.dtype),
+                         p["w2"].astype(cfg.dtype),
+                         _gate_slices(h.size // h.shape[-1],
+                                      p["w1"].shape[1], cfg))
     else:
-        u = jax.nn.gelu(u)
-    out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
-                     p["w2"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
+        u = jax.nn.gelu(jnp.einsum(
+            "bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32))
+        out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
+                         p["w2"].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
     return _residual(x, _psum(out, axes.tp), cfg), zero, None
+
+
+def _mm(spec, a, b):
+    return jnp.einsum(spec, a, b, preferred_element_type=jnp.float32)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _cotangent_once(x, dt):
+    """``x``; its cotangent is rounded to ``dt`` and stored, once, for
+    every consumer. Both consumers of the gated FFN's two gate cotangents
+    are matmuls at the default precision, which multiply float32 operands
+    rounded to bfloat16: the rounding is theirs, moved in front of them
+    (PERF.md section 6 PR 32: stored in float32 and stored rounded, the
+    first update of a step is the same in every bit). Left to itself XLA
+    fuses the cotangent's producer (an exp, a divide, five multiplies an
+    element) into each consumer and evaluates it there again and again:
+    the weight gradients of ``w1`` / ``w3`` ran at a third of the matmul
+    unit's rate."""
+    return x
+
+
+_cotangent_once.defvjp(
+    lambda x, dt: (x, None),
+    lambda dt, _, ct: (
+        lax.optimization_barrier(ct.astype(dt)).astype(ct.dtype),))
+
+
+def _gated_ffn_rows(h, w1, w3, w2, dt):
+    """``(silu(h w1) * (h w3)) w2`` over rows of tokens ``h`` (t, d), the
+    activations' type ``dt``: what :func:`_gated_ffn` computes and what
+    its backward hands to autodiff, a slice of the rows at a time."""
+    u1 = _cotangent_once(_mm("td,df->tf", h, w1), dt)
+    u3 = _cotangent_once(_mm("td,df->tf", h, w3), dt)
+    with jax.named_scope("hvd_ffn_gate"):
+        act = (jax.nn.silu(u1) * u3).astype(dt)
+    return _mm("tf,fd->td", act, w2)
+
+
+# The gated FFN's backward takes the tokens in slices while one float32
+# (tokens, ff) array of a slice - u1, u3 - is larger than this, in a model
+# whose FFNs are all dense: there the step's memory peak lies in a gated
+# layer's backward, and slices lower it. In a model with sparse layers it
+# does not, and slices of its few dense layers only make XLA schedule the
+# sparse layers' backward anew and the heap fragment. What was observed on
+# four shapes, not a law (PERF.md section 6 PR 32; a rule on the shapes
+# alone, tokens against d, held the two cells and chose wrongly on the
+# third shape it was tried on). The whole step
+# compiled for a described v5e (benchmark/tools/fit_mode.py, GiB) / on the
+# chip the allocator's GiB / ms a step:
+#   granite4h-micro_s16k, all dense, 16,384 tokens, ff 8192 (512 MiB; its
+#   parent 12.15 / 12.350 / 1,075.6): whole 12.34 / 12.478 / 940.7, over
+#   peak_hbm_gib's bound of 1 %; 2 slices 12.03 / 12.192 / 953.9 (taken);
+#   4 slices 11.86 offline. Over 32,768 tokens: whole 15.59,
+#   4 slices 14.46 offline
+#   laguna-s21_s8k, 1 dense layer of 5, 16,384 tokens, ff 12288 (768 MiB;
+#   its parent 9.67 / 9.496): whole 9.51 / 9.492 / 357.7 (taken); 2 slices
+#   12.09 offline. Over 32,768 tokens: whole 11.85, 2 slices 14.82 offline
+_GATE_SLICE_BYTES = 256 * 2 ** 20
+
+
+def _gate_slices(tokens, ff, cfg):
+    """Slices of the tokens the gated FFN's backward runs over: a power
+    of two that leaves whole (8, 128) tiles (see the constant above)."""
+    n = 1
+    while (not cfg.has_sparse and tokens * ff * 4 > n * _GATE_SLICE_BYTES
+           and tokens % (256 * n) == 0):
+        n *= 2
+    return n
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _gated_ffn(h, w1, w3, w2, slices):
+    """The gated SiLU FFN ``(silu(h w1) * (h w3)) w2`` of a dense layer:
+    ``h`` (..., d) float32 as :func:`_rmsnorm` returns it, the weights
+    already cast to the activations' type; float32 out.
+
+    The arithmetic is autodiff's of :func:`_gated_ffn_rows`, forward and
+    backward. A ``custom_vjp`` only to run the backward over ``slices``
+    of the tokens (:func:`_gate_slices`) one after the other, so that the
+    float32 ``u1`` / ``u3`` and the stored gate cotangents of one slice
+    are live at a time: whole, granite4h-micro_s16k's step passes its
+    parent's peak by 1.04 % on the chip. It keeps ``h`` and the weights
+    and computes ``u1`` / ``u3`` again in the backward, as
+    ``jax.checkpoint`` of the layer does anyway; a model WITHOUT remat
+    pays for that with two of the layer's eight FFN matmuls more (no cell
+    runs one)."""
+    return _gated_ffn_rows(h.reshape(-1, h.shape[-1]), w1, w3, w2,
+                           w1.dtype).reshape(h.shape)
+
+
+def _gated_ffn_fwd(h, w1, w3, w2, slices):
+    return _gated_ffn(h, w1, w3, w2, slices), (h, w1, w3, w2)
+
+
+def _gated_ffn_bwd(n, res, dout):
+    h, w1, w3, w2 = res
+    dt = w1.dtype
+    hd, g = (a.reshape(-1, h.shape[-1]) for a in (h, dout))
+    tokens = hd.shape[0]
+    # float32 views of the weights (exact): autodiff then hands back a
+    # slice's weight gradients unrounded, to be summed in float32 and
+    # rounded to dt once, where the transpose of the caller's cast
+    # rounds the whole gradient
+    ws = tuple(w.astype(jnp.float32) for w in (w1, w3, w2))
+
+    def rows(a, k):
+        return a[k * tokens // n:(k + 1) * tokens // n]
+
+    dw, dh = None, []
+    hk, gk = rows(hd, 0), rows(g, 0)
+    for k in range(n):
+        dhk, *part = jax.vjp(
+            functools.partial(_gated_ffn_rows, dt=dt), hk, *ws)[1](gk)
+        dh.append(dhk)
+        dw = part if dw is None else jax.tree.map(jnp.add, dw, part)
+        if k + 1 < n:
+            # the next slice starts when this one's sums exist: left to
+            # itself the scheduler runs the slices side by side
+            dw, hk, gk = lax.optimization_barrier(
+                (dw, rows(hd, k + 1), rows(g, k + 1)))
+    dh = dh[0] if n == 1 else jnp.concatenate(dh)
+    return (dh.reshape(h.shape),) + tuple(a.astype(dt) for a in dw)
+
+
+_gated_ffn.defvjp(_gated_ffn_fwd, _gated_ffn_bwd)
 
 
 MOE_AUX_COEF = 0.01  # Switch-style load-balance coefficient
@@ -801,6 +935,12 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     (layers, heads), the root mean square of each head's final state.
     ``{}`` when the model has neither kind of layer."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
+    from .. import metrics
+    # the gated dense layers of THIS model, by what _mlp_block_stats
+    # branches on (jax.checkpoint traces layers of one shape once, so
+    # the branch itself cannot count them; other callers of _mlp_block
+    # leave the gauge as it was)
+    metrics.FFN_GATED_LAYERS.set(sum("w3" in p for p in params["layers"]))
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 

@@ -14,6 +14,7 @@ is handed this file loads libtpu.
 
 import logging
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -195,6 +196,48 @@ def test_step_on_one_chip_is_the_bare_jit(topo):
     assert " all-reduce" not in text
     assert "async_collective_fusion" not in text
     assert xla_trace.exchange_async(text)["all_reduces"] == 0
+
+
+def _wide_float32_pairs(text, least=2 ** 22):
+    """Convolutions of an optimized HLO text under ``hvd_backward``, the
+    head's aside, with ``least`` output elements or more and float32 on
+    both sides."""
+    found, dtypes = [], {}
+    for line in text.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]", line)
+        if not m:
+            if line.startswith("}"):
+                dtypes = {}  # names are per computation
+            continue
+        name, dtype, dims = m.groups()
+        dtypes[name] = dtype
+        call = re.search(r" convolution\(([^)]*)\)", line)
+        if call and "hvd_backward" in line and "hvd_head_ce" not in line \
+                and np.prod([int(d) for d in dims.split(",")]) >= least \
+                and all(dtypes.get(o) == "f32" for o in
+                        re.findall(r"%([\w.\-]+)", call.group(1))):
+            found.append(line.split(" = ")[0].strip())
+    return found
+
+
+def test_gated_ffn_backward_feeds_no_wide_matmul_two_float32_operands(topo):
+    """The regression PR 32 removed (PERF.md section 6): autodiff of the
+    gated FFN fused the gate's float32 cotangents into the ``w1`` / ``w3``
+    weight gradients, whose other operand, the normed input, is float32
+    too — the step's only wide matmuls with float32 on both sides, at a
+    third of the matmul unit's rate (lost to the producer evaluated inside
+    the convolution, not to the operands' type: float32 on both sides is
+    the mark it leaves in the HLO). granite-4.0-h-micro's FFN widths, one
+    layer under remat; the parent commit's step has two here."""
+    cfg = tfm.TransformerConfig(
+        vocab_size=4096, d_model=2048, n_heads=16, n_kv_heads=4, n_layers=1,
+        d_ff=8192, max_seq=512, dtype=jnp.bfloat16, attention_impl="flash",
+        flash_interpret=False, positional="rope", loss_chunk=512,
+        mlp_gated=True, remat=True)
+    mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
+    text = _compile_step(mesh, optax.adamw(3e-4), cfg=cfg).as_text()
+    assert "hvd_ffn_gate" in text
+    assert _wide_float32_pairs(text) == []
 
 
 def test_step_with_striped_state_compiles_on_four_chips(topo):
