@@ -782,14 +782,39 @@ SSM_STATE_RMS = _registry.gauge(
     "drifting towards 1.", labelnames=("layer",))
 
 
+def _set_state_rms(gauge, by_layer_and_head):
+    """One gauge value a layer: the root of its heads' mean square."""
+    for i, heads in enumerate(by_layer_and_head):
+        mean_sq = sum(float(h) ** 2 for h in heads) / len(heads)
+        gauge.labels(layer=str(i)).set(mean_sq ** 0.5)
+
+
 def record_ssm_state(stats):
     """Host-side per-step accounting of the Mamba-2 layers: set
     hvd_ssm_state_rms{layer} from the fetched aux of a compiled step
     whose loss is ``transformer.loss_and_stats`` (``ssm_state_rms``
     (layers, heads))."""
-    for i, heads in enumerate(stats["ssm_state_rms"]):
-        mean_sq = sum(float(h) ** 2 for h in heads) / len(heads)
-        SSM_STATE_RMS.labels(layer=str(i)).set(mean_sq ** 0.5)
+    _set_state_rms(SSM_STATE_RMS, stats["ssm_state_rms"])
+
+
+# KDA layers (models/kda.py; docs/observability.md)
+KDA_STATE_RMS = _registry.gauge(
+    "hvd_kda_state_rms",
+    "Root mean square of a KDA layer's final recurrent state (batch, "
+    "heads, key x value features) in the most recent observed step, from "
+    "the heads' own root mean squares; layer counts the KDA layers in "
+    "model order.", labelnames=("layer",))
+KDA_LAYERS = _registry.gauge(
+    "hvd_kda_layers",
+    "KDA layers of the model that transformer.trunk_with_stats traced "
+    "last; set while it is traced, not per step.")
+
+
+def record_kda_state(stats):
+    """:func:`record_ssm_state` for the KDA layers: set
+    hvd_kda_state_rms{layer} from the fetched aux's ``kda_state_rms``
+    (layers, heads)."""
+    _set_state_rms(KDA_STATE_RMS, stats["kda_state_rms"])
 
 
 # Dense gated FFNs (models/transformer.py _gated_ffn; docs/observability.md)

@@ -74,6 +74,10 @@ class MoEConfig:
     gated: bool = False
     # the renormalised top-k probabilities are scaled by this
     routed_scale: float = 1.0
+    # what scores the experts: "softmax" over all of them, or "sigmoid" of
+    # each logit with the balancing bias ``router_bias`` added for the
+    # CHOICE of the top k only (arXiv:2408.15664), never for the weights
+    router: str = "softmax"
     # width of the shared expert every token passes through (0 = none)
     shared_d_ff: int = 0
     # run the grouped matmul kernels in the interpreter (CPU tests)
@@ -102,6 +106,10 @@ def init_moe_params(key, cfg):
 
     out = {"w_router": jax.random.normal(k1, (d, e), pd) / math.sqrt(d),
            **ffn((k2, k3), (held,), ff)}
+    if cfg.router == "sigmoid":
+        # starts at zero and gets no gradient: whoever balances the
+        # experts' loads moves it between steps
+        out["router_bias"] = jnp.zeros((e,), pd)
     if cfg.shared_d_ff:
         out["shared"] = ffn(jax.random.split(jax.random.fold_in(key, 1)),
                             (), cfg.shared_d_ff)
@@ -126,6 +134,8 @@ def dropless_specs(cfg):
     from jax.sharding import PartitionSpec as P
     ffn = {"w1": P(), "w2": P(), **({"w3": P()} if cfg.gated else {})}
     out = {"w_router": P(), **ffn}
+    if cfg.router == "sigmoid":
+        out["router_bias"] = P()
     if cfg.shared_d_ff:
         out["shared"] = dict(ffn)
     return out
@@ -398,9 +408,10 @@ def moe_dropless(params, x, cfg):
     """Sparse FFN of a chip that holds ``cfg.experts_held`` of the
     experts. x: (B, S, d) -> ``(y, stats)``.
 
-    ``p = softmax_fp32(x w_router)`` over all ``num_experts``; each token
-    takes its ``top_k``, weighted ``routed_scale * p / sum(p over the
-    top_k)``; ``y = sum over (top_k and held) of weight * FFN_e(x)`` plus,
+    ``p = softmax_fp32(x w_router)`` over all ``num_experts`` (``cfg.router
+    == "sigmoid"``: ``p = sigmoid_fp32(x w_router)``, the top k chosen on
+    ``p + router_bias``); each token takes its ``top_k``, weighted
+    ``routed_scale * p / sum(p over the top_k)``; ``y = sum over (top_k and held) of weight * FFN_e(x)`` plus,
     when the parameters carry one, the ``shared`` expert's FFN of every
     token. No capacity, no dropped token: the assignments are sorted by
     expert (``lax.sort``), their tokens' rows gathered, run through
@@ -422,7 +433,13 @@ def moe_dropless(params, x, cfg):
             logits = jnp.dot(x_flat.astype(jnp.float32),
                              params["w_router"].astype(jnp.float32),
                              precision=lax.Precision.HIGHEST)
-            top_p, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
+            if cfg.router == "sigmoid":
+                scores = jax.nn.sigmoid(logits)
+                _, top_i = lax.top_k(scores + lax.stop_gradient(
+                    params["router_bias"].astype(jnp.float32)), k)
+                top_p = jnp.take_along_axis(scores, top_i, axis=-1)
+            else:
+                top_p, top_i = lax.top_k(jax.nn.softmax(logits, axis=-1), k)
             gates = cfg.routed_scale * top_p / jnp.sum(
                 top_p, axis=-1, keepdims=True)
             local = top_i - first

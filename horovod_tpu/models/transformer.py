@@ -68,7 +68,10 @@ class LayerSpec:
     rope: Optional[RopeSpec] = None    # None = no rotary embedding
     mlp: str = "dense"                 # "dense" | "sparse" (MoE FFN)
     # "attention" | "mamba2" (models/ssm.py; n_heads / window / rope then
-    # say nothing, the ssm_* sizes of the configuration do)
+    # say nothing, the ssm_* sizes of the configuration do) | "kda"
+    # (models/kda.py; likewise, the kda_* sizes) | "mla" (latent attention:
+    # n_heads heads with q and k of mla_qk_nope + mla_qk_shared beside v of
+    # mla_v_dim, k and v from one latent of mla_kv_rank; no positions)
     mixer: str = "attention"
 
 
@@ -184,6 +187,21 @@ class TransformerConfig:
     ssm_state: int = 128
     ssm_conv: int = 4
     ssm_chunk: int = 256
+    # KDA layers (LayerSpec.mixer == "kda"; models/kda.py): heads, features
+    # a head (keys and values alike), convolution kernel.
+    kda_heads: int = 0
+    kda_head_dim: int = 128
+    kda_conv: int = 4
+    # Latent-attention layers (LayerSpec.mixer == "mla"): the rank of the
+    # latent k and v are expanded from, the per-head and the shared part
+    # of a key (the shared part is one for all heads), v's head size.
+    mla_kv_rank: int = 512
+    mla_qk_nope: int = 128
+    mla_qk_shared: int = 64
+    mla_v_dim: int = 128
+    # The sparse layers' router: "softmax" | "sigmoid" (models/moe.py
+    # MoEConfig.router: sigmoid scores, a balancing bias in the choice).
+    moe_router: str = "softmax"
 
     def __post_init__(self):
         if self.layers:
@@ -196,12 +214,20 @@ class TransformerConfig:
                     "a per-layer description needs positional='rope' "
                     "(each LayerSpec carries its rotary embedding or "
                     "None) and an explicit head_size")
-            if any(l.mixer not in ("attention", "mamba2")
+            if any(l.mixer not in ("attention", "mamba2", "kda", "mla")
                    for l in self.layers):
                 raise ValueError(
-                    "a layer's mixer is 'attention' or 'mamba2'")
+                    "a layer's mixer is 'attention', 'mamba2', 'kda' or "
+                    "'mla'")
             if self.has_ssm and self.ssm_heads < 1:
                 raise ValueError("a Mamba-2 layer needs ssm_heads")
+            if self.kda_layers and self.kda_heads < 1:
+                raise ValueError("a KDA layer needs kda_heads")
+            if any(l.mixer == "mla" and (l.rope or l.window)
+                   for l in self.layers):
+                raise ValueError(
+                    "a latent-attention layer here has neither positions "
+                    "nor a window (LayerSpec.rope and .window None)")
             if self.n_kv_heads and any(l.n_heads % self.n_kv_heads
                                        for l in self.layers
                                        if l.mixer == "attention"):
@@ -246,6 +272,11 @@ class TransformerConfig:
         return any(l.mixer == "mamba2" for l in self.layers)
 
     @property
+    def kda_layers(self):
+        """How many layers mix with KDA."""
+        return sum(l.mixer == "kda" for l in self.layers)
+
+    @property
     def has_sparse(self):
         return bool(self.moe_layers) or any(
             l.mlp == "sparse" for l in self.layers)
@@ -256,13 +287,20 @@ class TransformerConfig:
         description, or an error where the layers differ."""
         if self.layers:
             if i is None:
+                why = {"mamba2": "Mamba-2 layers, whose state has no "
+                       "decode step or pipeline stage here",
+                       "kda": "KDA layers, whose state has no decode step "
+                       "or pipeline stage here",
+                       "mla": "latent-attention layers, whose unequal qk / "
+                       "v head sizes the cache and the stage program do "
+                       "not hold"}
+                mixers = {l.mixer for l in self.layers}
                 raise ValueError(
                     "this path takes one kind of layer; the "
                     "configuration describes its layers one by one "
-                    "(TransformerConfig.layers)" + (
-                        ", some of them Mamba-2 layers, whose state has "
-                        "no decode step or pipeline stage here"
-                        if self.has_ssm else ""))
+                    "(TransformerConfig.layers)" + "".join(
+                        f", some of them {text}"
+                        for mixer, text in why.items() if mixer in mixers))
             return self.layers[i]
         if i is None and (self.attention_scale, self.embedding_multiplier,
                           self.residual_multiplier,
@@ -288,7 +326,8 @@ class TransformerConfig:
                          gated=self.mlp_gated,
                          routed_scale=self.moe_routed_scale,
                          shared_d_ff=self.moe_shared_d_ff,
-                         interpret=self.flash_interpret)
+                         interpret=self.flash_interpret,
+                         router=self.moe_router)
 
     @property
     def ssm_cfg(self):
@@ -299,6 +338,15 @@ class TransformerConfig:
                          chunk=self.ssm_chunk,
                          norm_eps=self.norm_eps, dtype=self.dtype,
                          param_dtype=self.param_dtype)
+
+
+    @property
+    def kda_cfg(self):
+        from .kda import KDAConfig
+        return KDAConfig(d_model=self.d_model, n_heads=self.kda_heads,
+                         head_dim=self.kda_head_dim, d_conv=self.kda_conv,
+                         norm_eps=self.norm_eps,
+                         dtype=self.dtype, param_dtype=self.param_dtype)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -331,6 +379,19 @@ def init_params(key, cfg):
         if spec.mixer == "mamba2":
             from .ssm import init_ssm_params
             layer["ssm"] = init_ssm_params(lk[0], cfg.ssm_cfg)
+        elif spec.mixer == "kda":
+            from .kda import init_kda_params
+            layer["kda"] = init_kda_params(lk[0], cfg.kda_cfg)
+        elif spec.mixer == "mla":
+            mk = jax.random.split(lk[0], 3)
+            rank, vd = cfg.mla_kv_rank, cfg.mla_v_dim
+            nope, shared = cfg.mla_qk_nope, cfg.mla_qk_shared
+            layer["mla"] = {
+                "wq": dense(mk[0], (d, h, nope + shared), d),
+                "w_kva": dense(mk[1], (d, rank + shared), d),
+                "kv_norm": jnp.ones((rank,), pd),
+                "w_kvb": dense(mk[2], (rank, h, nope + vd), rank),
+                "wo": dense(lk[1], (h, vd, d), h * vd)}
         elif h_kv is not None and h_kv != h:
             qk = jax.random.split(lk[0])
             layer["wq"] = dense(qk[0], (d, h, hd), d)
@@ -381,6 +442,12 @@ def param_specs(cfg, axes=ShardAxes()):
         if spec.mixer == "mamba2":
             from .ssm import ssm_specs
             layer["ssm"] = ssm_specs()         # a layer whole on its chip
+        elif spec.mixer == "kda":
+            from .kda import kda_specs
+            layer["kda"] = kda_specs()
+        elif spec.mixer == "mla":
+            layer["mla"] = {name: P() for name in (
+                "wq", "w_kva", "kv_norm", "w_kvb", "wo")}
         elif cfg.n_kv_heads is not None \
                 and cfg.n_kv_heads != spec.n_heads:
             layer["wq"] = P(None, tp, None)        # q heads sharded
@@ -699,6 +766,57 @@ def _ssm_block(p, x, cfg, axes):
     return _residual(x, out, cfg), rms
 
 
+def _kda_block(p, x, cfg, axes):
+    """The mixer half of a KDA layer (models/kda.py): ``(x +
+    residual_multiplier * mixer(rmsnorm(x)), state_rms)``."""
+    if axes.sp:
+        raise ValueError(
+            "a KDA layer carries its state along the sequence; sequence "
+            "parallelism (axes.sp) would have to hand it from shard to "
+            "shard and is not supported")
+    from .kda import kda_mixer
+    out, rms = kda_mixer(p["kda"], _rmsnorm(x, p["ln1"], cfg.norm_eps),
+                         cfg.kda_cfg)
+    return _residual(x, out, cfg), rms
+
+
+def _mla_block(p, x, cfg, axes):
+    """The mixer half of a latent-attention layer without positions: q
+    one projection a head (``mla_qk_nope + mla_qk_shared`` wide); k's
+    per-head part and v expanded from one normed latent of
+    ``mla_kv_rank``, k's shared part the same for every head; softmax
+    over ``q . k / sqrt(q's width)``, full causal. The five projections
+    and the latent's norm run under the device scope ``hvd_mla_proj``,
+    the attention under ``hvd_attn_full``."""
+    if axes.sp or axes.tp:
+        raise ValueError(
+            "a latent-attention layer (unequal qk / v head sizes, one "
+            "shared key part) is whole on its chip: no sequence or "
+            "tensor parallelism (axes.sp, axes.tp)")
+    m, dt, f32 = p["mla"], cfg.dtype, jnp.float32
+    rank, nope = cfg.mla_kv_rank, cfg.mla_qk_nope
+    h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
+    with jax.named_scope("hvd_mla_proj"):
+        q = jnp.einsum("bsd,dhx->bshx", h, m["wq"].astype(dt),
+                       preferred_element_type=f32).astype(dt)
+        kva = jnp.einsum("bsd,de->bse", h, m["w_kva"].astype(dt),
+                         preferred_element_type=f32).astype(dt)
+        latent = _rmsnorm(kva[..., :rank], m["kv_norm"], cfg.norm_eps)
+        kvb = jnp.einsum("bsr,rhx->bshx", latent.astype(dt),
+                         m["w_kvb"].astype(dt),
+                         preferred_element_type=f32).astype(dt)
+        shared = jnp.broadcast_to(
+            kva[:, :, None, rank:], kvb.shape[:3] + (cfg.mla_qk_shared,))
+        k = jnp.concatenate([kvb[..., :nope], shared], axis=-1)
+        v = kvb[..., nope:]
+    with jax.named_scope("hvd_attn_full"):
+        attn = _attend(q, k, v, None, cfg, axes)
+    with jax.named_scope("hvd_mla_proj"):
+        out = jnp.einsum("bshx,hxd->bsd", attn, m["wo"].astype(dt),
+                         preferred_element_type=f32)
+    return _residual(x, out, cfg)
+
+
 def _attend(q, k, v, win, cfg, axes):
     """Causal attention of one layer by the configured implementation
     (flash / dense; ring or ulysses under sp), keys within ``win``."""
@@ -932,8 +1050,9 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     the layers that count it, in order: the dropless sparse layers'
     routing counters ``expert_load`` (layers, experts held) and
     ``unrouted_tokens`` (layers,); the Mamba-2 layers' ``ssm_state_rms``
-    (layers, heads), the root mean square of each head's final state.
-    ``{}`` when the model has neither kind of layer."""
+    and the KDA layers' ``kda_state_rms`` (layers, heads), the root mean
+    square of each head's final state. ``{}`` when the model has none of
+    these layers."""
     axes = axes or ShardAxes(dp=None, sp=None, tp=None)
     from .. import metrics
     # the gated dense layers of THIS model, by what _mlp_block_stats
@@ -941,6 +1060,7 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     # the branch itself cannot count them; other callers of _mlp_block
     # leave the gauge as it was)
     metrics.FFN_GATED_LAYERS.set(sum("w3" in p for p in params["layers"]))
+    metrics.KDA_LAYERS.set(cfg.kda_layers)
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 
@@ -948,24 +1068,31 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
         rms = None
         if spec.mixer == "mamba2":
             x, rms = _ssm_block(p, x, cfg, axes)
+        elif spec.mixer == "kda":
+            x, rms = _kda_block(p, x, cfg, axes)
+        elif spec.mixer == "mla":
+            x = _mla_block(p, x, cfg, axes)
         else:
             x = _attention_block(p, x, cfg, axes, spec)
         return _mlp_block_stats(p, x, cfg, axes) + (rms,)
 
     if cfg.remat:
         one_layer = jax.checkpoint(one_layer, static_argnums=(2,))
-    routing, state_rms = [], []
+    routing, state_rms = [], {"mamba2": [], "kda": []}
     for i, p in enumerate(params["layers"]):
-        x, aux, stats, rms = one_layer(p, x, cfg.layer_spec(i))
+        spec = cfg.layer_spec(i)
+        x, aux, stats, rms = one_layer(p, x, spec)
         aux_total = aux_total + aux
         if stats is not None:
             routing.append(stats)
         if rms is not None:
-            state_rms.append(rms)
+            state_rms[spec.mixer].append(rms)
     stats = jax.tree.map(lambda *a: jnp.stack(a), *routing) if routing \
         else {}
-    if state_rms:
-        stats["ssm_state_rms"] = jnp.stack(state_rms)
+    if state_rms["mamba2"]:
+        stats["ssm_state_rms"] = jnp.stack(state_rms["mamba2"])
+    if state_rms["kda"]:
+        stats["kda_state_rms"] = jnp.stack(state_rms["kda"])
     return x, aux_total, stats
 
 

@@ -453,7 +453,9 @@ def _from_slab(x, b, h):
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
 def flash_attention(q, k, v, causal=True, block_size=512, interpret=False,
                     window=None, scale=None):
-    """Fused attention. q/k/v: (B, S, H, D); returns (B, S, H, D).
+    """Fused attention. q/k/v: (B, S, H, D); returns (B, S, H, D). v may
+    have a head size of its own, which is then the output's (q and k of
+    192 beside v of 128 in a latent-attention layer).
 
     Same contract as ring_attention/dense_attention (parallel/
     ring_attention.py) — drop-in for the per-shard attention inside the
@@ -486,8 +488,12 @@ def _pad_seq(x, s_pad):
 
 def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None,
                     scale=None):
-    """Returns (out, lse) with lse shaped (B*H, 1, S)."""
+    """Returns (out, lse) with lse shaped (B*H, 1, S). q and k share one
+    head size, v and out another (``v.shape[-1]``: latent attention has q
+    and k of 192 beside v of 128); one size for all four is the usual
+    case."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]
     group = _gqa_group(q, k, v)
     if window is not None:
         if not causal:
@@ -541,19 +547,19 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None,
         in_specs=[
             pl.BlockSpec((1, block, d), lambda bh, qi, kj: (bh, qi, 0)),
             pl.BlockSpec((1, block, d), kv_map),
-            pl.BlockSpec((1, block, d), kv_map),
+            pl.BlockSpec((1, block, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block, d), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((1, block, dv), lambda bh, qi, kj: (bh, qi, 0)),
             # lse rides as (B*H, 1, block-of-S): TPU lowering needs the
             # trailing two block dims to tile (8, 128) or match the array.
             pl.BlockSpec((1, 1, block), lambda bh, qi, kj: (bh, 0, qi)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
-        scratch_shapes=_softmax_scratch(block, d),
+        scratch_shapes=_softmax_scratch(block, dv),
         interpret=interpret,
     )(qs, ks, vs)
     return _from_slab(out, b, h), lse
@@ -618,6 +624,7 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
     pass (``out`` may then be None) — ring attention computes one global
     delta and feeds every tile's backward from it."""
     b, s, h, d = q.shape
+    dv = v.shape[-1]    # v, out and their cotangents; q and k have d
     group = _gqa_group(q, k, v)
     h_kv = k.shape[2]
     scale = _softmax_scale(d, scale)
@@ -641,13 +648,13 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
         delta_pad = None
         if delta is not None:
             delta_pad = jnp.pad(delta, ((0, 0), (0, 0), (0, s_pad - s)))
-        dq, dk, dv = _flash_bwd_impl(
+        dq, dk, dv_ = _flash_bwd_impl(
             causal, bs, interpret, _pad_seq(q, s_pad),
             _pad_seq(k, s_pad), _pad_seq(v, s_pad),
             None if out is None else _pad_seq(out, s_pad),
             lse_pad, _pad_seq(g, s_pad), g_lse_pad, window, delta_pad,
             scale)
-        return dq[:, :s], dk[:, :s], dv[:, :s]
+        return dq[:, :s], dk[:, :s], dv_[:, :s]
     n = s // block
 
     qs, ks, vs = _to_slab(q), _to_slab(k), _to_slab(v)
@@ -662,23 +669,22 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
         if g_lse is not None:
             delta = delta - g_lse.astype(jnp.float32).reshape(b * h, 1, s)
 
-    q_blk = pl.BlockSpec((1, block, d), lambda bh, i, j: (bh, i, 0))
+    def q_blk(w):
+        return pl.BlockSpec((1, block, w), lambda bh, i, j: (bh, i, 0))
+
     wb = None if window is None else _window_blocks(window, block)
     # same DMA clamp as the forward: pruned (j > i) cells re-address the
     # diagonal K/V block instead of streaming a block they won't use
     # (K/V rows indexed through // group for GQA, as in the forward);
     # a window additionally clamps below the band start
     if causal and window is not None:
-        kv_blk = pl.BlockSpec(
-            (1, block, d),
-            lambda bh, i, j: (bh // group, jnp.clip(j, i - wb, i), 0))
+        kv_map = lambda bh, i, j: (bh // group,  # noqa: E731
+                                   jnp.clip(j, i - wb, i), 0)
     elif causal:
-        kv_blk = pl.BlockSpec(
-            (1, block, d),
-            lambda bh, i, j: (bh // group, jnp.minimum(j, i), 0))
+        kv_map = lambda bh, i, j: (bh // group,  # noqa: E731
+                                   jnp.minimum(j, i), 0)
     else:
-        kv_blk = pl.BlockSpec((1, block, d),
-                              lambda bh, i, j: (bh // group, j, 0))
+        kv_map = lambda bh, i, j: (bh // group, j, 0)  # noqa: E731
     vec_q = pl.BlockSpec((1, 1, block), lambda bh, i, j: (bh, 0, i))
 
     dq = _named_pallas_call(
@@ -686,8 +692,10 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
         functools.partial(_bwd_dq_kernel, block=block, num_kv=n,
                           scale=scale, causal=causal, window=window),
         grid=(b * h, n, n),
-        in_specs=[q_blk, kv_blk, kv_blk, q_blk, vec_q, vec_q],
-        out_specs=q_blk,
+        in_specs=[q_blk(d), pl.BlockSpec((1, block, d), kv_map),
+                  pl.BlockSpec((1, block, dv), kv_map), q_blk(dv), vec_q,
+                  vec_q],
+        out_specs=q_blk(d),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
         interpret=interpret,
@@ -697,22 +705,18 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
     # Pruned cells here are j (q block) < i (k block): clamp the q-side
     # DMAs up to the diagonal.
     if causal and window is not None:
-        q_in = pl.BlockSpec(
-            (1, block, d),
-            lambda bh, i, j: (bh, jnp.clip(j, i, i + wb), 0))
+        q_map = lambda bh, i, j: (bh, jnp.clip(j, i, i + wb), 0)  # noqa: E731
         vec_in = pl.BlockSpec(
             (1, 1, block),
             lambda bh, i, j: (bh, 0, jnp.clip(j, i, i + wb)))
     elif causal:
-        q_in = pl.BlockSpec((1, block, d),
-                            lambda bh, i, j: (bh, jnp.maximum(j, i), 0))
+        q_map = lambda bh, i, j: (bh, jnp.maximum(j, i), 0)  # noqa: E731
         vec_in = pl.BlockSpec((1, 1, block),
                               lambda bh, i, j: (bh, 0, jnp.maximum(j, i)))
     else:
-        q_in = pl.BlockSpec((1, block, d), lambda bh, i, j: (bh, j, 0))
+        q_map = lambda bh, i, j: (bh, j, 0)  # noqa: E731
         vec_in = pl.BlockSpec((1, 1, block), lambda bh, i, j: (bh, 0, j))
-    k_in = pl.BlockSpec((1, block, d),
-                        lambda bh, i, j: (bh // group, i, 0))
+    k_map = lambda bh, i, j: (bh // group, i, 0)  # noqa: E731
     # dK/dV accumulate across the `group` query heads sharing each kv
     # head. The kernel writes per-q-head partials (scratch accumulation
     # across grid dim 0 would be clobbered by the inner k-block loop);
@@ -720,29 +724,31 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
     # group > 1 the partials stay f32 so that reduction keeps the f32
     # accumulation used everywhere else (casting to bf16 before the
     # group-sum would lose the low bits the sum is meant to carry).
-    dk_out = pl.BlockSpec((1, block, d), lambda bh, i, j: (bh, i, 0))
     part_dtype = jnp.float32 if group > 1 else k.dtype
-    dk, dv = _named_pallas_call(
+    dk, dv_ = _named_pallas_call(
         "hvd_flash_dkv",
         functools.partial(_bwd_dkv_kernel, block=block, num_q=n,
                           scale=scale, causal=causal, window=window),
         grid=(b * h, n, n),
-        in_specs=[q_in, k_in, k_in, q_in, vec_in, vec_in],
-        out_specs=[dk_out, dk_out],
+        in_specs=[pl.BlockSpec((1, block, d), q_map),
+                  pl.BlockSpec((1, block, d), k_map),
+                  pl.BlockSpec((1, block, dv), k_map),
+                  pl.BlockSpec((1, block, dv), q_map), vec_in, vec_in],
+        out_specs=[q_blk(d), q_blk(dv)],
         out_shape=[jax.ShapeDtypeStruct((b * h, s, d), part_dtype),
-                   jax.ShapeDtypeStruct((b * h, s, d), part_dtype)],
+                   jax.ShapeDtypeStruct((b * h, s, dv), part_dtype)],
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
-                        pltpu.VMEM((block, d), jnp.float32)],
+                        pltpu.VMEM((block, dv), jnp.float32)],
         interpret=interpret,
     )(qs, ks, vs, dos, lse, delta)
 
     if group > 1:
         dk = dk.reshape(b, h_kv, group, s, d).sum(axis=2).reshape(
             b * h_kv, s, d).astype(k.dtype)
-        dv = dv.reshape(b, h_kv, group, s, d).sum(axis=2).reshape(
-            b * h_kv, s, d).astype(v.dtype)
+        dv_ = dv_.reshape(b, h_kv, group, s, dv).sum(axis=2).reshape(
+            b * h_kv, s, dv).astype(v.dtype)
     return (_from_slab(dq, b, h), _from_slab(dk, b, h_kv),
-            _from_slab(dv, b, h_kv))
+            _from_slab(dv_, b, h_kv))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

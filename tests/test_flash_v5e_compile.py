@@ -74,25 +74,32 @@ CELLS = {
     # granite4h-micro_s16k: the one attention layer, heads of 64 (half
     # the 128 lanes), 4 query heads a kv head, the scale the model states
     "granite_full": (1, 16384, 32, 8, 64, None),
+    # kimi-linear_s16k: the latent-attention layer, q and k of 192 (one
+    # and a half lane tiles) beside v of 128 (V_SIZES), every head its key
+    "kimi_mla": (1, 16384, 32, 32, 192, None),
 }
 # the factor on q.k where it is not 1 / sqrt(D)
 SCALES = {"granite_full": 0.015625}
+# v's head size where it is not q's and k's
+V_SIZES = {"kimi_mla": 128}
 
 
 def _shapes(cell, sharding):
+    """``(q, k, v, window)`` of a cell's attention layer."""
     b, s, h, h_kv, d, window = CELLS[cell]
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=sharding)
-    kv = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16,
-                              sharding=sharding)
-    return q, kv, window
+    k, v = (jax.ShapeDtypeStruct((b, s, h_kv, width), jnp.bfloat16,
+                                 sharding=sharding)
+            for width in (d, V_SIZES.get(cell, d)))
+    return q, k, v, window
 
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_flash_forward_compiles_for_v5e(one_chip, cell):
-    q, kv, window = _shapes(cell, one_chip)
+    q, k, v, window = _shapes(cell, one_chip)
     text = jax.jit(lambda q, k, v: flash_attention(
         q, k, v, True, 512, False, window, SCALES.get(cell))).lower(
-            q, kv, kv).compile().as_text()
+            q, k, v).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
 
@@ -100,7 +107,7 @@ def test_flash_forward_compiles_for_v5e(one_chip, cell):
 def test_flash_vjp_compiles_for_v5e(one_chip, cell):
     """Forward + dQ + dK/dV: the three Mosaic calls of one layer's
     attention in the train step."""
-    q, kv, window = _shapes(cell, one_chip)
+    q, k, v, window = _shapes(cell, one_chip)
 
     def loss(q, k, v):
         out = flash_attention(q, k, v, True, 512, False, window,
@@ -108,7 +115,7 @@ def test_flash_vjp_compiles_for_v5e(one_chip, cell):
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        q, kv, kv).compile().as_text()
+        q, k, v).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 

@@ -279,7 +279,9 @@ def test_kernel_and_head_names_in_the_step_jaxpr():
                  "hvd_moe_dispatch", "hvd_moe_experts", "hvd_moe_combine",
                  "hvd_moe_shared", "hvd_ssm", "hvd_ssm_in_proj",
                  "hvd_ssm_conv", "hvd_ssm_scan", "hvd_ssm_norm",
-                 "hvd_ssm_out_proj"):
+                 "hvd_ssm_out_proj", "hvd_kda", "hvd_kda_in_proj",
+                 "hvd_kda_conv", "hvd_kda_scan", "hvd_kda_norm",
+                 "hvd_kda_out_proj", "hvd_mla_proj"):
         assert phase_of_op_name(f"jit(f)/{name}/x") is None
 
 
