@@ -810,6 +810,14 @@ KDA_LAYERS = _registry.gauge(
     "last; set while it is traced, not per step.")
 
 
+KDA_FUSED_LAYERS = _registry.gauge(
+    "hvd_kda_fused_layers",
+    "KDA layers of that model whose recurrence compiled to the Pallas "
+    "kernel pair (ops/kda_scan.py: a head of whole 128-lane tiles); the "
+    "others run it as XLA ops and say so once in the log. Set where "
+    "hvd_kda_layers is set.")
+
+
 def record_kda_state(stats):
     """:func:`record_ssm_state` for the KDA layers: set
     hvd_kda_state_rms{layer} from the fetched aux's ``kda_state_rms``

@@ -16,7 +16,13 @@ One layer, ``h`` the normed layer input (B, L, d_model), ``H`` heads of
     o_t = S_t^T q_t / sqrt(D)
     out = (rmsnorm_D(o_t) * norm * sigmoid((h w_ga) w_gb)) wo
 
-The recurrence runs in its chunked form (:func:`kda_chunked`). With ``G``
+The recurrence runs in its chunked form, in one of two forms that the
+head size chooses (one algorithm, no flag): a head of whole 128-lane
+tiles — every published KDA model's — runs the Pallas kernel pair of
+``ops/kda_scan.py`` (:func:`~horovod_tpu.ops.kda_scan.kda_scan`), which
+keeps the state and a chunk's intermediates in VMEM; any other head size
+(the tests' toy models) runs :func:`kda_chunked`, the same arithmetic in
+XLA ops, which is also the kernels' oracle. With ``G``
 the cumulative sum of ``g`` inside a chunk of ``C`` positions and ``S_0``
 the state before it, the chunk's ``C`` rank-1 updates are one triangular
 system (the WY form of the delta rule)::
@@ -49,15 +55,17 @@ cancel badly.
 Cumulative sums, decays, the solve and the carried state are float32
 whatever ``dtype`` is; the matrix products take their operands in
 ``dtype`` and accumulate in float32, like every other matmul of the
-model. The chunks are scanned :data:`BLOCK_CHUNKS` at a time under
-``jax.checkpoint`` (one block's ``A``, ``P``, ``T`` are live, not a
-layer's), XLA ops throughout: the state goes through HBM once a chunk,
-which a fused kernel would keep on the chip (ROADMAP B2).
+model. :func:`kda_chunked` scans the chunks :data:`BLOCK_CHUNKS` at a
+time under ``jax.checkpoint`` (one block's ``A``, ``P``, ``T`` are live,
+not a layer's) and its state goes through HBM once a chunk; the kernels
+cut a chunk of 64 their own way (ops/kda_scan.py) and write only ``o``,
+the final state and, for the backward, the state before each chunk.
 
 Device scopes: ``hvd_kda`` around ``hvd_kda_in_proj`` (q, k, v, the two
 low-rank pairs, beta), ``hvd_kda_conv``, ``hvd_kda_scan`` (l2norm,
 softplus and decays, the chunk's products and solve, the recurrence over
-chunks), ``hvd_kda_norm`` and ``hvd_kda_out_proj``.
+chunks — with the kernels, ``hvd_kda_fwd`` and ``hvd_kda_bwd`` inside it),
+``hvd_kda_norm`` and ``hvd_kda_out_proj``.
 
 Training only: there is no single-token state update, so the decode and
 serve paths refuse a KDA layer; sequence parallelism would have to hand
@@ -65,6 +73,7 @@ the state from shard to shard and is refused here.
 """
 
 import dataclasses
+import functools
 import math
 from typing import Any
 
@@ -72,12 +81,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops import kda_scan
+from ..utils.logging import get_logger
 from .ssm import causal_conv1d
 
 # Positions of a chunk and of a sub-chunk (see the module docstring) and the
-# chunks a step of the scan's loop takes: the implementation's, no key of a
-# published configuration (the family's kernels take chunks of 64 and
-# sub-chunks of 16). Swept on the v5e at the published
+# chunks a step of the scan's loop takes in the XLA form: the
+# implementation's, no key of a published configuration. Swept on the v5e
+# at the published
 # shapes (32 heads of 128, 16,384 positions; chunk, sub-chunk, chunks a step:
 # forward / gradient ms a layer, PERF.md section 6 PR 33): 64, 16, 8: 22.9 /
 # 96.4; 64, 8, 8: 22.2 / 92.0; 64, 8, 4: 22.3 / 74.6; 64, 4, 8: 23.6 / 95.6;
@@ -102,10 +113,24 @@ class KDAConfig:
     norm_eps: float
     dtype: Any
     param_dtype: Any
+    interpret: bool = False     # the kernels in the Pallas interpreter
 
     @property
     def d_inner(self):
         return self.n_heads * self.head_dim
+
+    @property
+    def fused(self):
+        """Whether the recurrence runs as the kernel pair: read off the
+        head size alone."""
+        return kda_scan.takes(self.head_dim)
+
+
+@functools.lru_cache(maxsize=None)
+def _say_xla_form(head_dim):
+    get_logger("horovod_tpu.models.kda").warning(
+        "KDA layers of head size %d run the recurrence as XLA ops: the "
+        "kernels take a head of whole 128-lane tiles", head_dim)
 
 
 def init_kda_params(key, cfg):
@@ -371,9 +396,16 @@ def kda_mixer(params, h, cfg):
                 x, params["conv_w"][i], jnp.zeros((), f32))).astype(dtype)
                 for i, x in enumerate(qkv))
         with jax.named_scope("hvd_kda_scan"):
-            o, state = kda_chunked(
-                *(x.reshape(b, l, hn, hd) for x in (q, k, v, f)), beta,
-                CHUNK, prepare=prepare)
+            if cfg.fused:
+                o, state = kda_scan.kda_scan(
+                    q, k, v, f, beta, params["A_log"], params["dt_bias"],
+                    interpret=cfg.interpret)
+                o = o.reshape(b, l, hn, hd)
+            else:
+                _say_xla_form(hd)
+                o, state = kda_chunked(
+                    *(x.reshape(b, l, hn, hd) for x in (q, k, v, f)), beta,
+                    CHUNK, prepare=prepare)
             state_rms = jnp.sqrt(jnp.mean(jnp.square(state),
                                           axis=(0, 2, 3)))
         with jax.named_scope("hvd_kda_norm"):

@@ -346,7 +346,8 @@ class TransformerConfig:
         return KDAConfig(d_model=self.d_model, n_heads=self.kda_heads,
                          head_dim=self.kda_head_dim, d_conv=self.kda_conv,
                          norm_eps=self.norm_eps,
-                         dtype=self.dtype, param_dtype=self.param_dtype)
+                         dtype=self.dtype, param_dtype=self.param_dtype,
+                         interpret=self.flash_interpret)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1061,6 +1062,8 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     # leave the gauge as it was)
     metrics.FFN_GATED_LAYERS.set(sum("w3" in p for p in params["layers"]))
     metrics.KDA_LAYERS.set(cfg.kda_layers)
+    metrics.KDA_FUSED_LAYERS.set(
+        cfg.kda_layers if cfg.kda_cfg.fused else 0)
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 

@@ -119,6 +119,47 @@ def test_flash_vjp_compiles_for_v5e(one_chip, cell):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+def test_kda_kernels_compile_for_v5e(one_chip):
+    """kimi-linear_s16k's recurrence, 32 heads of 128 over 16,384
+    positions in bf16: the forward kernel (as the forward pass calls it)
+    and, under the gradient, the forward kernel that saves the states and
+    the backward kernel — strided row blocks, 64 x 64 transposes and the
+    backward's 25 MiB of VMEM are what the interpreter does not see."""
+    from horovod_tpu.ops import kda_scan
+    b, l, h, d = 1, 16384, 32, 128
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (like((b, l, h * d), jnp.bfloat16),) * 4 + (
+        like((b, l, h), jnp.float32), like((h,), jnp.float32),
+        like((h * d,), jnp.float32))
+
+    def scan(*a):
+        with jax.named_scope("hvd_kda_scan"):
+            return kda_scan.kda_scan(*a)
+
+    def loss(*a):
+        o, state = scan(*a)
+        return o.astype(jnp.float32).sum() + state.sum()
+
+    # the compiled op_name joins the caller's scopes and the kernel's
+    # name, forward and backward: what dev_kda_scan_ms, kda_fwd_ms and
+    # kda_bwd_ms select on
+    for fn, names in ((scan, [("_forward", "hvd_kda_fwd")]),
+                      (jax.grad(loss, argnums=tuple(range(7))),
+                       [("_forward", "hvd_kda_fwd"),
+                        ("_backward", "hvd_kda_bwd")])):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == len(names)
+        for call, kernel in names:   # under autodiff: jvp(hvd_kda_scan)
+            assert sum(bool(re.search(
+                rf"hvd_kda_scan\)*/jit\({call}\)/{kernel}/", line))
+                for line in calls) == 1
+
+
 # (rows, contraction, columns): laguna-s21_s8k's expert matrices over one
 # chunk of sorted rows (moe.chunk_rows at 16,384 tokens), 8 experts, bf16
 @pytest.mark.parametrize("shape", [(10240, 3072, 1024),

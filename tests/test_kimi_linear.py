@@ -8,6 +8,7 @@ seeded random weights.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import math
 import os
@@ -109,6 +110,130 @@ def test_chunked_kda_is_the_sequential_recurrence(seq, chunk, decay):
     for name, g, w in zip("qkvgb", grads, want):
         assert bool(jnp.isfinite(g).all()), name
         assert rel_err(g, w) < 1e-4, name
+
+
+def kernel_inputs(l, dtype, decay, beta):
+    """What the projections leave at 2 heads of 128: q, k (not yet
+    normalised), v, the decay's input (1, l, 256) in ``dtype``; beta's
+    logits (1, l, 2); A_log (2,), dt_bias (256,). ``decay`` > 1: half the
+    channels decay by up to -20 a position, the others hardly; ``beta``:
+    None, or the value sigmoid(logits) takes everywhere."""
+    ks = jax.random.split(jax.random.PRNGKey(l), 6)
+    q, k, v, f = (jax.random.normal(key, (1, l, 256)) for key in ks[:4])
+    a_log = jnp.log(jnp.array([3.0, 1.5]))
+    dt_bias = 0.1 * jax.random.normal(ks[4], (256,))
+    if decay > 1:
+        f = jnp.where(jnp.arange(256) % 2 == 0,
+                      5.0 * jax.random.uniform(ks[3], (1, l, 256)), -6.0)
+        a_log, dt_bias = jnp.log(jnp.array([4.0, 4.0])), 0.0 * dt_bias
+    logits = (jax.random.normal(ks[5], (1, l, 2)) if beta is None
+              else jnp.full((1, l, 2), 1e4 if beta else -1e4, jnp.float32))
+    return tuple(x.astype(dtype) for x in (q, k, v, f)) + (
+        logits, a_log, dt_bias)
+
+
+def prepared(q, k, v, f, logits, a_log, dt_bias):
+    """What ``kda_mixer``'s ``prepare`` makes of them, (1, l, 2, 128): q
+    and k normalised, the log-decay, sigmoid(logits)."""
+    q, k, v, f = (x.reshape(x.shape[:2] + (2, 128)) for x in (q, k, v, f))
+    q, k = (kda._l2norm(x).astype(x.dtype) for x in (q, k))
+    g = -jnp.exp(a_log)[:, None] * jax.nn.softplus(
+        f.astype(jnp.float32) + dt_bias.reshape(2, 128))
+    return q, k, v, g, jax.nn.sigmoid(logits)
+
+
+@functools.lru_cache(maxsize=None)
+def value_and_grads(form, seq):
+    """``(o, final state)`` and the gradient of every input, jitted once
+    a form and length (the interpreted kernels take seconds to compile):
+    the Pallas kernel pair, ``kda_chunked``, or the sequential
+    recurrence (values only)."""
+    from horovod_tpu.ops import kda_scan
+
+    def run(*a):
+        if form == "kernels":
+            return kda_scan.kda_scan(*a, interpret=True)
+        o, s = kda.kda_chunked(*prepared(*a),
+                               chunk=kda.CHUNK if seq > 32 else 8)
+        return o.reshape(1, seq, 256), s
+
+    def f(*a):
+        o, s = run(*a)
+        o = o.astype(jnp.float32)
+        return jnp.sum(o * jnp.cos(o)) + jnp.sum(s * s), (o, s)
+
+    if form == "sequential":
+        return jax.jit(lambda *a: ref.recurrence(*(
+            x.astype(jnp.float32) for x in prepared(*a))))
+    return jax.jit(jax.value_and_grad(f, argnums=tuple(range(7)),
+                                      has_aux=True))
+
+
+@pytest.mark.parametrize("seq, decay, beta, dtype", [
+    (128, 1.0, None, "float32"), (72, 1.0, None, "float32"),
+    (24, 1.0, None, "float32"), (128, 20.0, None, "float32"),
+    (128, 1.0, 0, "float32"), (128, 1.0, 1, "float32"),
+    (128, 1.0, None, "bfloat16"), (128, 20.0, None, "bfloat16"),
+    (128, 1.0, 0, "bfloat16"), (128, 1.0, 1, "bfloat16")])
+def test_kda_kernels_are_the_xla_form(seq, decay, beta, dtype):
+    """The Pallas kernel pair (interpreted) against ``kda_chunked`` on the
+    same inputs — ``o``, the final state and the gradient of EVERY input
+    (q, k, v, the decay's input, beta's logits, A_log, dt_bias) — and,
+    for values, against the sequential recurrence: whole chunks, a padded
+    tail, less than a chunk, log-decays of -20 a position (nothing inf or
+    nan), beta at 0 and at 1; float32 at the chunked form's own
+    tolerances, bfloat16 at 8 / 16 of its eps (the two forms round at the
+    same places and cut their chunks differently)."""
+    args = kernel_inputs(seq, jnp.dtype(dtype), decay, beta)
+    tol_v, tol_g = (2e-5, 1e-4) if dtype == "float32" else (2 ** -5, 2 ** -4)
+    with jax.default_matmul_precision("highest"):
+        (_, (o, s)), grads = value_and_grads("kernels", seq)(*args)
+        (_, (o_w, s_w)), want = value_and_grads("xla", seq)(*args)
+        o_r, s_r = value_and_grads("sequential", seq)(*args)
+    assert o.shape == (1, seq, 256) and s.shape == (1, 2, 128, 128)
+    assert rel_err(o, o_w) < tol_v and rel_err(s, s_w) < tol_v
+    assert rel_err(o, o_r.reshape(o.shape)) < tol_v
+    assert rel_err(s, s_r) < tol_v
+    for name, g, w in zip(("q", "k", "v", "f", "beta", "A_log", "dt_bias"),
+                          grads, want):
+        g, w = g.astype(jnp.float32), w.astype(jnp.float32)
+        assert g.shape == w.shape and bool(jnp.isfinite(g).all()), name
+        if beta is not None and name == "beta":
+            assert not g.any() and not w.any()   # sigmoid is flat there
+            continue
+        assert rel_err(g, w) < tol_g, name
+
+
+@pytest.mark.parametrize("head_dim, fused", [(16, 0), (128, 2)])
+def test_the_head_size_chooses_the_form(head_dim, fused, caplog):
+    """A head of 16 runs the XLA form and says so once in the log; a
+    head of 128 runs the kernels (``hvd_kda_fwd`` in the traced program)
+    — ``hvd_kda_fused_layers`` reads 0 / the KDA layers, and no
+    configuration key, flag or environment variable enters."""
+    import horovod_tpu as hvd
+    cfg = make_cfg(kinds=("kda", "mla", "kda"), kda_heads=1,
+                   kda_head_dim=head_dim)
+    assert cfg.kda_cfg.fused == bool(fused)
+    shapes = jax.eval_shape(lambda k: tfm.init_params(k, cfg),
+                            jax.random.PRNGKey(0))
+    tok, tgt = batch()
+    kda._say_xla_form.cache_clear()
+    from horovod_tpu.utils.logging import get_logger
+    logger = get_logger("horovod_tpu.models.kda")   # does not propagate
+    logger.addHandler(caplog.handler)
+    try:
+        text = str(jax.make_jaxpr(
+            lambda p: tfm.loss_and_stats(p, tok, tgt, cfg))(shapes))
+    finally:
+        logger.removeHandler(caplog.handler)
+    said = [r.getMessage() for r in caplog.records
+            if "run the recurrence as XLA ops" in r.getMessage()]
+    assert ("hvd_kda_fwd" in text) == bool(fused)
+    assert len(said) == (0 if fused else 1)
+    assert all("head size 16" in line for line in said)
+    snap = hvd.metrics_snapshot()
+    assert snap["hvd_kda_layers"]["values"][""] == 2
+    assert snap["hvd_kda_fused_layers"]["values"][""] == fused
 
 
 def test_a_chunk_is_whole_sub_chunks():
@@ -267,15 +392,19 @@ def test_the_cell_s_parameter_count():
     assert count(shapes["layers"][1]["mla"]) == 29_114_880
 
 
-def test_every_new_scope_is_in_the_step_s_hlo():
+@pytest.mark.parametrize("head_dim", [16, 128])
+def test_every_new_scope_is_in_the_step_s_hlo(head_dim):
     """``hvd_kda`` around its five parts, ``hvd_mla_proj`` and the
     latent-attention layer's kernels under ``hvd_attn_full``, forward and
-    backward; none of the names is a step-region label."""
+    backward; none of the names is a step-region label. With a head of
+    128 ``hvd_kda_scan`` holds the forward kernel (in the forward and in
+    remat's second one) and the backward kernel, a ``custom_vjp``'s."""
     import re
 
     from horovod_tpu.diag.xla_trace import phase_of_op_name
     cfg = make_cfg(kinds=("kda", "mla"), attention_impl="flash",
-                   remat=True)
+                   remat=True, kda_heads=1 if head_dim == 128 else H,
+                   kda_head_dim=head_dim)
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     tok, tgt = batch()
 
@@ -299,6 +428,19 @@ def test_every_new_scope_is_in_the_step_s_hlo():
         assert any(re.search(r"hvd_mla_proj\)?/", p) for p in region)
     for kernel in ("hvd_flash_fwd", "hvd_flash_dq", "hvd_flash_dkv"):
         assert any(re.search(rf"hvd_attn_full/{kernel}", p) for p in paths)
+    if head_dim == 128:
+        # each kernel's call is jitted (one lowering a model): the call
+        # site carries the scopes, the callee the kernel's own name, and
+        # XLA joins them (tests/test_flash_v5e_compile.py reads the
+        # compiled op_name)
+        for region, calls in ((paths - backward, ("_forward",)),
+                              (backward, ("_forward", "_backward"))):
+            for call in calls:
+                assert any(re.search(rf"hvd_kda_scan/jit\({call}\)$", p)
+                           for p in region), call
+        inside = set(re.findall(r'loc\("(hvd_kda_(?:fwd|bwd)/[^"]*)"', text))
+        for kernel in ("hvd_kda_fwd", "hvd_kda_bwd"):
+            assert any(p.startswith(f"{kernel}/{kernel}/") for p in inside)
     assert any("hvd_moe_route" in p for p in paths)
     for name in parts + ("hvd_kda", "hvd_mla_proj"):
         assert phase_of_op_name(f"jit(f)/{name}/x") is None
