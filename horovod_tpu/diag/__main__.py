@@ -22,9 +22,14 @@ on the flight view's clock through the sidecar's clock mapping (the
 program's spans are in the ring and in the capture; the median
 difference is the offset) — and the report gains a per-phase device-time
 breakdown (forward / backward / exchange / optimizer / guard / other),
-the named kernels, the collectives by message size and the clock
-mapping's quality: the device-level critical path next to the host-side
-flight view.
+the named kernels, the collectives by message size, the clock mapping's
+quality, device time by scope path and op class, and the fifteen matmul
+fusions that lose most time against their FLOPs: the device-level
+critical path next to the host-side flight view. DIR may also be a
+capture without a sidecar beside the HLO text of the program that ran —
+a directory holding ``*.xplane.pb`` and ``*.hlo.txt``, or one
+``<name>.xplane.pb`` with ``<name>.hlo.txt`` next to it, as the
+benchmark's ``--dump-dir`` writes them.
 
 Usage::
 
@@ -143,10 +148,23 @@ def load_xla_trace(trace_dir):
     and on the wall clock the dumps are merged on. The events list is
     empty when the capture has no such pair (no program span ran in it):
     device timestamps alone cannot be aligned to the flight view."""
-    from .xla_trace import load_meta, read_capture, summarize
+    from .xla_trace import (build_op_table, load_meta, read_capture,
+                            summarize)
     meta = load_meta(trace_dir) or {}
     events = read_capture(trace_dir)
-    summary = meta.get("summary") or summarize(events)
+    summary = meta.get("summary")
+    if summary is None:
+        # no sidecar: join against the HLO text kept beside the capture
+        table = {}
+        for path in ([trace_dir[:-len(".xplane.pb")] + ".hlo.txt"]
+                     if os.path.isfile(trace_dir) else sorted(glob.glob(
+                         os.path.join(trace_dir, "*.hlo.txt")))):
+            try:
+                with open(path, encoding="utf-8") as fh:
+                    table.update(build_op_table(fh.read()))
+            except OSError:
+                pass
+        summary = summarize(events, table)
     if summary is None:
         print(f"warning: no parseable device events under {trace_dir}",
               file=sys.stderr)
@@ -283,7 +301,7 @@ def print_report(report, desync=None):
 def print_xla_report(xla):
     """Per-phase device-time breakdown for a --xla-trace capture."""
     s = xla["summary"]
-    steps = max(int(xla["meta"].get("steps", 1) or 1), 1)
+    steps = max(int(xla["meta"].get("steps") or s.get("step_runs") or 1), 1)
     lanes = max(int(s.get("lanes", 1) or 1), 1)
     print(f"xla device trace: {xla['dir']}  steps={steps} lanes={lanes} "
           f"events={s.get('events', 0)} "
@@ -322,6 +340,55 @@ def print_xla_report(xla):
         print(f"  clock: {clock['pairs']} span pairs, spread "
               f"{round(clock['spread_ns'] * 1e-3, 1)} us, host-device "
               f"skew <= {clock.get('host_device_skew_bound_us')} us")
+    _print_scope_table(s.get("classes") or {}, steps * lanes)
+    _print_matmul_table(s.get("matmuls") or [], steps * lanes)
+
+
+def _print_scope_table(classes, runs):
+    """ms a step a lane by scope path (rows, the longest first) and op
+    class (columns)."""
+    if not classes:
+        return
+    from .xla_trace import CLASSES
+    cols = [c for c in CLASSES if any(c in by for by in classes.values())]
+    width = max(len(p) for p in classes)
+    print(f"  device ms/step/lane by scope path and op class:\n  "
+          f"{'scope':<{width}}  {'total':>9}"
+          + "".join(f"  {c:>14}" for c in cols))
+    for path, by in sorted(classes.items(),
+                           key=lambda kv: -sum(kv[1].values())):
+        print(f"  {path:<{width}}  {sum(by.values()) / runs * 1e3:9.3f}"
+              + "".join(f"  {by.get(c, 0.0) / runs * 1e3:14.3f}"
+                        for c in cols))
+
+
+def _print_matmul_table(rows, runs, limit=15):
+    """The matmul rows that lose most: calls and ms a step a lane, a
+    call's GFLOP, its time at the peak and the time lost beside it."""
+    if not rows:
+        return
+    total = sum(r["calls"] * r["flops"] for r in rows
+                if r["flops"] is not None) / runs
+    unknown = sum(r["flops"] is None for r in rows)
+    print(f"  matmul fusions: {len(rows)} rows, {total * 1e-12:.4f} TFLOP"
+          f"/step/lane" + (f", {unknown} without FLOPs" if unknown else "")
+          + f"; the {min(limit, len(rows))} that lose most "
+          "(calls, ms, ms at the peak, ms lost /step/lane; GFLOP a call):")
+    def cell(value, scale, digits=3):
+        return ("-" if value is None
+                else f"{value * scale:.{digits}f}").rjust(8)
+
+    per_run = 1e3 / runs
+    for r in rows[:limit]:
+        at_peak = r.get("at_peak_s")
+        cells = [f"{r['calls'] / runs:6.1f}", cell(r["device_s"], per_run),
+                 cell(at_peak and at_peak * r["calls"], per_run),
+                 cell(r.get("lost_s"), per_run), cell(r["flops"], 1e-9, 2)]
+        rides = ", ".join(r["rides"]
+                          + ["all-reduce"] * r["carries_collective"])
+        print("  " + " ".join(cells)
+              + f"  {r['operands']}  {r['scope']}  {r['lhs']} * {r['rhs']}"
+              f" -> {r['result']}" + (f"  rides: {rides}" if rides else ""))
 
 
 def main(argv=None):

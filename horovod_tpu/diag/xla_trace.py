@@ -31,7 +31,11 @@ dispatch per step. This module opens that box without TensorBoard:
   device SELF time (an enclosing ``while`` does not count its body twice)
   per phase; instructions outside any ``hvd_`` scope land in ``other``.
   It also reports the named kernels, the collectives by opcode and
-  message size, the host spans in the window and the clock mapping.
+  message size, the host spans in the window and the clock mapping —
+  and, from the fused computations' bodies :func:`build_op_table` keeps,
+  the same time by scope PATH (``backward/hvd_ffn/hvd_ffn_gate``) and op
+  class (``matmul``, ``copy``, ...) and every matmul fusion's time
+  against its FLOPs, with what rides along in it.
   The summary plus the clock mapping is written next to the capture as
   ``xla-trace-meta.json`` so the ``python -m horovod_tpu.diag
   --xla-trace`` merger can lay the device view on the flight-recorder
@@ -44,10 +48,12 @@ installed but idle is one attribute check.
 
 import glob
 import json
+import math
 import os
 import re
 import statistics
 import time
+import typing
 
 from .. import metrics
 from ..utils.logging import get_logger
@@ -78,6 +84,11 @@ META_FILENAME = "xla-trace-meta.json"
 _REGION_RE = re.compile(r"hvd_(forward|backward|exchange|optimizer|guard"
                         r"|prefill|decode)")
 _MOE_RE = re.compile(r"hvd_(dispatch|expert|combine)")
+# the labels above as whole names: what a scope PATH leaves out after its
+# first component (the phase)
+_PHASE_LABEL_RE = re.compile(
+    r"hvd_(?:forward|backward|exchange(?:_bucket\d+)?|optimizer|guard"
+    r"|prefill|decode|dispatch|expert|combine)")
 _STAGE_RE = re.compile(r"hvd_(ici|dcn)")
 _SCOPE_RE = re.compile(r"hvd_[a-z0-9_]+")
 # An HLO instruction, as a line of the optimized HLO text and as the name
@@ -88,6 +99,7 @@ _NAME_RE = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
 _OPCODE_RE = re.compile(r"[\]\})] ([a-z][a-z0-9\-]*)\(")
 _OP_NAME_RE = re.compile(r'metadata=\{[^}]*op_name="([^"]*)"')
 _ARRAY_RE = re.compile(r"\b([a-z]+[0-9]*[a-z0-9]*)\[([0-9,]*)\]")
+_LAYOUT_RE = re.compile(r"\{[^}]*\}")
 _MOSAIC_TARGET = 'custom_call_target="tpu_custom_call"'
 _DTYPE_BYTES = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8,
@@ -127,16 +139,22 @@ def kernel_of_op_name(op_name):
     return None
 
 
+def _match_instruction(text):
+    """The name's and the opcode's matches in one instruction's text, or
+    None: the result type lies between them, the operands after."""
+    m = _NAME_RE.match(text)
+    op = m and _OPCODE_RE.search(text, m.end() - 1)
+    return (m, op) if op else None
+
+
 def parse_instruction(text):
     """``(name, opcode, result type)`` of one HLO instruction's text (a
     line of HLO, or the name of a TPU ``XLA Ops`` event), ``None`` when
     the text is not an instruction."""
-    m = _NAME_RE.match(text)
-    if not m:
+    found = _match_instruction(text)
+    if not found:
         return None
-    op = _OPCODE_RE.search(text, m.end() - 1)
-    if not op:
-        return None
+    m, op = found
     return m.group(1), op.group(1), text[m.end():op.start() + 1]
 
 
@@ -145,7 +163,7 @@ def shape_bytes(shape, largest=False):
     arrays, or with ``largest`` takes the biggest — the output of an
     asynchronous start whose tuple also carries its operand)."""
     sizes = []
-    for dtype, dims in _ARRAY_RE.findall(re.sub(r"\{[^}]*\}", "", shape)):
+    for dtype, dims in _ARRAY_RE.findall(_LAYOUT_RE.sub("", shape)):
         width = _DTYPE_BYTES.get(dtype, 1 if dtype.startswith("f8") else 0)
         n = 1
         for d in dims.split(","):
@@ -156,25 +174,274 @@ def shape_bytes(shape, largest=False):
     return max(sizes) if largest else sum(sizes)
 
 
+_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
+
+
+class Op(typing.NamedTuple):
+    """One HLO instruction as :func:`build_op_table` keeps it. ``body``:
+    for a ``fusion``, the instructions of the computation it ``calls=``
+    (those of the fusions nested in it too), else empty. ``attrs``: for a
+    ``convolution`` / ``dot`` its ``dim_labels``, ``window``,
+    ``feature_group_count``, ``batch_group_count`` and contracting /
+    batch dimensions as the text gives them, for a ``custom-call`` its
+    ``custom_call_target``; None for every other opcode."""
+    opcode: str
+    shape: str
+    op_name: str
+    body: tuple = ()
+    operands: tuple = ()
+    attrs: typing.Optional[dict] = None
+
+
+_NO_OP = Op("", "", "")
+_OPERAND_RE = re.compile(r"%([\w.\-]+)")
+_ATTR_RE = re.compile(
+    r"\b(dim_labels|feature_group_count|batch_group_count"
+    r"|lhs_contracting_dims|rhs_contracting_dims|lhs_batch_dims"
+    r"|custom_call_target)=(?:\{([\d,]*)\}|\"([^\"]*)\"|([^\s,]+))")
+_WINDOW_RE = re.compile(r"\bwindow=\{([^}]*)\}")
+_KEPT_ATTRS = ("convolution", "dot", "custom-call")
+
+
+def _operands(text, start):
+    """The operand names between the parenthesis at ``start`` and its
+    match (jax 0.9 prints them as ``%name``; a TPU event's text puts the
+    type in front of each)."""
+    i = text.find(")", start)
+    if i < 0 or text.count("(", start + 1, i):   # a tuple type inside
+        depth = 0
+        for i in range(start, len(text)):
+            c = text[i]
+            if c == "(":
+                depth += 1
+            elif c == ")":
+                depth -= 1
+                if not depth:
+                    break
+    return tuple(_OPERAND_RE.findall(text, start, i)), i
+
+
+def _attrs(text, start):
+    out = {m.group(1): next(g for g in m.groups()[1:] if g is not None)
+           for m in _ATTR_RE.finditer(text, start)}
+    m = _WINDOW_RE.search(text, start)
+    if m:
+        out["window"] = m.group(1)
+    return out
+
+
 def build_op_table(hlo_text):
-    """``{instruction name: (opcode, result type, op_name)}`` from
-    optimized-HLO text; ``op_name`` is "" where the compiler created the
-    instruction without metadata."""
-    table = {}
+    """``{instruction name: Op}`` from optimized-HLO text — every
+    instruction of every computation, a fusion with the instructions of
+    the computation it calls as its ``body`` (the text defines a
+    computation before its caller). ``Op[:3]`` is ``(opcode, result
+    type, op_name)``; ``op_name`` is "" where the compiler created the
+    instruction without metadata, and the trace join tolerates an
+    instruction the table lacks (it falls into ``other``)."""
+    table, comps, comp = {}, {}, None
     for line in (hlo_text or "").splitlines():
-        inst = parse_instruction(line)
-        if inst:
-            m = _OP_NAME_RE.search(line)
-            table[inst[0]] = (inst[1], inst[2], m.group(1) if m else "")
+        if not line.startswith(" "):
+            m = _COMPUTATION_RE.match(line)
+            if m:
+                comp = comps.setdefault(m.group(1), [])
+            continue
+        found = _match_instruction(line)
+        if not found:
+            continue
+        m, op = found
+        opcode = op.group(1)
+        operands, end = _operands(line, op.end() - 1)
+        body = []
+        if opcode == "fusion":
+            called = _CALLS_RE.search(line, end)
+            for inner in comps.get(called.group(1), ()) if called else ():
+                body.append(inner)
+                body.extend(inner.body)
+        meta = _OP_NAME_RE.search(line, end)
+        row = table[m.group(1)] = Op(
+            opcode, line[m.end():op.start() + 1],
+            meta.group(1) if meta else "", tuple(body), operands,
+            _attrs(line, end) if opcode in _KEPT_ATTRS else None)
+        if comp is not None:
+            comp.append(row)
     return table
 
 
-def build_op_phase_map(hlo_text):
-    """``{hlo_instruction_name: op_name}`` for the instructions whose
-    metadata carries an op_name; the trace join tolerates misses (they
-    fall into ``other``)."""
-    return {name: row[2] for name, row in build_op_table(hlo_text).items()
-            if row[2]}
+# ------------------------------------------------- scope, class and FLOPs
+
+#: Op classes of ``summarize``'s ``classes``; of what a fusion's body
+#: holds the earlier one names it.
+CLASSES = ("matmul", "kernel", "collective", "gather_scatter", "reduce",
+           "copy", "elementwise", "other")
+_MATMUL = frozenset(("convolution", "dot"))
+_GATHER = frozenset(("gather", "scatter", "sort"))
+_REDUCE = frozenset(("reduce", "reduce-window"))
+_MOVES = frozenset(("copy", "transpose", "bitcast", "slice", "dynamic-slice",
+                    "dynamic-update-slice", "concatenate", "pad", "broadcast",
+                    "reshape", "reverse"))
+# in a fusion's body these say nothing about what the fusion does
+_NEUTRAL = frozenset(("parameter", "constant", "tuple", "get-tuple-element",
+                      "iota", "fusion"))
+_CONTROL = frozenset(("", "while", "conditional", "call", "tuple",
+                      "get-tuple-element", "parameter", "async-start",
+                      "async-done", "async-update"))
+TRANSCENDENTAL = frozenset(("exponential", "exponential-minus-one", "tanh",
+                            "logistic", "divide", "rsqrt", "sqrt", "log",
+                            "log-plus-one", "power", "erf"))
+
+
+def scope_path(op_name):
+    """The scope PATH of an ``op_name``: its phase
+    (:func:`phase_of_op_name`; ``other`` outside every region), then
+    every finer ``hvd_*`` name in nesting order, each once, whatever
+    ``transpose(jvp(...))``, ``checkpoint`` or ``rematted_computation``
+    autodiff wrapped around them: ``backward/hvd_ffn/hvd_ffn_gate``."""
+    parts = [phase_of_op_name(op_name) or "other"]
+    for label in _SCOPE_RE.findall(op_name or ""):
+        if not _PHASE_LABEL_RE.fullmatch(label) and label not in parts:
+            parts.append(label)
+    return "/".join(parts)
+
+
+def _base(opcode):
+    """``all-reduce-start`` / ``slice-done`` -> the opcode they split."""
+    for tail in ("-start", "-done"):
+        if opcode.endswith(tail):
+            return opcode[:-len(tail)]
+    return opcode
+
+
+def op_class(op, text=None):
+    """The class of one instruction (:data:`CLASSES`), read from the
+    instruction itself and, for a fusion, from its body: ``matmul`` holds
+    a convolution / dot, ``kernel`` is a Mosaic custom call (``text``: a
+    TPU event's, for an instruction the table lacks), ``copy`` holds
+    nothing but data movement, ``other`` is control flow's own self time,
+    XLA's own small custom calls and what cannot be told (a fusion whose
+    body was not registered)."""
+    if op.opcode == "fusion" and not op.body:
+        return "other"
+    if op.opcode == "custom-call":
+        target = (op.attrs or {}).get("custom_call_target")
+        mosaic = (target == "tpu_custom_call" if target is not None
+                  else bool(text and _MOSAIC_TARGET in text))
+        return "kernel" if mosaic else "other"
+    codes = {_base(o.opcode) for o in op.body or (op,)}
+    if op.body:
+        codes -= _NEUTRAL
+    elif op.opcode in _CONTROL:
+        return "other"
+    if codes & _MATMUL:
+        return "matmul"
+    if any(c in COLLECTIVES for c in codes):
+        return "collective"
+    if codes & _GATHER:
+        return "gather_scatter"
+    if codes & _REDUCE:
+        return "reduce"
+    return "copy" if codes <= _MOVES else "elementwise"
+
+
+def _dims(shape):
+    """``(dtype, [dims])`` of the first array of a type."""
+    m = _ARRAY_RE.search(_LAYOUT_RE.sub("", shape))
+    if not m:
+        return "", []
+    return m.group(1), [int(d) for d in m.group(2).split(",") if d]
+
+
+def _window(text, n):
+    """A convolution's ``window={size=1x4 pad=0_0x3_3 ...}`` as lists
+    over its ``n`` spatial dimensions: pad (low), stride, lhs_dilate,
+    rhs_dilate."""
+    fields = dict(f.split("=", 1) for f in (text or "").split() if "=" in f)
+
+    def per_dim(key, default):
+        vals = fields.get(key)
+        return ([int(v.split("_")[0]) for v in vals.split("x")] if vals
+                else [default] * n)
+
+    return (per_dim("pad", 0), per_dim("stride", 1),
+            per_dim("lhs_dilate", 1), per_dim("rhs_dilate", 1))
+
+
+def matmul_flops(op, table):
+    """FLOPs of one ``convolution`` / ``dot`` instruction: two for every
+    multiply-add of one element of each operand. A window's taps that
+    fall on padding or into the holes of a dilated operand are not
+    counted, so a product that XLA wrote as a convolution — a padded
+    window, or a batch dimension as a dilated one with a stride — counts
+    what the dense product of the same operands needs. None when an
+    operand's type is not in ``table`` (operand types are looked up by
+    name: jax 0.9's text does not print them inline)."""
+    attrs = op.attrs or {}
+    types = [table.get(name, _NO_OP).shape for name in op.operands[:2]]
+    if len(types) < 2 or not all(types):
+        return None
+    lhs, rhs, out = (_dims(t)[1] for t in (*types, op.shape))
+    if op.opcode == "dot":
+        n = 2
+        for d in out:
+            n *= d
+        for c in (attrs.get("lhs_contracting_dims") or "").split(","):
+            n *= lhs[int(c)] if c else 1
+        return n
+    try:
+        lhs_l, rest = attrs.get("dim_labels", "").split("_")
+        rhs_l, out_l = rest.split("->")
+        lhs, rhs, out = (dict(zip(lab, dims)) for lab, dims in
+                         ((lhs_l, lhs), (rhs_l, rhs), (out_l, out)))
+        n = (2 * lhs["b"] // int(attrs.get("batch_group_count") or 1)
+             * rhs["o"] * rhs["i"])
+    except (KeyError, ValueError):
+        return None
+    spatial = sorted(k for k in rhs_l if k.isdigit())
+    for k, pad, stride, ld, rd in zip(
+            spatial, *_window(attrs.get("window"), len(spatial))):
+        # (output position, tap) pairs that meet an element of the left
+        # operand: tap t of output o reads position o * stride + t * rd -
+        # pad of the operand dilated by ld, whose elements sit at the
+        # multiples of ld up to (size - 1) * ld
+        size, taps, steps = lhs[k], rhs[k], out[k]
+        g = math.gcd(stride, ld)
+        m = ld // g
+        pairs = 0
+        for t in range(taps):
+            c = t * rd - pad
+            first = max(-(c // stride), 0)
+            last = min(((size - 1) * ld - c) // stride, steps - 1)
+            if last >= first and c % g == 0:
+                o = (-c // g) * pow(stride // g, -1, m) % m
+                pairs += (last - o) // m - (first - 1 - o) // m
+        n *= pairs
+    return n
+
+
+def _matmul_row(op, table):
+    """What ``summarize``'s ``matmuls`` says of one instruction of class
+    ``matmul`` apart from its time: the FLOPs of a call (the body's
+    convolutions together), the operands of the largest, and what rides
+    along in the fusion."""
+    own = set(_SCOPE_RE.findall(op.op_name))
+    flops, best, rides, carries = 0, None, set(), False
+    for inner in op.body or (op,):
+        if inner.opcode in _MATMUL:
+            n = matmul_flops(inner, table)
+            flops = None if n is None or flops is None else flops + n
+            if best is None or (n or 0) > best[0]:
+                best = (n or 0, inner)
+        elif _base(inner.opcode) in COLLECTIVES:
+            carries = True
+        elif inner.opcode in TRANSCENDENTAL:
+            rides.add(inner.opcode)
+        rides.update(set(_SCOPE_RE.findall(inner.op_name)) - own)
+    lhs, rhs = (_LAYOUT_RE.sub("", table.get(name, _NO_OP).shape)
+                for name in (best[1].operands + ("", ""))[:2])
+    return {"result": _LAYOUT_RE.sub("", op.shape), "lhs": lhs, "rhs": rhs,
+            "operands": "×".join(_dims(t)[0] for t in (lhs, rhs)),
+            "flops": flops, "rides": rides,
+            "carries_collective": carries}
 
 
 def live_hlo(module_names=None, executables=None):
@@ -198,8 +465,6 @@ def live_hlo(module_names=None, executables=None):
     return out
 
 
-_COMPUTATION_RE = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
-_CALLS_RE = re.compile(r"\bcalls=%?([\w.\-]+)")
 # replica groups of one device each, listed or as an iota: the
 # all-reduce of an axis of size 1, which a backend may leave in the text
 _ALONE_RE = re.compile(r"replica_groups=(?:\{(?:\{\d+\},?)+\}|\[\d+,1\]<=)")
@@ -313,17 +578,20 @@ def read_capture(trace_dir):
                           "async": [[instruction, start_ns, dur_ns,
                                      hlo text], ...]}},
          "host": [[annotation, start_ns, dur_ns, step_num or None], ...],
+         "device_kind": "TPU v5 Lite" or None,
          "files": [paths]}
 
     ``host`` holds the program's ``hvd_*`` annotations; times are on the
-    profiler's clock. Unreadable files degrade to "no data", never a
-    crash."""
-    if not trace_dir or not os.path.isdir(trace_dir):
+    profiler's clock; ``device_kind`` is what a TPU plane says of its
+    chip. ``trace_dir`` may also be one ``*.xplane.pb`` file. Unreadable
+    files degrade to "no data", never a crash."""
+    if not trace_dir or not os.path.exists(trace_dir):
         return None
     import jax
-    lanes, host, files = {}, [], []
-    for path in sorted(glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                                 recursive=True)):
+    lanes, host, files, kind = {}, [], [], None
+    for path in ([trace_dir] if os.path.isfile(trace_dir) else sorted(
+            glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                      recursive=True))):
         try:
             data = jax.profiler.ProfileData.from_file(path)
             planes = list(data.planes)
@@ -336,6 +604,7 @@ def read_capture(trace_dir):
             m = re.match(r"^/device:TPU:(\d+)$", plane.name)
             if m:
                 lanes[f"tpu:{m.group(1)}"] = _tpu_lane(plane)
+                kind = dict(plane.stats).get("device_type_string", kind)
             elif plane.name == "/host:CPU":
                 _host_plane(plane, lanes, host)
     if not files:
@@ -344,7 +613,8 @@ def read_capture(trace_dir):
         for rows in lane.values():
             rows.sort(key=lambda r: (r[1], -r[2]))
     host.sort(key=lambda r: r[1])
-    return {"lanes": lanes, "host": host, "files": files}
+    return {"lanes": lanes, "host": host, "files": files,
+            "device_kind": kind}
 
 
 # ----------------------------------------------------------- the reduction
@@ -448,11 +718,17 @@ def _host_self_seconds(inside):
     return out
 
 
-def summarize(events, op_table=None, ring_spans=None, window=None):
+def summarize(events, op_table=None, ring_spans=None, window=None,
+              peak_flops=None):
     """Reduce :func:`read_capture`'s lists. Returns None when no device
     op ran in the capture; otherwise a dict::
 
         {"phases": {phase: seconds, ..., "other": s},
+         "scopes": {scope path: seconds},
+         "classes": {scope path: {op class: seconds}},
+         "matmuls": [{"scope", "result", "lhs", "rhs", "operands",
+                      "calls", "device_s", "flops", "at_peak_s",
+                      "lost_s", "rides", "carries_collective"}, ...],
          "stages": {"ici": s, "dcn": s},
          "moe": {...} or None, "exchange": {...} or None,
          "kernels": {name: {"s": seconds, "calls": n}},
@@ -471,6 +747,25 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
     without it TPU ops still have their opcode and result type (from the
     event's text) but no scope, so everything is ``other``.
 
+    ``scopes`` splits ``phases`` by scope PATH (:func:`scope_path`:
+    ``backward/hvd_ffn/hvd_ffn_gate``; summed by its first component it
+    is ``phases``) and ``classes`` splits that by op class
+    (:func:`op_class`: which part of a name's time the matmul unit could
+    have had, and which is data movement). ``matmuls``: one row per scope
+    path, result type and operand types of class ``matmul``, sorted by
+    ``lost_s`` — ``calls`` and ``device_s`` over all lanes and captured
+    steps like every other time, ``flops`` of ONE call
+    (:func:`matmul_flops`),
+    ``at_peak_s`` a call's time at ``peak_flops`` (FLOP/s of a chip: the
+    argument, else the table entry of ``hardware.PEAK_BF16_FLOPS`` for
+    the capture's device; both left out on a device it does not know),
+    ``lost_s = device_s - calls * at_peak_s``, ``operands`` the two
+    types the matmul unit was handed (``f32×bf16``), ``rides`` what else
+    the fusion holds — the other ``hvd_*`` names on its body's
+    instructions (an ``hvd_optimizer`` epilogue, an ``hvd_block_io`` norm
+    as producer) and its transcendental opcodes — and
+    ``carries_collective`` for an all-reduce fused with its matmul.
+
     ``moe`` appears when the capture contains MoE sub-phases
     (``hvd_dispatch``/``hvd_combine`` wrap only the dispatch/combine
     alltoalls, ``hvd_expert`` only the expert FFN): ``hidden_s`` is the
@@ -478,15 +773,19 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
     of expert-compute intervals across ALL lanes — an alltoall lane is
     stalled on peers, so any concurrent expert compute anywhere on the
     mesh is dispatch latency the chunked pipeline hid — and
-    ``hidden_frac = hidden_s / alltoall_s`` is the overlap fraction the
-    bench/CI acceptance gate reads (``alltoall_hidden_frac``).
+    ``hidden_frac = hidden_s / alltoall_s`` is the overlap fraction
+    ``bench_transformer.py --moe`` prints as ``alltoall_hidden_frac``
+    (the smoke in ``.github/workflows/ci.yml`` asserts on it; nothing
+    under ``benchmark/`` reads it) and the gauge
+    ``hvd_moe_alltoall_hidden_frac`` carries.
 
     ``exchange`` appears when the capture contains gradient-exchange
     device time (``hvd_exchange`` scopes — one interval per bucketed psum
     under HOROVOD_EXCHANGE_BUCKETS > 1): the same interval fold, with the
     compute union taken over the forward/backward/optimizer/expert phases
     across ALL lanes. ``hidden_frac = hidden_s / exchange_s`` feeds
-    ``hvd_exchange_hidden_frac`` and the bench/CI overlap gates.
+    the gauge ``hvd_exchange_hidden_frac`` and ``bench.py``'s
+    ``exchange_hidden_frac`` (ci.yml's overlap smoke).
 
     ``kernels``: custom calls by the name they run under
     (:func:`kernel_of_op_name`; an unnamed Mosaic call by its
@@ -510,7 +809,7 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
     phases = {p: 0.0 for p in PHASES}
     phases["other"] = 0.0
     stages = {s: 0.0 for s in STAGES}
-    kernels, coll = {}, {}
+    kernels, coll, classes, matmuls = {}, {}, {}, {}
     expert_iv, a2a_iv, exch_iv, compute_iv = [], [], [], []
     n_events, ts_min, ts_max = 0, None, None
     lanes_seen, step_runs, idle_lanes = 0, [], []
@@ -519,14 +818,26 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
     def info(instr, text):
         row = cache.get(instr)
         if row is None:
-            opcode, shape, op_name = op_table.get(instr, ("", "", ""))
-            if not opcode and text:
+            op = op_table.get(instr, _NO_OP)
+            if not op.opcode and text:
                 inst = parse_instruction(text)
                 if inst:
-                    opcode, shape = inst[1], inst[2]
-            row = cache[instr] = (opcode, shape, op_name,
-                                  phase_of_op_name(op_name),
-                                  stage_of_op_name(op_name))
+                    op = Op(inst[1], inst[2], "")
+            path = scope_path(op.op_name)
+            by_class = classes.setdefault(path, {})
+            cls = op_class(op, text)
+            mm = None
+            if cls == "matmul":
+                found = _matmul_row(op, op_table)
+                mm = matmuls.setdefault(
+                    (path, found["result"], found["lhs"], found["rhs"],
+                     found["flops"]),
+                    dict(found, scope=path, calls=0, device_s=0.0))
+                mm["rides"] |= found["rides"]
+            row = cache[instr] = (op.opcode, op.shape, op.op_name,
+                                  path.split("/", 1)[0],
+                                  stage_of_op_name(op.op_name),
+                                  by_class, cls, mm)
         return row
 
     for lane in events["lanes"].values():
@@ -542,9 +853,14 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
         pending = []
         for (instr, start, dur, text), self_ns in zip(ops,
                                                       _self_times(ops)):
-            opcode, shape, op_name, phase, stage = info(instr, text)
+            (opcode, shape, op_name, phase, stage, by_class, cls,
+             mm) = info(instr, text)
             iv = (start, start + dur)
-            phases[phase if phase in phases else "other"] += self_ns
+            phases[phase] += self_ns
+            by_class[cls] = by_class.get(cls, 0.0) + self_ns
+            if mm is not None:
+                mm["calls"] += 1
+                mm["device_s"] += self_ns * 1e-9
             if stage in stages:
                 stages[stage] += self_ns
             if phase == "expert":
@@ -620,8 +936,14 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
             step_runs, inside, clock["offset_ns"])
     idle = _idle_by_span(idle_lanes, inside, clock)
     to_s = 1e-9  # xplane times are nanoseconds
+    classes = {path: {c: v * to_s for c, v in by.items()}
+               for path, by in classes.items() if by}
     return {
         "phases": {k: v * to_s for k, v in phases.items()},
+        "scopes": {path: sum(by.values()) for path, by in classes.items()},
+        "classes": classes,
+        "matmuls": _matmul_rows(matmuls.values(), peak_flops
+                                or _peak_of(events.get("device_kind"))),
         "stages": {k: v * to_s for k, v in stages.items()},
         "moe": moe,
         "exchange": exchange,
@@ -639,6 +961,31 @@ def summarize(events, op_table=None, ring_spans=None, window=None):
         "ts_max_us": ts_max * 1e-3,
         "files": events.get("files", []),
     }
+
+
+def _peak_of(device_kind):
+    """Peak FLOP/s of the chip a capture names (``TPU v5 Lite``), None
+    for one ``hardware.PEAK_BF16_FLOPS`` does not list."""
+    from .. import hardware
+    for kind, peak in hardware.PEAK_BF16_FLOPS.items():
+        if kind.lower() == str(device_kind).lower():
+            return peak
+    return None
+
+
+def _matmul_rows(rows, peak):
+    """``summarize``'s ``matmuls`` from the rows it gathered: the time
+    at the peak and the time lost beside each, the most lost first."""
+    out = []
+    for row in rows:
+        if not row["calls"]:   # seen on the asynchronous line only
+            continue
+        row = dict(row, rides=sorted(row["rides"]))
+        if peak and row["flops"] is not None:
+            row["at_peak_s"] = row["flops"] / peak
+            row["lost_s"] = row["device_s"] - row["calls"] * row["at_peak_s"]
+        out.append(row)
+    return sorted(out, key=lambda r: -r.get("lost_s", r["device_s"]))
 
 
 def _step_runs(modules):
@@ -869,7 +1216,9 @@ class StepTracer:
             # offset it lays profiler ns on the flight dumps' wall clock
             "mono_start": self._mono_start,
             "trace_dir": self.last_dir,
-            "summary": summary,
+            # the side file keeps the forty matmul rows that lose most
+            "summary": summary and dict(
+                summary, matmuls=summary["matmuls"][:40]),
             # Per-instruction phase/stage labels so the offline diag CLI
             # (--xla-trace) can phase-attribute individual device events
             # without the executable's HLO text.
@@ -911,10 +1260,23 @@ class StepTracer:
                 for text in live_hlo(names).values():
                     self.register_hlo(text)
             return summarize(events, self._op_table, recorder.spans(),
-                             window)
+                             window, self._peak_flops())
         except Exception:  # noqa: BLE001 - a capture that cannot be read
             _logger.warning("xla_trace: could not reduce %s", self.last_dir,
                             exc_info=True)
+            return None
+
+    @staticmethod
+    def _peak_flops():
+        """A chip's peak FLOP/s as the MFU gauges take it
+        (``HOROVOD_PEAK_FLOPS``, else the table's entry for the device);
+        None on the CPU and on a chip the table does not list."""
+        from .. import hardware, runtime
+        try:
+            return hardware.peak_flops_per_chip(
+                runtime.state().config if runtime.is_initialized()
+                else None) or None
+        except ValueError:
             return None
 
     @staticmethod

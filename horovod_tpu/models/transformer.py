@@ -675,17 +675,19 @@ def _gather_vocab(logits, tp_axis):
 
 def embed_tokens(params, tokens, cfg, axes):
     """Vocab-parallel embedding lookup + learned positions (training path:
-    positions start at this sp shard's offset)."""
-    x = _embed_rows(params, tokens, axes)
-    if cfg.embedding_multiplier != 1.0:
-        x = x * cfg.embedding_multiplier
+    positions start at this sp shard's offset). Device scope
+    ``hvd_embed``; the embedding gradient's scatter-add inherits it."""
+    with jax.named_scope("hvd_embed"):
+        x = _embed_rows(params, tokens, axes)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
 
-    if cfg.positional != "learned":
-        return x.astype(cfg.dtype)  # rope: rotation happens on q/k
-    s_loc = tokens.shape[1]
-    sp_idx = _axis_index(axes.sp)
-    pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * s_loc, s_loc)
-    return (x + pos[None]).astype(cfg.dtype)
+        if cfg.positional != "learned":
+            return x.astype(cfg.dtype)  # rope: rotation happens on q/k
+        s_loc = tokens.shape[1]
+        sp_idx = _axis_index(axes.sp)
+        pos = lax.dynamic_slice_in_dim(params["pos"], sp_idx * s_loc, s_loc)
+        return (x + pos[None]).astype(cfg.dtype)
 
 
 def _qkv_proj(p, h, cfg):
@@ -722,35 +724,50 @@ def _attention_block_kv(p, x, cfg, axes, spec=None):
 
     ``spec`` is this layer's :class:`LayerSpec` (``None``: the one kind
     of layer the configuration has). The attention itself runs under the
-    device scope ``hvd_attn_window`` or ``hvd_attn_full``."""
+    device scope ``hvd_attn_window`` or ``hvd_attn_full``; the
+    projections, rope and the per-head gate around it under
+    ``hvd_attn_proj``."""
     spec = spec or cfg.layer_spec()
-    h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
-    q, k, v = _qkv_proj(p, h, cfg)
-    if spec.rope is not None:
-        s_loc = x.shape[1]
-        start = _axis_index(axes.sp) * s_loc
-        positions = start + jnp.arange(s_loc)
-        q = _rope_spec(q, positions, spec.rope)
-        k = _rope_spec(k, positions, spec.rope)
+    h = _pre_norm(x, p["ln1"], cfg)
+    with jax.named_scope("hvd_attn_proj"):
+        q, k, v = _qkv_proj(p, h, cfg)
+        if spec.rope is not None:
+            s_loc = x.shape[1]
+            start = _axis_index(axes.sp) * s_loc
+            positions = start + jnp.arange(s_loc)
+            q = _rope_spec(q, positions, spec.rope)
+            k = _rope_spec(k, positions, spec.rope)
     win = spec.window
     with jax.named_scope("hvd_attn_window" if win else "hvd_attn_full"):
         attn = _attend(q, k, v, win, cfg, axes)
-    if "wg" in p:
-        gate = jax.nn.sigmoid(jnp.einsum(
-            "bsd,hd->bsh", h, p["wg"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32))
-        attn = attn * gate[..., None].astype(cfg.dtype)
-    out = jnp.einsum("bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
-    return _residual(x, _psum(out, axes.tp), cfg), k, v
+    with jax.named_scope("hvd_attn_proj"):
+        if "wg" in p:
+            gate = jax.nn.sigmoid(jnp.einsum(
+                "bsd,hd->bsh", h, p["wg"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32))
+            attn = attn * gate[..., None].astype(cfg.dtype)
+        out = _psum(jnp.einsum(
+            "bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
+            preferred_element_type=jnp.float32), axes.tp)
+    return _residual(x, out, cfg), k, v
+
+
+def _pre_norm(x, scale, cfg):
+    """A block's RMS norm of its input, under the device scope
+    ``hvd_block_io`` (with :func:`_residual`: what a block does around
+    its mixer or FFN). XLA fuses most of them into the matmul that
+    consumes them; such a fusion keeps the matmul's name."""
+    with jax.named_scope("hvd_block_io"):
+        return _rmsnorm(x, scale, cfg.norm_eps)
 
 
 def _residual(x, branch, cfg):
     """``x + residual_multiplier * branch``; the branch arrives as its
     matmul accumulated it (float32) and is rounded once."""
-    if cfg.residual_multiplier != 1.0:
-        branch = branch * cfg.residual_multiplier
-    return x + branch.astype(cfg.dtype)
+    with jax.named_scope("hvd_block_io"):
+        if cfg.residual_multiplier != 1.0:
+            branch = branch * cfg.residual_multiplier
+        return x + branch.astype(cfg.dtype)
 
 
 def _ssm_block(p, x, cfg, axes):
@@ -762,7 +779,7 @@ def _ssm_block(p, x, cfg, axes):
             "sequence parallelism (axes.sp) would have to hand it from "
             "shard to shard and is not supported")
     from .ssm import mamba2_mixer
-    out, rms = mamba2_mixer(p["ssm"], _rmsnorm(x, p["ln1"], cfg.norm_eps),
+    out, rms = mamba2_mixer(p["ssm"], _pre_norm(x, p["ln1"], cfg),
                             cfg.ssm_cfg)
     return _residual(x, out, cfg), rms
 
@@ -776,7 +793,7 @@ def _kda_block(p, x, cfg, axes):
             "parallelism (axes.sp) would have to hand it from shard to "
             "shard and is not supported")
     from .kda import kda_mixer
-    out, rms = kda_mixer(p["kda"], _rmsnorm(x, p["ln1"], cfg.norm_eps),
+    out, rms = kda_mixer(p["kda"], _pre_norm(x, p["ln1"], cfg),
                          cfg.kda_cfg)
     return _residual(x, out, cfg), rms
 
@@ -796,7 +813,7 @@ def _mla_block(p, x, cfg, axes):
             "tensor parallelism (axes.sp, axes.tp)")
     m, dt, f32 = p["mla"], cfg.dtype, jnp.float32
     rank, nope = cfg.mla_kv_rank, cfg.mla_qk_nope
-    h = _rmsnorm(x, p["ln1"], cfg.norm_eps)
+    h = _pre_norm(x, p["ln1"], cfg)
     with jax.named_scope("hvd_mla_proj"):
         q = jnp.einsum("bsd,dhx->bshx", h, m["wq"].astype(dt),
                        preferred_element_type=f32).astype(dt)
@@ -884,33 +901,37 @@ def _mlp_block_stats(p, x, cfg, axes, moe_full_capacity=False):
     other layer). The parameters pick the FFN: ``moe`` a sparse layer —
     dropless when the configuration states the experts held, else the
     capacity layer over ``axes.ep`` —, ``w3`` the gated SiLU FFN
-    (:func:`_gated_ffn`)."""
-    h = _rmsnorm(x, p["ln2"], cfg.norm_eps)
+    (:func:`_gated_ffn`). The two dense FFNs run under the device scope
+    ``hvd_ffn`` (the sparse ones under models/moe.py's own)."""
+    h = _pre_norm(x, p["ln2"], cfg)
     zero = jnp.zeros((), jnp.float32)
-    if "moe" in p and cfg.moe_experts_held is not None:
-        from .moe import moe_dropless
-        y, stats = moe_dropless(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg)
-        return _residual(x, y, cfg), zero, stats
     if "moe" in p:
+        with jax.named_scope("hvd_block_io"):
+            h = h.astype(cfg.dtype)
+        if cfg.moe_experts_held is not None:
+            from .moe import moe_dropless
+            y, stats = moe_dropless(p["moe"], h, cfg.moe_cfg)
+            return _residual(x, y, cfg), zero, stats
         from .moe import moe_layer
-        y, aux = moe_layer(p["moe"], h.astype(cfg.dtype), cfg.moe_cfg,
-                           ep_axis=axes.ep,
+        y, aux = moe_layer(p["moe"], h, cfg.moe_cfg, ep_axis=axes.ep,
                            full_capacity=moe_full_capacity)
         return _residual(x, y, cfg), aux, None
-    if "w3" in p:
-        out = _gated_ffn(h.astype(jnp.float32), p["w1"].astype(cfg.dtype),
-                         p["w3"].astype(cfg.dtype),
-                         p["w2"].astype(cfg.dtype),
-                         _gate_slices(h.size // h.shape[-1],
-                                      p["w1"].shape[1], cfg))
-    else:
-        u = jax.nn.gelu(jnp.einsum(
-            "bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
-            preferred_element_type=jnp.float32))
-        out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
-                         p["w2"].astype(cfg.dtype),
-                         preferred_element_type=jnp.float32)
-    return _residual(x, _psum(out, axes.tp), cfg), zero, None
+    with jax.named_scope("hvd_ffn"):
+        if "w3" in p:
+            out = _gated_ffn(
+                h.astype(jnp.float32), p["w1"].astype(cfg.dtype),
+                p["w3"].astype(cfg.dtype), p["w2"].astype(cfg.dtype),
+                _gate_slices(h.size // h.shape[-1], p["w1"].shape[1],
+                             cfg))
+        else:
+            u = jax.nn.gelu(jnp.einsum(
+                "bsd,df->bsf", h, p["w1"].astype(cfg.dtype),
+                preferred_element_type=jnp.float32))
+            out = jnp.einsum("bsf,fd->bsd", u.astype(cfg.dtype),
+                             p["w2"].astype(cfg.dtype),
+                             preferred_element_type=jnp.float32)
+        out = _psum(out, axes.tp)
+    return _residual(x, out, cfg), zero, None
 
 
 def _mm(spec, a, b):
@@ -1376,12 +1397,13 @@ def pipeline_loss_fn(params, tokens, targets, cfg, axes=None,
         # B/m, the chunk additionally bounds them by (B/m, chunk, V_loc)
         # — at real vocab sizes both levers are needed.
         y, aux = h
-        if cfg.loss_chunk:
-            ce = _chunked_cross_entropy(params, y, targets_mb[mb], cfg,
-                                        axes)
-        else:
-            ce = _cross_entropy(_head(params, y, cfg), targets_mb[mb],
-                                axes)
+        with jax.named_scope("hvd_head_ce"):
+            if cfg.loss_chunk:
+                ce = _chunked_cross_entropy(params, y, targets_mb[mb], cfg,
+                                            axes)
+            else:
+                ce = _cross_entropy(_head(params, y, cfg), targets_mb[mb],
+                                    axes)
         return ce + MOE_AUX_COEF * aux if moe else ce
 
     losses = pipeline(
@@ -1490,10 +1512,13 @@ def pipeline_value_and_grad_1f1b(params, tokens, targets, cfg, axes=None,
 
     def loss_f(sh, h, mb):
         y, aux = h
-        if cfg.loss_chunk:
-            ce = _chunked_cross_entropy(sh, y, targets_mb[mb], cfg, axes)
-        else:
-            ce = _cross_entropy(_head(sh, y, cfg), targets_mb[mb], axes)
+        with jax.named_scope("hvd_head_ce"):
+            if cfg.loss_chunk:
+                ce = _chunked_cross_entropy(sh, y, targets_mb[mb], cfg,
+                                            axes)
+            else:
+                ce = _cross_entropy(_head(sh, y, cfg), targets_mb[mb],
+                                    axes)
         return ce + MOE_AUX_COEF * aux if moe else ce
 
     # The per-(stage, microbatch) loss value is REPLICATED across the tp

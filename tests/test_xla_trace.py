@@ -17,10 +17,11 @@ import pytest
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from horovod_tpu.diag import xla_trace
-from horovod_tpu.diag.xla_trace import (StepTracer, build_op_phase_map,
-                                        build_op_table, clock_map,
-                                        kernel_of_op_name, parse_trace_dir,
-                                        phase_of_op_name, read_capture,
+from horovod_tpu.diag.xla_trace import (StepTracer, build_op_table,
+                                        clock_map, kernel_of_op_name,
+                                        matmul_flops, op_class,
+                                        parse_trace_dir, phase_of_op_name,
+                                        read_capture, scope_path,
                                         shape_bytes, stage_of_op_name,
                                         summarize)
 
@@ -83,20 +84,260 @@ def test_phase_of_op_name_first_region_wins():
     assert kernel_of_op_name("jit(f)/hvd_forward/pallas_call") is None
 
 
-def test_build_op_phase_map_synthetic_hlo():
-    m = build_op_phase_map(SYNTH_HLO)
-    assert m["dot.1"].endswith("hvd_forward/dot_general")
-    # an instruction the compiler made without metadata has no op_name
-    assert "copy.5" not in m and len(m) == 9
-    assert build_op_phase_map("") == {}
+def test_build_op_table_synthetic_hlo():
     table = build_op_table(SYNTH_HLO)
-    assert table["copy.5"] == ("copy", "f32[4]{0}", "")
+    assert table["dot.1"].op_name.endswith("hvd_forward/dot_general")
+    # an instruction the compiler made without metadata has no op_name
+    assert sum(bool(op.op_name) for op in table.values()) == 9
+    assert build_op_table("") == {}
+    assert table["copy.5"][:3] == ("copy", "f32[4]{0}", "")
+    assert table["dot.1"].operands == ("p0", "p1")
+    assert table["kern.7"].attrs == {
+        "custom_call_target": "tpu_custom_call"}
     assert table["kern.7"][0] == "custom-call"
     assert table["ars.9"][0] == "all-reduce-start"
     assert shape_bytes(table["ar.8"][1]) == 4096
     assert shape_bytes("(f32[256]{0}, bf16[2,8]{1,0:T(8,128)})") \
         == 1024 + 32
     assert shape_bytes("(f32[8]{0}, f32[32]{0}, u32[])", largest=True) == 128
+
+
+def _conv_hlo(lhs, rhs, out, attrs, opcode="convolution"):
+    """A fused computation holding one matmul, and the fusion calling it."""
+    return f"""
+%fused_mm (p0: {lhs}, p1: {rhs}) -> {out} {{
+  %p0 = {lhs}{{1,0}} parameter(0)
+  %p1 = {rhs}{{1,0}} parameter(1)
+  ROOT %mm.1 = {out}{{1,0}} {opcode}(%p0, %p1), {attrs}, metadata={{op_name="jit(step)/hvd_forward/hvd_ffn/dot_general"}}
+}}
+
+ENTRY %main (a: {lhs}, b: {rhs}) -> {out} {{
+  %a = {lhs}{{1,0}} parameter(0)
+  %b = {rhs}{{1,0}} parameter(1)
+  ROOT %mm_fusion = {out}{{1,0}} fusion(%a, %b), kind=kOutput, calls=%fused_mm, metadata={{op_name="jit(step)/hvd_forward/hvd_ffn/dot_general"}}
+}}
+"""
+
+
+@pytest.mark.parametrize("lhs,rhs,out,attrs,want", [
+    # the forms the eight cells' step programs show on a v5e
+    ("f32[8,16]", "f32[16,32]", "f32[8,32]", "dim_labels=bf_io->bf",
+     2 * 8 * 16 * 32),
+    ("f32[64,8]", "f32[64,32]", "f32[8,32]", "dim_labels=fb_io->bf",
+     2 * 8 * 64 * 32),
+    ("bf16[8,16]", "bf16[32,16]", "f32[8,32]", "dim_labels=bf_oi->bf",
+     2 * 8 * 16 * 32),
+    # a product per head as a window of 32 with nothing padded ...
+    ("bf16[16384,32,192]", "bf16[2304,32,192]", "f32[16384,2304,1]",
+     "window={size=32}, dim_labels=b0f_o0i->bf0",
+     2 * 16384 * 2304 * 32 * 192),
+    # ... and as a window of 32 of which 31 taps are padding: what the
+    # dense product of the same operands needs, not 32 times that
+    ("f32[16384,2304,1]", "bf16[2304,32,192]", "f32[16384,32,192]",
+     "window={size=32 pad=31_31 rhs_reversal=1}, dim_labels=bf0_i0o->b0f",
+     2 * 16384 * 2304 * 32 * 192),
+    # three spatial dimensions, one padded (kimi-linear's 32-wide
+    # projection over 256 x 8 x 8 tokens)
+    ("bf16[256,8,8,2304,1]", "bf16[4,8,2304,1,1]", "f32[256,8,8,4,8]",
+     "window={size=1x1x4 pad=0_0x0_0x3_3 rhs_reversal=0x0x1}, "
+     "dim_labels=01bf2_2oi01->01b2f", 2 * 16384 * 2304 * 32),
+    # a causal depthwise convolution: the first three positions see
+    # 1, 2, 3 taps
+    ("bf16[2,100,64]", "bf16[4,1,64]", "bf16[2,100,64]",
+     "window={size=4 pad=3_0}, dim_labels=b0f_0io->b0f, "
+     "feature_group_count=64", 2 * 2 * 64 * (4 * 100 - 6)),
+    ("f32[2,50,64]", "f32[4,64,8]", "f32[2,24,8]",
+     "window={size=4 stride=2}, dim_labels=b0f_0io->b0f",
+     2 * 2 * 24 * 4 * 64 * 8),
+    # a dilated left operand: ten elements at the even positions of 19,
+    # each tap meets 9, 8 and 9 of them
+    ("f32[2,10,8]", "f32[3,8,8]", "f32[2,17,8]",
+     "window={size=3 lhs_dilate=2}, dim_labels=b0f_0io->b0f",
+     2 * 2 * 8 * 8 * 26),
+    # a batch of 8 written as a window of 8 over an operand dilated by 8
+    # at stride 7, so that only chunk c of one side meets chunk c of the
+    # other (granite's chunked scan, "convolution-base-dilated")
+    ("bf16[8,256,128]", "bf16[8,256,128]", "f32[8,256,256]",
+     "window={size=8 stride=7 lhs_dilate=8}, dim_labels=0bf_0oi->0bf",
+     2 * 8 * 256 * 256 * 128),
+    ("bf16[8,256,64,64]", "bf16[8,64,256,256]", "f32[8,64,64,256]",
+     "window={size=8x64 stride=7x63 lhs_dilate=8x64}, "
+     "dim_labels=0f1b_01oi->01bf", 2 * 8 * 64 * 256 * 256 * 64),
+])
+def test_matmul_flops_by_dim_labels(lhs, rhs, out, attrs, want):
+    table = build_op_table(_conv_hlo(lhs, rhs, out, attrs))
+    assert matmul_flops(table["mm.1"], table) == want
+    fusion = table["mm_fusion"]
+    assert [op.opcode for op in fusion.body] == ["parameter", "parameter",
+                                                 "convolution"]
+    assert op_class(fusion) == "matmul"
+    s = summarize(_events(l0=_lane([("mm_fusion", 0, 10)])), table,
+                  peak_flops=1e12)
+    (row,) = s["matmuls"]
+    assert row["flops"] == want and row["calls"] == 1
+    assert row["scope"] == "forward/hvd_ffn"
+    assert row["operands"] == "×".join(
+        t.split("[")[0] for t in (lhs, rhs))
+    assert row["at_peak_s"] == pytest.approx(want / 1e12)
+    assert row["lost_s"] == pytest.approx(10e-6 - want / 1e12)
+
+
+def test_matmul_flops_dot_and_what_cannot_be_counted():
+    for lhs, out, dims, want in (
+            ("f32[8,16]", "f32[8,32]",
+             "lhs_contracting_dims={1}, rhs_contracting_dims={0}",
+             2 * 8 * 32 * 16),
+            ("f32[4,8,16]", "f32[4,8,32]",
+             "lhs_batch_dims={0}, lhs_contracting_dims={2}, "
+             "rhs_batch_dims={0}, rhs_contracting_dims={1}",
+             2 * 4 * 8 * 32 * 16)):
+        table = build_op_table(_conv_hlo(lhs, "f32[16,32]", out, dims,
+                                         opcode="dot"))
+        assert matmul_flops(table["mm.1"], table) == want
+    # an operand the table does not hold: no count, never a guess
+    del table["p1"]
+    assert matmul_flops(table["mm.1"], table) is None
+
+
+CLASS_HLO = """
+%fused_copy (p: f32[8,4]) -> f32[4,8] {
+  %p = f32[8,4]{1,0} parameter(0)
+  %b.1 = f32[8,4]{1,0} bitcast(%p)
+  ROOT %t.1 = f32[4,8]{1,0} transpose(%b.1), dimensions={1,0}
+}
+
+%fused_elem (p.1: f32[8,4]) -> f32[8,4] {
+  %p.1 = f32[8,4]{1,0} parameter(0)
+  %c.1 = f32[] constant(2)
+  %bc.1 = f32[8,4]{1,0} broadcast(%c.1), dimensions={}
+  ROOT %m.1 = f32[8,4]{1,0} multiply(%p.1, %bc.1)
+}
+
+%fused_reduce (p.2: f32[8,4]) -> f32[8] {
+  %p.2 = f32[8,4]{1,0} parameter(0)
+  %e.2 = f32[8,4]{1,0} exponential(%p.2)
+  %z.2 = f32[] constant(0)
+  ROOT %r.2 = f32[8]{0} reduce(%e.2, %z.2), dimensions={1}, to_apply=%sum
+}
+
+%fused_scatter (p.3: f32[8,4], i.3: s32[2,1], u.3: f32[2,4]) -> f32[8,4] {
+  %p.3 = f32[8,4]{1,0} parameter(0)
+  %i.3 = s32[2,1]{1,0} parameter(1)
+  %u.3 = f32[2,4]{1,0} parameter(2)
+  ROOT %s.3 = f32[8,4]{1,0} scatter(%p.3, %i.3, %u.3), to_apply=%sum
+}
+
+%fused_mm_ar (p.4: f32[8,4], q.4: f32[4,4]) -> f32[8,4] {
+  %p.4 = f32[8,4]{1,0} parameter(0)
+  %q.4 = f32[4,4]{1,0} parameter(1)
+  %g.4 = f32[8,4]{1,0} logistic(%p.4), metadata={op_name="jit(f)/hvd_backward/hvd_ffn/hvd_ffn_gate/logistic"}
+  %cv.4 = f32[8,4]{1,0} convolution(%g.4, %q.4), dim_labels=bf_io->bf, metadata={op_name="jit(f)/hvd_backward/hvd_ffn/dot_general"}
+  ROOT %ar.4 = f32[8,4]{1,0} all-reduce(%cv.4), replica_groups={{0,1}}, to_apply=%sum, metadata={op_name="jit(f)/hvd_exchange/psum"}
+}
+
+ENTRY %main () -> f32[] {
+  %x = f32[8,4]{1,0} parameter(0)
+  %copy_fusion = f32[4,8]{1,0} fusion(%x), kind=kLoop, calls=%fused_copy
+  %elem_fusion = f32[8,4]{1,0} fusion(%x), kind=kLoop, calls=%fused_elem
+  %reduce_fusion = f32[8]{0} fusion(%x), kind=kInput, calls=%fused_reduce
+  %scatter_fusion = f32[8,4]{1,0} fusion(%x, %i, %u), kind=kLoop, calls=%fused_scatter
+  %mm_ar_fusion = f32[8,4]{1,0} fusion(%x, %w), kind=kOutput, calls=%fused_mm_ar, metadata={op_name="jit(f)/hvd_backward/hvd_ffn/dot_general"}
+  %dot.9 = f32[8,4]{1,0} dot(%x, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %conv.9 = f32[8,4]{1,0} convolution(%x, %w), dim_labels=bf_io->bf
+  %mosaic.9 = f32[8,4]{1,0} custom-call(%x), custom_call_target="tpu_custom_call"
+  %topk.9 = f32[8,4]{1,0} custom-call(%x), custom_call_target="TopK"
+  %ag.9 = f32[16,4]{1,0} all-gather(%x), dimensions={0}
+  %ars.9 = f32[8,4]{1,0} all-reduce-start(%x), to_apply=%sum
+  %ard.9 = f32[8,4]{1,0} all-reduce-done(%ars.9)
+  %cs.9 = (f32[8,4]{1,0}, f32[8,4]{1,0}, u32[]) copy-start(%x)
+  %ds.9 = f32[2,4]{1,0} dynamic-slice(%x, %z, %z), dynamic_slice_sizes={2,4}
+  %sort.9 = f32[8,4]{1,0} sort(%x), dimensions={1}, to_apply=%lt
+  %rw.9 = f32[8,1]{1,0} reduce-window(%x, %z), window={size=1x4}, to_apply=%sum
+  %tanh.9 = f32[8,4]{1,0} tanh(%x)
+  %cvt.9 = bf16[8,4]{1,0} convert(%x)
+  %while.9 = (s32[], f32[8,4]{1,0}) while(%t), condition=%c, body=%b
+  %gte.9 = f32[8,4]{1,0} get-tuple-element(%while.9), index=1
+}
+"""
+
+
+@pytest.mark.parametrize("instr,want", [
+    ("mm_ar_fusion", "matmul"), ("dot.9", "matmul"), ("conv.9", "matmul"),
+    ("mosaic.9", "kernel"),
+    ("ag.9", "collective"), ("ars.9", "collective"), ("ard.9", "collective"),
+    ("copy_fusion", "copy"), ("cs.9", "copy"), ("ds.9", "copy"),
+    ("scatter_fusion", "gather_scatter"), ("sort.9", "gather_scatter"),
+    ("reduce_fusion", "reduce"), ("rw.9", "reduce"),
+    ("elem_fusion", "elementwise"), ("tanh.9", "elementwise"),
+    ("cvt.9", "elementwise"),
+    ("while.9", "other"), ("gte.9", "other"), ("topk.9", "other"),
+])
+def test_op_class_of_an_instruction_and_of_a_fusion_body(instr, want):
+    table = build_op_table(CLASS_HLO)
+    assert op_class(table[instr]) == want
+    s = summarize(_events(l0=_lane([(instr, 0, 7)])), table)
+    path = scope_path(table[instr].op_name)
+    assert s["classes"] == {path: {want: pytest.approx(7e-6)}}
+    assert s["scopes"] == {path: pytest.approx(7e-6)}
+
+
+def test_op_class_without_the_table():
+    """An instruction the table lacks has its event's text: the opcode,
+    and for a custom call the target; a fusion's body it has not."""
+    ev = {"lanes": {"tpu:0": {"ops": [
+        ["k.1", 0, 5000, '%k.1 = f32[8]{0} custom-call(f32[8]{0} %x), '
+                         'custom_call_target="tpu_custom_call"'],
+        ["fusion.7", 6000, 2000,
+         "%fusion.7 = f32[8]{0} fusion(f32[8]{0} %x), kind=kLoop, "
+         "calls=%fused_computation.7"],
+        ["copy.3", 9000, 1000, "%copy.3 = f32[8]{0} copy(f32[8]{0} %x)"]],
+        "modules": [], "async": []}}, "host": [], "files": []}
+    s = summarize(ev)
+    assert s["classes"] == {"other": {"kernel": pytest.approx(5e-6),
+                                      "other": pytest.approx(2e-6),
+                                      "copy": pytest.approx(1e-6)}}
+    assert s["matmuls"] == []
+
+
+def test_matmul_row_rides_and_carried_collective():
+    table = build_op_table(CLASS_HLO)
+    s = summarize(_events(
+        l0=_lane([("mm_ar_fusion", 0, 10), ("mm_ar_fusion", 20, 10)]),
+        l1=_lane([("mm_ar_fusion", 0, 12)])), table, peak_flops=1e9)
+    (row,) = s["matmuls"]
+    assert row["scope"] == "backward/hvd_ffn"
+    assert row["carries_collective"] is True
+    # the other names on the body's instructions, and its transcendentals
+    assert row["rides"] == ["hvd_exchange", "hvd_ffn_gate", "logistic"]
+    assert (row["lhs"], row["rhs"], row["result"]) == (
+        "f32[8,4]", "f32[4,4]", "f32[8,4]")
+    assert row["calls"] == 3 and row["flops"] == 2 * 8 * 4 * 4
+    assert row["device_s"] == pytest.approx(32e-6)
+    assert row["lost_s"] == pytest.approx(32e-6 - 3 * 256e-9)
+    # a device the peak table does not list: the two are left out
+    s = summarize(_events(l0=_lane([("mm_ar_fusion", 0, 10)])), table)
+    assert "at_peak_s" not in s["matmuls"][0]
+    assert "lost_s" not in s["matmuls"][0]
+
+
+@pytest.mark.parametrize("op_name,want", [
+    ("jit(step)/hvd_backward/transpose(jvp(hvd_forward))/jvp()/checkpoint/"
+     "rematted_computation/hvd_kda/hvd_kda_scan/hvd_kda_fwd/pallas_call",
+     "backward/hvd_kda/hvd_kda_scan/hvd_kda_fwd"),
+    ("jit(step)/hvd_backward/transpose(jvp(hvd_forward))/hvd_ffn/"
+     "transpose(jvp(hvd_ffn))/hvd_ffn_gate/mul",
+     "backward/hvd_ffn/hvd_ffn_gate"),
+    ("jit(step)/hvd_forward/jvp(hvd_mla_proj)/bsd,dhx->bshx/dot_general",
+     "forward/hvd_mla_proj"),
+    ("jit(step)/hvd_forward/hvd_moe/hvd_dispatch/all_to_all", "dispatch"
+     "/hvd_moe"),
+    ("jit(step)/hvd_exchange_bucket3/hvd_ici/psum", "exchange/hvd_ici"),
+    ("jit(step)/hvd_optimizer/hvd_exchange/psum", "optimizer"),
+    ("jit(step)/transpose/neg", "other"), ("", "other"), (None, "other"),
+])
+def test_scope_path(op_name, want):
+    assert scope_path(op_name) == want
+    assert want.split("/")[0] == (phase_of_op_name(op_name) or "other")
 
 
 def test_parse_trace_dir_missing_empty_malformed(tmp_path):
@@ -256,6 +497,35 @@ def test_reader_on_recorded_v5e_trace_agrees_with_benchmark_reducer(
     # the recording's Pallas kernel predates the kernel names: it is
     # filed under its instruction's
     assert s["kernels"]["hvd_forward.1"]["calls"] == 3
+    # the two fusions that hold a convolution are the matmuls, with the
+    # FLOPs their operand types give; what rides along is read from the
+    # fusions' bodies
+    table = build_op_table(hlo)
+    assert op_class(table["convolution_tanh_fusion"]) == "matmul"
+    assert op_class(table["fusion"]) == "matmul"
+    assert s["classes"]["forward"].keys() == {"matmul", "kernel"}
+    assert s["classes"]["backward"].keys() == {"matmul"}
+    rows = {r["scope"]: r for r in s["matmuls"]}
+    assert rows["forward"]["flops"] == rows["backward"]["flops"] \
+        == 2 * 8192 * 2048 * 2048
+    assert rows["forward"]["rides"] == ["tanh"]
+    assert rows["backward"]["rides"] == ["hvd_optimizer"]
+    assert rows["backward"]["operands"] == "f32×f32"
+    assert rows["backward"]["calls"] == 3
+    # the capture names its chip: a v5e's peak, and what was lost to it
+    assert events["device_kind"] == "TPU v5 Lite"
+    assert rows["forward"]["at_peak_s"] == pytest.approx(
+        2 * 8192 * 2048 * 2048 / 197e12)
+    assert 0 < rows["forward"]["lost_s"] < rows["forward"]["device_s"]
+    # scopes split the phases, classes split the scopes
+    by_region = {}
+    for path, sec in s["scopes"].items():
+        region = path.split("/")[0]
+        by_region[region] = by_region.get(region, 0.0) + sec
+    for region, sec in by_region.items():
+        assert sec == pytest.approx(s["phases"][region], rel=1e-12)
+    assert sum(sum(by.values()) for by in s["classes"].values()) \
+        == pytest.approx(s["total_s"], rel=1e-12)
 
 
 def test_tick_owner_locking_and_window(monkeypatch, tmp_path):
@@ -319,9 +589,22 @@ def test_trace_steps_compiled_end_to_end(hvd_init, tmp_path):
         # the in-graph psum exchange nonzero
         assert s["phases"]["forward"] > 0.0
         assert s["phases"]["exchange"] > 0.0
+        # ... split by scope path and op class, and the matmuls among
+        # them set against their FLOPs (a chip's shard: 2 of 16 rows)
+        assert sum(s["scopes"].values()) == pytest.approx(s["total_s"])
+        assert sum(v for p, v in s["scopes"].items()
+                   if p.split("/")[0] == "forward") == pytest.approx(
+                       s["phases"]["forward"])
+        assert s["classes"].keys() == s["scopes"].keys()
+        assert any("matmul" in by for by in s["classes"].values())
+        dots = [r for r in s["matmuls"] if r["flops"] == 2 * 2 * 16 * 4]
+        assert dots and all(r["calls"] >= 2 and r["device_s"] > 0.0
+                            and r["operands"] == "f32×f32" for r in dots)
+        assert all(r["flops"] is not None for r in s["matmuls"])
         meta = xla_trace.load_meta(tr.last_dir)
         assert meta["steps"] == 2 and meta["summary"] is not None
         assert meta["op_phases"]
+        assert {"scopes", "classes", "matmuls"} <= meta["summary"].keys()
         # device-busy time per lane fits inside the capture wall window
         # (generous bound: CPU trace timestamps are coarse)
         assert s["total_s"] / s["lanes"] <= meta["wall_elapsed_s"] * 1.5
@@ -449,3 +732,32 @@ def test_cli_xla_trace_without_flight_dumps(tmp_path, capsys):
     assert rc == 0
     out = capsys.readouterr().out
     assert "xla device trace" in out and "not clock-aligned" in out
+
+
+@pytest.mark.parametrize("as_file", [False, True])
+def test_cli_xla_trace_reduces_a_dump_beside_its_hlo(tmp_path, capsys,
+                                                     as_file):
+    """No sidecar, but the HLO text of the program that ran lies beside
+    the capture (the benchmark's ``--dump-dir``): the capture is joined
+    against it, and the report has the scope x class table and the matmul
+    rows. The capture may be named as its directory or as the file."""
+    from horovod_tpu.diag.__main__ import main
+    for ext in ("xplane.pb", "hlo.txt"):
+        shutil.copy(os.path.join(RECORDED, f"tiny_step.{ext}"), tmp_path)
+    target = tmp_path / "tiny_step.xplane.pb" if as_file else tmp_path
+    rep_path = tmp_path / "report.json"
+    assert main([str(tmp_path), "--xla-trace", str(target),
+                 "--json", str(rep_path)]) == 0
+    out = capsys.readouterr().out
+    assert "steps=3 lanes=1" in out and "forward=0.569" in out
+    assert "by scope path and op class" in out
+    rows = {ln.split()[0]: ln.split() for ln in out.splitlines()
+            if ln.startswith("  ") and len(ln.split()) == 7}
+    assert rows["scope"][1:] == ["total", "matmul", "kernel", "reduce",
+                                 "copy", "other"]
+    assert rows["forward"][1:4] == ["0.569", "0.362", "0.207"]
+    assert "matmul fusions: 2 rows, 0.1374 TFLOP/step/lane" in out
+    assert "f32×f32  backward  f32[8192,2048] * f32[8192,2048] -> " \
+        "f32[2048,2048]  rides: hvd_optimizer" in out
+    assert json.loads(rep_path.read_text())["xla"]["phases"]["forward"] \
+        == pytest.approx(0.001707939)
