@@ -835,6 +835,18 @@ FFN_GATED_LAYERS = _registry.gauge(
     "or sparse FFNs only.")
 
 
+# Chunked cross entropy (models/transformer.py _chunked_cross_entropy)
+HEAD_GRAD_CHUNKS = _registry.gauge(
+    "hvd_head_grad_chunks",
+    "Chunks of the chunked cross entropy (TransformerConfig.loss_chunk) "
+    "per product into the head's weight gradient, in the loss traced "
+    "last: chosen from the shapes so that a product contracts over up to "
+    "2,048 tokens while the group's stored logit cotangents stay under "
+    "128 MiB (transformer._head_grad_chunks); 1 = every chunk its own "
+    "product, the plain scan. Set while the loss is traced, not per "
+    "step; a loss without loss_chunk leaves it as it was.")
+
+
 # Inference serving (serve/; docs/serving.md, docs/observability.md
 # "Serving")
 SERVE_REQUESTS = _registry.counter(

@@ -117,11 +117,16 @@ class TransformerConfig:
     # visiting shards).
     attention_window: int = None
     # Chunked cross entropy: compute the LM head + loss over sequence
-    # chunks of this many positions under jax.checkpoint, so the (B, S,
-    # vocab) f32 logits tensor never materializes — at 32k vocab the
-    # logits, not K/V, are what OOMs first at long context. None =
-    # whole-sequence logits (the default; required if callers want
-    # forward() logits anyway).
+    # chunks of this many positions, each chunk's logits computed again
+    # in the backward, so the (B, S, vocab) f32 logits tensor never
+    # materializes — at 32k vocab the logits, not K/V, are what OOMs
+    # first at long context. What is live is one chunk's (B, chunk,
+    # V_loc) f32 logits plus, in the backward, the logit cotangents of a
+    # group of chunks in `dtype` (at most 128 MiB): the head's weight
+    # gradient is one product a group of up to 2,048 tokens, the group
+    # chosen from the shapes (_head_grad_chunks; gauge
+    # hvd_head_grad_chunks). None = whole-sequence logits (the default;
+    # required if callers want forward() logits anyway).
     loss_chunk: int = None
     # Rematerialization: wrap each transformer layer in jax.checkpoint so
     # the backward recomputes activations instead of storing them — trades
@@ -1159,12 +1164,123 @@ def _cross_entropy(logits, targets, axes):
     return jnp.mean(_nll(logits, targets, axes))
 
 
+# The tokens one product into the head's weight gradient should contract
+# over, and the most the group's stored logit cotangents may take. Under
+# the scan's own transpose every chunk adds its product into the whole
+# float32 (d, V_loc) gradient, which is read and written once a chunk: at
+# one sequence of 16,384 positions in chunks of 512 the gradient's bytes,
+# not its FLOPs, set the product's time (sc2-3b_s16k: 32 x 1.2 GB = 47 ms
+# at 819 GB/s for 25.1 ms of FLOPs, 56.7 ms measured). The sweep, PR 36's
+# chip runs of sc2-3b_s16k_noremat on one seed (PERF.md section 6): chunks
+# a product (tokens) / the product's ms a step / dev_head_ce_ms /
+# tokens/s / the allocator's peak GiB:
+#   1 (512)    56.7 / 143.9 / 28,262 / 14.261, the plain scan
+#   2 (1,024)  34.7 / 124.0 / 29,266 / 14.361 (taken: 96 MiB stored)
+#   4 (2,048)  27.0 / 117.2 / 29,581 / 14.472, over peak_hbm_gib's bound
+#              of 1 % by 0.07 GiB: the 192 MiB it stores are ALL that cell
+#              adds, its peak lies in the head's backward
+#   8 (4,096)  34.8 / 126.1 / 29,140 / 14.683: a product of 4,096 tokens
+#              runs SLOWER than two of 2,048 (8.7 ms against 2 x 3.4)
+# So 2,048 tokens is the target and the bytes are what stops short of it:
+# sc2-3b_s16k itself (remat; 10.339 GiB at 2 and at 4) would take 4 chunks
+# for 25,762 tokens/s against 25,505, but the two cells have one shape.
+_HEAD_GROUP_TOKENS = 2048
+_HEAD_GROUP_BYTES = 128 * 2 ** 20
+
+
+def _head_grad_chunks(chunks, chunk_tokens, vloc, itemsize):
+    """Chunks of the chunked cross entropy per product into the head's
+    weight gradient: the smallest power of two dividing ``chunks`` that
+    gives a product ``_HEAD_GROUP_TOKENS`` tokens, while the group's logit
+    cotangents (tokens x ``vloc`` x ``itemsize``) stay under
+    ``_HEAD_GROUP_BYTES``; a chunk count it cannot divide that far keeps
+    the largest it can (1: every chunk its own product)."""
+    g = 1
+    while (g * chunk_tokens < _HEAD_GROUP_TOKENS and chunks % (2 * g) == 0
+           and 2 * g * chunk_tokens * vloc * itemsize <= _HEAD_GROUP_BYTES):
+        g *= 2
+    return g
+
+
+def _grouped_nll(cfg, axes):
+    """``f(carry, head, xg, tg)``: ``carry`` plus the negative log
+    likelihoods of a GROUP of chunks, ``xg`` (g, B, chunk, d) and ``tg``
+    (g, B, chunk), added chunk by chunk as the plain scan adds them, under
+    the head's leaves ``head`` (``ln_f`` and the weight).
+
+    The arithmetic is autodiff's of :func:`_head` and :func:`_nll`, chunk
+    by chunk in both directions (the backward computes each chunk's logits
+    again, as ``jax.checkpoint`` would). A ``custom_vjp`` only to make the
+    weight gradient ONE product over the group's tokens: the transpose of
+    a scan cannot defer a product across iterations, so it adds every
+    chunk's ``xn^T d`` into the whole float32 (d, V_loc) gradient. Here
+    each chunk's logit cotangent ``d`` is kept rounded to ``cfg.dtype``
+    (the product is at the default precision, which multiplies operands
+    rounded to bfloat16: the rounding is its own, moved in front of it as
+    in :func:`_cotangent_once`), and after the last chunk autodiff's
+    transpose of :func:`_head_product` in the weight runs once over all
+    of them."""
+
+    def norm(ln, x):
+        return _rmsnorm(x, ln, cfg.norm_eps)
+
+    def chunk_nll(raw, tk):
+        return jnp.sum(_nll(_head_scale(raw, cfg), tk, axes))
+
+    def total(carry, head, xg, tg):
+        def one(carry, xt):
+            xk, tk = xt
+            return carry + chunk_nll(
+                _head_product(head, norm(head["ln_f"], xk), cfg), tk), None
+        return lax.scan(one, carry, (xg, tg))[0]
+
+    group = jax.custom_vjp(total)
+
+    def bwd(res, ct):
+        # as jax.checkpoint does: what the forward left is not to be
+        # touched before the cotangent is there. Without it XLA moves
+        # the recompute about and granite4h-micro_s16k's step compiles to
+        # 13.06 GiB for its parent's 12.00 (11.97 with it; offline)
+        head, xg, tg, ct = lax.optimization_barrier(res + (ct,))
+        weight = {k: v for k, v in head.items() if k != "ln_f"}
+
+        def one(dln, xt):
+            xk, tk = xt
+            xn, norm_vjp = jax.vjp(norm, head["ln_f"], xk)
+            raw, xn_vjp = jax.vjp(
+                lambda a: _head_product(weight, a, cfg), xn)
+            d, = jax.vjp(lambda r: chunk_nll(r, tk), raw)[1](ct)
+            d = d.astype(cfg.dtype)
+            dlnk, dxk = norm_vjp(*xn_vjp(d.astype(raw.dtype)))
+            return dln + dlnk, (dxk, d)
+
+        dln, (dxg, dg) = lax.scan(one, jnp.zeros_like(head["ln_f"]),
+                                  (xg, tg))
+        # one product over the group's tokens
+        xn = norm(head["ln_f"], xg).reshape(1, -1, xg.shape[-1])
+        dweight, = jax.vjp(lambda w: _head_product(w, xn, cfg), weight)[1](
+            dg.reshape(1, -1, dg.shape[-1]).astype(jnp.float32))
+        return ct, dict(dweight, ln_f=dln), dxg, None
+
+    group.defvjp(lambda carry, head, xg, tg: (
+        total(carry, head, xg, tg), (head, xg, tg)), bwd)
+    return group
+
+
 def _chunked_cross_entropy(params, x, targets, cfg, axes):
-    """Mean CE with the head applied per sequence chunk under
-    jax.checkpoint: peak logits memory is (B, chunk, V_loc) in both
-    directions (backward rematerializes each chunk's logits), instead of
-    the full (B, S, V_loc) — the long-context memory wall at real vocab
-    sizes."""
+    """Mean CE with the head applied per sequence chunk: the logits that
+    are live are one chunk's (B, chunk, V_loc) float32 in both directions
+    (the backward computes each chunk's logits again), instead of the
+    full (B, S, V_loc) — the long-context memory wall at real vocab
+    sizes — plus, in the backward, the logit cotangents of one GROUP of
+    chunks in ``cfg.dtype``, (g, B, chunk, V_loc): the head's weight
+    gradient is one product a group (:func:`_grouped_nll`), ``g`` chosen
+    from the shapes by :func:`_head_grad_chunks` and shown by the gauge
+    ``hvd_head_grad_chunks``. At ``g`` = 1 (a chunk of
+    ``_HEAD_GROUP_TOKENS`` tokens or more, as in cgpt13b_dp1 / _dp4 and
+    sc2-3b_s4k, whose compiled steps are their parent's instruction for
+    instruction) it is the plain scan under ``jax.checkpoint``."""
+    from .. import metrics
     chunk = cfg.loss_chunk
     b, s_loc, d = x.shape
     if s_loc % chunk != 0:
@@ -1175,34 +1291,54 @@ def _chunked_cross_entropy(params, x, targets, cfg, axes):
             f"length ({s_loc}); pick a divisor (e.g. "
             f"{math.gcd(s_loc, chunk)})")
     n = s_loc // chunk
+    key = "embed" if cfg.tie_embeddings else "lm_head"
+    vloc = params[key].shape[0 if cfg.tie_embeddings else 1]
+    g = _head_grad_chunks(n, b * chunk, vloc, jnp.dtype(cfg.dtype).itemsize)
+    metrics.HEAD_GRAD_CHUNKS.set(g)
     xc = jnp.moveaxis(x.reshape(b, n, chunk, d), 1, 0)       # (n,B,c,d)
     tc = jnp.moveaxis(targets.reshape(b, n, chunk), 1, 0)    # (n,B,c)
 
-    @jax.checkpoint
-    def one(carry, ct):
-        xk, tk = ct
-        nll = _nll(_head(params, xk, cfg), tk, axes)
-        return carry + jnp.sum(nll), None
+    if g == 1:
+        @jax.checkpoint
+        def one(carry, ct):
+            xk, tk = ct
+            nll = _nll(_head(params, xk, cfg), tk, axes)
+            return carry + jnp.sum(nll), None
+    else:
+        xc = xc.reshape((n // g, g) + xc.shape[1:])
+        tc = tc.reshape((n // g, g) + tc.shape[1:])
+        group = _grouped_nll(cfg, axes)
+        head = {k: params[k] for k in ("ln_f", key)}
+
+        def one(carry, ct):
+            return group(carry, head, *ct), None
 
     total, _ = lax.scan(one, jnp.float32(0), (xc, tc))
     return total / (b * s_loc)
+
+
+def _head_product(params, x, cfg):
+    """The normed rows ``x`` (B, S, d) times the (possibly vocab-sharded)
+    head, tied or not: f32 (B, S, V_loc)."""
+    if cfg.tie_embeddings:
+        return jnp.einsum("bsd,vd->bsv", x,
+                          params["embed"].astype(cfg.dtype),
+                          preferred_element_type=jnp.float32)
+    return jnp.einsum("bsd,dv->bsv", x, params["lm_head"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _head_scale(logits, cfg):
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 def _head(params, x, cfg):
     """Final norm + (possibly vocab-sharded) LM head: (B, S, d) -> f32
     logits (B, S, V_loc)."""
     x = _rmsnorm(x, params["ln_f"], cfg.norm_eps)
-    if cfg.tie_embeddings:
-        logits = jnp.einsum("bsd,vd->bsv", x,
-                            params["embed"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = jnp.einsum("bsd,dv->bsv", x,
-                            params["lm_head"].astype(cfg.dtype),
-                            preferred_element_type=jnp.float32)
-    if cfg.logits_scaling != 1.0:
-        logits = logits / cfg.logits_scaling
-    return logits
+    return _head_scale(_head_product(params, x, cfg), cfg)
 
 
 def loss_fn(params, tokens, targets, cfg, axes=None):

@@ -196,9 +196,11 @@ def _shaped(tree, sharding):
         a.shape, a.dtype, sharding=sharding), tree)
 
 
-def _compile_step(mesh, tx, spec=None, state=None, cfg=_STEP_CFG):
+def _compile_step(mesh, tx, spec=None, state=None, cfg=_STEP_CFG,
+                  tokens=None):
     """``_build_step_program``'s program for ``mesh``, lowered on shapes
-    and compiled for its described chips."""
+    and compiled for its described chips (``tokens``: the global batch's
+    shape, two sequences of 512 a chip unless given)."""
     axes = tfm.ShardAxes(dp=None, sp=None, tp=None)
 
     def loss_fn(p, tokens, targets):
@@ -209,7 +211,7 @@ def _compile_step(mesh, tx, spec=None, state=None, cfg=_STEP_CFG):
                             jax.random.PRNGKey(0))
     if state is None:
         state = jax.eval_shape(tx.init, params)
-    tok = jax.ShapeDtypeStruct((2 * mesh.size, 512), jnp.int32,
+    tok = jax.ShapeDtypeStruct(tokens or (2 * mesh.size, 512), jnp.int32,
                                sharding=NamedSharding(mesh, P("hvd")))
     prog = step_program._build_step_program(
         mesh, loss_fn, tx, 2, "psum", True, None, False, True, False, None,
@@ -246,26 +248,34 @@ def test_step_on_one_chip_is_the_bare_jit(topo):
     assert xla_trace.exchange_async(text)["all_reduces"] == 0
 
 
-def _wide_float32_pairs(text, least=2 ** 22):
-    """Convolutions of an optimized HLO text under ``hvd_backward``, the
-    head's aside, with ``least`` output elements or more and float32 on
-    both sides."""
-    found, dtypes = [], {}
+def _convolutions(text):
+    """``(line, result dimensions, operand types)`` of every convolution of
+    an optimized HLO text, a type written ``f32[512,8192]``."""
+    types = {}
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]", line)
         if not m:
             if line.startswith("}"):
-                dtypes = {}  # names are per computation
+                types = {}  # names are per computation
             continue
         name, dtype, dims = m.groups()
-        dtypes[name] = dtype
+        types[name] = f"{dtype}[{dims}]"
         call = re.search(r" convolution\(([^)]*)\)", line)
-        if call and "hvd_backward" in line and "hvd_head_ce" not in line \
-                and np.prod([int(d) for d in dims.split(",")]) >= least \
-                and all(dtypes.get(o) == "f32" for o in
-                        re.findall(r"%([\w.\-]+)", call.group(1))):
-            found.append(line.split(" = ")[0].strip())
-    return found
+        if call:
+            yield line, [int(d) for d in dims.split(",")], [
+                types.get(o) for o in re.findall(r"%([\w.\-]+)",
+                                                 call.group(1))]
+
+
+def _wide_float32_pairs(text, least=2 ** 22):
+    """Convolutions of an optimized HLO text under ``hvd_backward``, the
+    head's aside, with ``least`` output elements or more and float32 on
+    both sides."""
+    return [line.split(" = ")[0].strip()
+            for line, dims, operands in _convolutions(text)
+            if "hvd_backward" in line and "hvd_head_ce" not in line
+            and np.prod(dims) >= least
+            and all(o and o.startswith("f32[") for o in operands)]
 
 
 def test_gated_ffn_backward_feeds_no_wide_matmul_two_float32_operands(topo):
@@ -286,6 +296,34 @@ def test_gated_ffn_backward_feeds_no_wide_matmul_two_float32_operands(topo):
     text = _compile_step(mesh, optax.adamw(3e-4), cfg=cfg).as_text()
     assert "hvd_ffn_gate" in text
     assert _wide_float32_pairs(text) == []
+
+
+@pytest.mark.parametrize("batch, token_operand", [
+    (1, "bf16[4,512,8192]"), (4, "f32[4,512,8192]")],
+    ids=["one-sequence-g4", "four-sequences-g1"])
+def test_head_gradient_is_one_product_a_group_of_chunks(topo, batch,
+                                                        token_operand):
+    """The head's weight gradient of the 16k cells' loss (PERF.md section
+    6 PR 36): ONE sequence in chunks of 512 positions. The scan's own
+    transpose made a product a chunk into the whole float32 (d, V)
+    gradient — its token operand ``f32[512,8192]`` on the parent commit,
+    4 products a step here; ``_grouped_nll`` makes one over the group's
+    4 x 512 tokens from the cotangents stored in bfloat16. At four
+    sequences a chunk holds 4 x 512 tokens already: the plain scan's
+    product a chunk from the float32 cotangent, as on the parent. (The
+    compiled text writes no trip count: the tokens a product contracts
+    over say how many a step makes.)"""
+    cfg = tfm.TransformerConfig(
+        vocab_size=8192, d_model=1024, n_heads=8, n_layers=1, d_ff=2048,
+        max_seq=2048, dtype=jnp.bfloat16, attention_impl="flash",
+        flash_interpret=False, positional="rope", loss_chunk=512)
+    mesh = Mesh(np.array(topo.devices[:1]), ("hvd",))
+    text = _compile_step(mesh, optax.adamw(3e-4), cfg=cfg,
+                         tokens=(batch, 2048)).as_text()
+    # the products into the (d, V) gradient, dimensions of 1 aside
+    made = [operands for _, dims, operands in _convolutions(text)
+            if [d for d in dims if d != 1] == [1024, 8192]]
+    assert len(made) == 1 and token_operand in made[0], made
 
 
 def test_step_with_striped_state_compiles_on_four_chips(topo):
