@@ -825,6 +825,14 @@ def record_kda_state(stats):
     _set_state_rms(KDA_STATE_RMS, stats["kda_state_rms"])
 
 
+# Short-convolution layers (models/sconv.py; docs/observability.md)
+SCONV_LAYERS = _registry.gauge(
+    "hvd_sconv_layers",
+    "Gated short-convolution layers (LayerSpec.mixer == 'sconv') of the "
+    "model that transformer.trunk_with_stats traced last; set while it "
+    "is traced, not per step, as hvd_kda_layers is.")
+
+
 # Dense gated FFNs (models/transformer.py _gated_ffn; docs/observability.md)
 FFN_GATED_LAYERS = _registry.gauge(
     "hvd_ffn_gated_layers",

@@ -120,16 +120,18 @@ def ssm_specs():
 def causal_conv1d(x, w, b):
     """Depthwise causal convolution: ``y[t, c] = b[c] + sum_k w[k, c]
     x[t - (K - 1) + k, c]`` with zeros before the sequence. x: (B, L, C),
-    w: (K, C); float32 out. The padded copy stays in x's type (half the
-    bytes of a float32 one at bf16); the taps are widened as they are
-    read."""
+    w: (K, C), b: (C,) or ``None`` (no bias: the sum starts at the first
+    tap's product and no zeros are built); float32 out. The padded copy
+    stays in x's type (half the bytes of a float32 one at bf16); the taps
+    are widened as they are read."""
     k, l = w.shape[0], x.shape[1]
     xp = jnp.pad(x, ((0, 0), (k - 1, 0), (0, 0)))
     w = w.astype(jnp.float32)
-    y = b.astype(jnp.float32)
+    y = None if b is None else b.astype(jnp.float32)
     for i in range(k):
-        y = y + w[i] * lax.slice_in_dim(xp, i, i + l, axis=1).astype(
+        tap = w[i] * lax.slice_in_dim(xp, i, i + l, axis=1).astype(
             jnp.float32)
+        y = tap if y is None else y + tap
     return y
 
 

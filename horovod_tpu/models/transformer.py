@@ -71,7 +71,9 @@ class LayerSpec:
     # say nothing, the ssm_* sizes of the configuration do) | "kda"
     # (models/kda.py; likewise, the kda_* sizes) | "mla" (latent attention:
     # n_heads heads with q and k of mla_qk_nope + mla_qk_shared beside v of
-    # mla_v_dim, k and v from one latent of mla_kv_rank; no positions)
+    # mla_v_dim, k and v from one latent of mla_kv_rank; no positions) |
+    # "sconv" (models/sconv.py: the doubly-gated short convolution over
+    # d_model channels, sconv_kernel taps)
     mixer: str = "attention"
 
 
@@ -207,6 +209,14 @@ class TransformerConfig:
     # The sparse layers' router: "softmax" | "sigmoid" (models/moe.py
     # MoEConfig.router: sigmoid scores, a balancing bias in the choice).
     moe_router: str = "softmax"
+    # Short-convolution layers (LayerSpec.mixer == "sconv";
+    # models/sconv.py): the taps of the depthwise causal convolution.
+    sconv_kernel: int = 3
+    # RMS norm over each head's features of q and of k, after the
+    # projection and before the rotary embedding: one weight of head_dim
+    # for q and one for k (leaves q_norm, k_norm), shared by the layer's
+    # heads, eps norm_eps. Training forward only.
+    qk_norm: bool = False
 
     def __post_init__(self):
         if self.layers:
@@ -219,11 +229,11 @@ class TransformerConfig:
                     "a per-layer description needs positional='rope' "
                     "(each LayerSpec carries its rotary embedding or "
                     "None) and an explicit head_size")
-            if any(l.mixer not in ("attention", "mamba2", "kda", "mla")
-                   for l in self.layers):
+            if any(l.mixer not in ("attention", "mamba2", "kda", "mla",
+                                   "sconv") for l in self.layers):
                 raise ValueError(
-                    "a layer's mixer is 'attention', 'mamba2', 'kda' or "
-                    "'mla'")
+                    "a layer's mixer is 'attention', 'mamba2', 'kda', "
+                    "'mla' or 'sconv'")
             if self.has_ssm and self.ssm_heads < 1:
                 raise ValueError("a Mamba-2 layer needs ssm_heads")
             if self.kda_layers and self.kda_heads < 1:
@@ -282,6 +292,11 @@ class TransformerConfig:
         return sum(l.mixer == "kda" for l in self.layers)
 
     @property
+    def sconv_layers(self):
+        """How many layers mix with the gated short convolution."""
+        return sum(l.mixer == "sconv" for l in self.layers)
+
+    @property
     def has_sparse(self):
         return bool(self.moe_layers) or any(
             l.mlp == "sparse" for l in self.layers)
@@ -298,8 +313,15 @@ class TransformerConfig:
                        "or pipeline stage here",
                        "mla": "latent-attention layers, whose unequal qk / "
                        "v head sizes the cache and the stage program do "
-                       "not hold"}
+                       "not hold",
+                       "sconv": "short-convolution layers, whose last "
+                       "products have no decode step or pipeline stage "
+                       "here",
+                       "qk_norm": "attention layers with the per-head QK "
+                       "norm, which only the training forward applies"}
                 mixers = {l.mixer for l in self.layers}
+                if self.qk_norm:
+                    mixers.add("qk_norm")
                 raise ValueError(
                     "this path takes one kind of layer; the "
                     "configuration describes its layers one by one "
@@ -308,12 +330,13 @@ class TransformerConfig:
                         for mixer, text in why.items() if mixer in mixers))
             return self.layers[i]
         if i is None and (self.attention_scale, self.embedding_multiplier,
-                          self.residual_multiplier,
-                          self.logits_scaling) != (None, 1.0, 1.0, 1.0):
+                          self.residual_multiplier, self.logits_scaling,
+                          self.qk_norm) != (None, 1.0, 1.0, 1.0, False):
             raise ValueError(
                 "this path computes the plain block: attention_scale, "
-                "embedding_multiplier, residual_multiplier and "
-                "logits_scaling are applied by the training forward only")
+                "embedding_multiplier, residual_multiplier, "
+                "logits_scaling and the per-head QK norm (qk_norm) are "
+                "applied by the training forward only")
         return LayerSpec(
             n_heads=self.n_heads, window=self.attention_window,
             rope=RopeSpec() if self.positional == "rope" else None,
@@ -354,6 +377,12 @@ class TransformerConfig:
                          dtype=self.dtype, param_dtype=self.param_dtype,
                          interpret=self.flash_interpret)
 
+    @property
+    def sconv_cfg(self):
+        from .sconv import SConvConfig
+        return SConvConfig(d_model=self.d_model, d_conv=self.sconv_kernel,
+                           dtype=self.dtype, param_dtype=self.param_dtype)
+
 
 @dataclasses.dataclass(frozen=True)
 class ShardAxes:
@@ -388,6 +417,9 @@ def init_params(key, cfg):
         elif spec.mixer == "kda":
             from .kda import init_kda_params
             layer["kda"] = init_kda_params(lk[0], cfg.kda_cfg)
+        elif spec.mixer == "sconv":
+            from .sconv import init_sconv_params
+            layer["sconv"] = init_sconv_params(lk[0], cfg.sconv_cfg)
         elif spec.mixer == "mla":
             mk = jax.random.split(lk[0], 3)
             rank, vd = cfg.mla_kv_rank, cfg.mla_v_dim
@@ -406,6 +438,9 @@ def init_params(key, cfg):
             layer["wqkv"] = dense(lk[0], (d, 3, h, hd), d)
         if spec.mixer == "attention":
             layer["wo"] = dense(lk[1], (h, hd, d), d)
+            if cfg.qk_norm:
+                layer["q_norm"] = jnp.ones((hd,), pd)
+                layer["k_norm"] = jnp.ones((hd,), pd)
             if cfg.attn_gate:
                 # (heads, d_model): a minor dimension of 6 or 9 heads
                 # would be padded to 128 lanes wherever XLA keeps the
@@ -451,6 +486,9 @@ def param_specs(cfg, axes=ShardAxes()):
         elif spec.mixer == "kda":
             from .kda import kda_specs
             layer["kda"] = kda_specs()
+        elif spec.mixer == "sconv":
+            from .sconv import sconv_specs
+            layer["sconv"] = sconv_specs()
         elif spec.mixer == "mla":
             layer["mla"] = {name: P() for name in (
                 "wq", "w_kva", "kv_norm", "w_kvb", "wo")}
@@ -462,6 +500,8 @@ def param_specs(cfg, axes=ShardAxes()):
             layer["wqkv"] = P(None, None, tp, None)  # heads sharded
         if spec.mixer == "attention":
             layer["wo"] = P(tp, None, None)    # row-parallel (psum after)
+            if cfg.qk_norm:
+                layer["q_norm"], layer["k_norm"] = P(), P()
             if cfg.attn_gate:
                 layer["wg"] = P(tp, None)      # one gate per q head
         if spec.mlp == "sparse":
@@ -731,11 +771,23 @@ def _attention_block_kv(p, x, cfg, axes, spec=None):
     of layer the configuration has). The attention itself runs under the
     device scope ``hvd_attn_window`` or ``hvd_attn_full``; the
     projections, rope and the per-head gate around it under
-    ``hvd_attn_proj``."""
+    ``hvd_attn_proj``; with ``cfg.qk_norm`` the two per-head norms
+    between projection and rope under ``hvd_qk_norm``."""
     spec = spec or cfg.layer_spec()
     h = _pre_norm(x, p["ln1"], cfg)
     with jax.named_scope("hvd_attn_proj"):
         q, k, v = _qkv_proj(p, h, cfg)
+    if cfg.qk_norm:
+        if axes.sp or axes.tp:
+            raise ValueError(
+                "an attention layer with the per-head QK norm runs whole "
+                "on its chip: the sequence-parallel paths and the heads' "
+                "tensor parallelism (axes.sp, axes.tp) do not carry the "
+                "norm's shared weights")
+        with jax.named_scope("hvd_qk_norm"):
+            q = _head_norm(q, p["q_norm"], cfg.norm_eps)
+            k = _head_norm(k, p["k_norm"], cfg.norm_eps)
+    with jax.named_scope("hvd_attn_proj"):
         if spec.rope is not None:
             s_loc = x.shape[1]
             start = _axis_index(axes.sp) * s_loc
@@ -755,6 +807,14 @@ def _attention_block_kv(p, x, cfg, axes, spec=None):
             "bshx,hxd->bsd", attn, p["wo"].astype(cfg.dtype),
             preferred_element_type=jnp.float32), axes.tp)
     return _residual(x, out, cfg), k, v
+
+
+def _head_norm(x, scale, eps):
+    """RMS norm over each head's features, x (B, S, H, D), ``scale`` (D,)
+    shared by the heads: float32 throughout like :func:`_rmsnorm`, rounded
+    to x's type once, after the weight."""
+    return _rmsnorm(x.astype(jnp.float32), scale.astype(jnp.float32),
+                    eps).astype(x.dtype)
 
 
 def _pre_norm(x, scale, cfg):
@@ -801,6 +861,20 @@ def _kda_block(p, x, cfg, axes):
     out, rms = kda_mixer(p["kda"], _pre_norm(x, p["ln1"], cfg),
                          cfg.kda_cfg)
     return _residual(x, out, cfg), rms
+
+
+def _sconv_block(p, x, cfg, axes):
+    """The mixer half of a short-convolution layer (models/sconv.py):
+    ``x + residual_multiplier * mixer(rmsnorm(x))``."""
+    if axes.sp:
+        raise ValueError(
+            "a short-convolution layer reads the positions before its "
+            "own; sequence parallelism (axes.sp) would have to hand them "
+            "from shard to shard and is not supported")
+    from .sconv import sconv_mixer
+    out = sconv_mixer(p["sconv"], _pre_norm(x, p["ln1"], cfg),
+                      cfg.sconv_cfg)
+    return _residual(x, out, cfg)
 
 
 def _mla_block(p, x, cfg, axes):
@@ -1090,6 +1164,7 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     metrics.KDA_LAYERS.set(cfg.kda_layers)
     metrics.KDA_FUSED_LAYERS.set(
         cfg.kda_layers if cfg.kda_cfg.fused else 0)
+    metrics.SCONV_LAYERS.set(cfg.sconv_layers)
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 
@@ -1101,6 +1176,8 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
             x, rms = _kda_block(p, x, cfg, axes)
         elif spec.mixer == "mla":
             x = _mla_block(p, x, cfg, axes)
+        elif spec.mixer == "sconv":
+            x = _sconv_block(p, x, cfg, axes)
         else:
             x = _attention_block(p, x, cfg, axes, spec)
         return _mlp_block_stats(p, x, cfg, axes) + (rms,)

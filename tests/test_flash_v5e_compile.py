@@ -74,6 +74,9 @@ CELLS = {
     # granite4h-micro_s16k: the one attention layer, heads of 64 (half
     # the 128 lanes), 4 query heads a kv head, the scale the model states
     "granite_full": (1, 16384, 32, 8, 64, None),
+    # lfm2-24b-a2b_s16k: the same heads at the default scale 1 / 8 (q and
+    # k arrive normed and rotated; the kernels do not see that)
+    "lfm2_full": (1, 16384, 32, 8, 64, None),
     # kimi-linear_s16k: the latent-attention layer, q and k of 192 (one
     # and a half lane tiles) beside v of 128 (V_SIZES), every head its key
     "kimi_mla": (1, 16384, 32, 32, 192, None),
@@ -161,9 +164,13 @@ def test_kda_kernels_compile_for_v5e(one_chip):
 
 
 # (rows, contraction, columns): laguna-s21_s8k's expert matrices over one
-# chunk of sorted rows (moe.chunk_rows at 16,384 tokens), 8 experts, bf16
+# chunk of sorted rows (moe.chunk_rows at 16,384 tokens), 8 experts, bf16;
+# lfm2-24b-a2b_s16k's: a chunk of 16,384 rows, experts 1536 wide (12 lane
+# tiles)
 @pytest.mark.parametrize("shape", [(10240, 3072, 1024),
-                                   (10240, 1024, 3072)])
+                                   (10240, 1024, 3072),
+                                   (16384, 2048, 1536),
+                                   (16384, 1536, 2048)])
 def test_grouped_matmul_vjp_compiles_for_v5e(one_chip, shape):
     """gmm forward, gmm for the rows' gradient, tgmm for the matrices'."""
     m, k, n = shape

@@ -469,18 +469,29 @@ def test_the_two_copies_of_the_reference_agree():
 
 @pytest.mark.parametrize("path", ["decode", "serve", "pipeline", "sp"])
 @pytest.mark.parametrize("mixer, named", [
-    ("kda", "KDA layers"), ("mla", "unequal qk / v head sizes")])
+    ("kda", "KDA layers"), ("mla", "unequal qk / v head sizes"),
+    ("sconv", "short-convolution layers"),
+    ("qk_norm", "per-head QK norm")])
 def test_a_layer_is_refused_where_it_cannot_run(mixer, named, path):
     """Decode, the serving engine, the pipeline stages and sequence
-    parallelism say which layer they cannot run."""
-    cfg = make_cfg(kinds=(mixer, "kda"))
+    parallelism say which layer they cannot run (``qk_norm``: an
+    attention layer with the per-head norm on q and k)."""
+    if mixer == "qk_norm":
+        layers = (tfm.LayerSpec(2, rope=tfm.RopeSpec()),
+                  tfm.LayerSpec(2, mixer="kda"))
+        cfg = make_cfg(layers=layers, n_layers=2, qk_norm=True)
+    else:
+        cfg = make_cfg(kinds=(mixer, "kda"))
     params = tfm.init_params(jax.random.PRNGKey(0), cfg)
     tok, tgt = batch(shape=(2, 32))
     if path == "sp":
-        block = tfm._kda_block if mixer == "kda" else tfm._mla_block
+        block = {"kda": tfm._kda_block, "mla": tfm._mla_block,
+                 "sconv": tfm._sconv_block,
+                 "qk_norm": tfm._attention_block}[mixer]
         with pytest.raises(ValueError, match="sequence"):
             block(params["layers"][0], jnp.zeros((1, 16, 64)), cfg,
-                  tfm.ShardAxes(dp=None, sp="sp", tp=None))
+                  tfm.ShardAxes(dp=None, sp="sp", tp=None),
+                  *((cfg.layers[0],) if mixer == "qk_norm" else ()))
         return
     with pytest.raises(ValueError, match=named):
         if path == "decode":

@@ -281,7 +281,9 @@ def test_kernel_and_head_names_in_the_step_jaxpr():
                  "hvd_ssm_conv", "hvd_ssm_scan", "hvd_ssm_norm",
                  "hvd_ssm_out_proj", "hvd_kda", "hvd_kda_in_proj",
                  "hvd_kda_conv", "hvd_kda_scan", "hvd_kda_norm",
-                 "hvd_kda_out_proj", "hvd_mla_proj"):
+                 "hvd_kda_out_proj", "hvd_mla_proj", "hvd_sconv",
+                 "hvd_sconv_in_proj", "hvd_sconv_gate",
+                 "hvd_sconv_out_proj", "hvd_qk_norm"):
         assert phase_of_op_name(f"jit(f)/{name}/x") is None
 
 
