@@ -855,6 +855,17 @@ HEAD_GRAD_CHUNKS = _registry.gauge(
     "step; a loss without loss_chunk leaves it as it was.")
 
 
+# Flash attention (ops/flash_attention.py; docs/observability.md)
+FLASH_HEADS_PER_STEP = _registry.gauge(
+    "hvd_flash_heads_per_step",
+    "Query heads one grid step of the flash kernels (forward, dQ, dK/dV) "
+    "served in the attention traced last: the GQA group, query heads per "
+    "kv head, read from the shapes — the step fetches K and V once for "
+    "all of them, and dK/dV sum the group in the kernel. 1 = plain "
+    "multi-head attention. Set while the attention is traced, not per "
+    "step; the ring attention's band tiles leave it as it was.")
+
+
 # Inference serving (serve/; docs/serving.md, docs/observability.md
 # "Serving")
 SERVE_REQUESTS = _registry.counter(

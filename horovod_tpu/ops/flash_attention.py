@@ -5,12 +5,23 @@ TPU-native "hot op" layer: attention without materializing the S x S score
 matrix in HBM — in either direction, with VMEM bounded by one (block_q,
 block_k) tile pair regardless of sequence length.
 
-Grid: (batch*heads, outer blocks, inner blocks) — the innermost grid axis
-streams the opposing side's blocks sequentially (TPU grids execute in
+Grid: (batch*kv heads, outer blocks, inner blocks) — the innermost grid
+axis streams the opposing side's blocks sequentially (TPU grids execute in
 order on a core), with the online-softmax state (running max m, normalizer
 l, accumulator acc) held in VMEM scratch that persists across the inner
 axis. Block-level causal pruning wraps each body in ``pl.when``: pruned
 cells do no compute.
+
+A grid step serves the ``group`` query heads that share one K/V head
+(grouped-query attention; ``group`` = H / H_kv, read from the shapes): it
+fetches its K and V blocks once, and the q-side blocks (q, out, dO, dQ,
+lse, delta) carry the group as their leading axis, ``(group, block, ·)``
+— consecutive rows of the (B*H, S, D) slab, so no copy lays them out.
+The heads run in ONE ``lax.fori_loop`` (``_per_head``: two an iteration
+where the group is even), so the kernel body, and the host's time to
+trace and lower it, do not grow with the group; the per-head softmax
+state is a (group, block, ·) scratch. At group 1 the kernels are the
+one-head kernels op for op.
 
 The forward's tile body (``_tile_scores`` / ``_softmax_update``, shared
 with the band forward):
@@ -56,11 +67,12 @@ want the unfused math ask for it (``attention_impl="dense"``,
 interpreter, which is how CPU tests validate them without a TPU.
 
 Grouped-query attention (GQA): k/v may carry H_kv < H heads with
-H % H_kv == 0. The kernels never materialize expanded K/V — q-head slab
-row ``bh`` simply streams kv row ``bh // group`` (forward and dq), so
-the K/V HBM footprint stays at H_kv heads; dK/dV come back per q-head
-and reduce over each group in one XLA sum. This includes the lse/tile
-variants ring attention composes with.
+H % H_kv == 0. The kernels never materialize expanded K/V, so the K/V
+HBM footprint stays at H_kv heads; dK/dV sum the group's heads and the
+q blocks in the kernel's f32 scratch and leave it once, in k's and v's
+type. This includes the lse/tile variants ring attention composes with.
+The band kernels below still run a grid step per query head
+(``bh // group``) and reduce dK/dV over the group in one XLA sum.
 
 Band tiles (ring attention under a sliding window): a visiting K/V shard
 sits a traced number of global positions before the local queries — the
@@ -105,14 +117,15 @@ def _tile_mask(off, qi, kj, block, window):
     return keep
 
 
-def _tile_scores(q_ref, k_ref, scale):
-    """(block_q, block_k) f32 scores of one tile. q and k cross the MXU in
-    the type they arrive in (bf16 in one pass, f32 unnarrowed), contracted
-    over their last dimension with no transpose materialised, accumulated
-    in f32. The scale goes on q, in f32 and rounded back to q's type once:
-    for bf16 that is what the MXU makes of the backward kernels' f32
-    ``q * scale``, so forward and backward see the same scores."""
-    q = (q_ref[0].astype(jnp.float32) * scale).astype(q_ref.dtype)
+def _tile_scores(q_ref, k_ref, scale, h=0):
+    """(block_q, block_k) f32 scores of one tile, for query head ``h`` of
+    the q block. q and k cross the MXU in the type they arrive in (bf16
+    in one pass, f32 unnarrowed), contracted over their last dimension
+    with no transpose materialised, accumulated in f32. The scale goes
+    on q, in f32 and rounded back to q's type once: for bf16 that is what
+    the MXU makes of the backward kernels' f32 ``q * scale``, so forward
+    and backward see the same scores."""
+    q = (q_ref[h].astype(jnp.float32) * scale).astype(q_ref.dtype)
     return jax.lax.dot_general(q, k_ref[0], (((1,), (1,)), ((), ())),
                                preferred_element_type=jnp.float32)
 
@@ -133,39 +146,85 @@ def _softmax_init(m_scr, l_scr, acc_scr):
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
 
-def _softmax_update(s, v_ref, m_scr, l_scr, acc_scr):
+def _softmax_update(s, v_ref, m_scr, l_scr, acc_scr, hs=...):
     """One online-softmax step over the (masked) f32 scores ``s`` of a
-    tile. The running max and normaliser are (block, _LANES) f32, every
-    lane of a row holding the same value; the row reductions keep their
-    dimension and broadcast into them, so no value changes between the
-    lane and the sublane layout per tile (see :func:`_lanes`)."""
-    m_prev = m_scr[...]
+    tile, into the state ``hs`` of the scratch (one head's). The running
+    max and normaliser are (block, _LANES) f32, every lane of a row
+    holding the same value; the row reductions keep their dimension and
+    broadcast into them, so no value changes between the lane and the
+    sublane layout per tile (see :func:`_lanes`)."""
+    m_prev = m_scr[hs]
     m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     alpha = jnp.exp(m_prev - m_next)
     p = jnp.exp(s - _lanes(m_next, s.shape[-1]))
-    m_scr[...] = m_next
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=-1, keepdims=True)
+    m_scr[hs] = m_next
+    l_scr[hs] = alpha * l_scr[hs] + jnp.sum(p, axis=-1, keepdims=True)
     v = v_ref[0]
-    acc_scr[...] = acc_scr[...] * _lanes(alpha, v.shape[-1]) + jnp.dot(
+    acc_scr[hs] = acc_scr[hs] * _lanes(alpha, v.shape[-1]) + jnp.dot(
         p.astype(v.dtype), v, preferred_element_type=jnp.float32)
 
 
-def _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr):
-    l = jnp.maximum(l_scr[...], 1e-30)
-    o_ref[0] = (acc_scr[...] / _lanes(l, acc_scr.shape[-1])).astype(
+def _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr, h=0, hs=...):
+    l = jnp.maximum(l_scr[hs], 1e-30)
+    o_ref[h] = (acc_scr[hs] / _lanes(l, acc_scr.shape[-1])).astype(
         o_ref.dtype)
     # the one rank-1 value: a row of lse per query block, once per row
-    lse_ref[0, 0] = (m_scr[...] + jnp.log(l))[:, 0]
+    lse_ref[h, 0] = (m_scr[hs] + jnp.log(l))[:, 0]
 
 
-def _softmax_scratch(block, d):
-    return [pltpu.VMEM((block, _LANES), jnp.float32),
-            pltpu.VMEM((block, _LANES), jnp.float32),
-            pltpu.VMEM((block, d), jnp.float32)]
+def _heads(group, shape):
+    """A per-head scratch: ``shape`` for each of the ``group`` heads a
+    grid step serves; one head's has no head axis."""
+    return shape if group == 1 else (group, *shape)
+
+
+def _softmax_scratch(block, d, group=1):
+    return [pltpu.VMEM(_heads(group, (block, _LANES)), jnp.float32),
+            pltpu.VMEM(_heads(group, (block, _LANES)), jnp.float32),
+            pltpu.VMEM(_heads(group, (block, d)), jnp.float32)]
+
+
+def _per_head(group, body):
+    """``body(h, hs)`` for each query head a grid step serves: ``h``
+    indexes the head in a (group, ...) block, ``hs`` its state in a
+    :func:`_heads` scratch. Group 1: the one head, the static 0 and the
+    whole scratch — the one-head kernel, op for op. Else ONE
+    ``lax.fori_loop`` over the heads, two an iteration where the group
+    is even: the body is traced once or twice whatever the group, so
+    neither the kernel's size nor the host's time to trace and lower it
+    grows with the group (PERF.md section 6, PR 39). Two heads an
+    iteration let the compiler overlap one head's matmuls with the
+    other's softmax, where one a time drains at every head."""
+    if group == 1:
+        body(0, ...)
+        return
+
+    def one(g, carry):
+        body(g, g)
+        return carry
+
+    def two(i, carry):
+        return one(2 * i + 1, one(2 * i, carry))
+
+    if group % 2:
+        jax.lax.fori_loop(0, group, one, 0)
+    else:
+        jax.lax.fori_loop(0, group // 2, two, 0)
+
+
+def _causal_keep(qi, kj, block, window):
+    """(block, block) keep-mask of the static tile (qi, kj), the backward
+    kernels' form of :func:`_tile_mask` at offset 0."""
+    q_pos = qi * block + jax.lax.broadcasted_iota(jnp.int32, (block, 1), 0)
+    k_pos = kj * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep = jnp.logical_and(keep, q_pos - k_pos < window)
+    return keep
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
-                *, block, num_kv, scale, causal, window=None):
+                *, block, num_kv, scale, causal, window=None, group=1):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -181,22 +240,27 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr,
         live = jnp.logical_and(live,
                                kj >= qi - _window_blocks(window, block))
 
-    @pl.when(live)
-    def _body():
-        s = _tile_scores(q_ref, k_ref, scale)
+    def head(h, hs):
+        s = _tile_scores(q_ref, k_ref, scale, h)
         if causal:
             s = jnp.where(_tile_mask(0, qi, kj, block, window), s, NEG_INF)
-        _softmax_update(s, v_ref, m_scr, l_scr, acc_scr)
+        _softmax_update(s, v_ref, m_scr, l_scr, acc_scr, hs)
+
+    @pl.when(live)
+    def _body():
+        _per_head(group, head)
 
     last = qi if causal else num_kv - 1
 
     @pl.when(kj == last)
     def _finalize():
-        _softmax_finalize(o_ref, lse_ref, m_scr, l_scr, acc_scr)
+        _per_head(group, lambda h, hs: _softmax_finalize(
+            o_ref, lse_ref, m_scr, l_scr, acc_scr, h, hs))
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-                   dq_scr, *, block, num_kv, scale, causal, window=None):
+                   dq_scr, *, block, num_kv, scale, causal, window=None,
+                   group=1):
     qi = pl.program_id(1)
     kj = pl.program_id(2)
 
@@ -209,39 +273,39 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         live = jnp.logical_and(live,
                                kj >= qi - _window_blocks(window, block))
 
-    @pl.when(live)
-    def _body():
-        q = q_ref[0].astype(jnp.float32) * scale       # (block, D)
-        do = do_ref[0].astype(jnp.float32)             # (block, D)
-        lse = lse_ref[0, 0]                            # (block,)
-        delta = delta_ref[0, 0]                        # (block,)
+    def head(h, hs):
+        q = q_ref[h].astype(jnp.float32) * scale       # (block, D)
+        do = do_ref[h].astype(jnp.float32)             # (block, D)
+        lse = lse_ref[h, 0]                            # (block,)
+        delta = delta_ref[h, 0]                        # (block,)
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0)
-            k_pos = kj * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep = jnp.logical_and(keep, q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
+            s = jnp.where(_causal_keep(qi, kj, block, window), s, NEG_INF)
         p = jnp.exp(s - lse[:, None])                  # (block, block)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
-        dq_scr[...] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+        dq_scr[hs] += jnp.dot(ds, k, preferred_element_type=jnp.float32)
+
+    @pl.when(live)
+    def _body():
+        _per_head(group, head)
 
     last = qi if causal else num_kv - 1
 
     @pl.when(kj == last)
     def _finalize():
-        dq_ref[0] = (dq_scr[...] * scale).astype(dq_ref.dtype)
+        dq_ref[0 if group == 1 else ...] = (dq_scr[...] * scale).astype(
+            dq_ref.dtype)
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                     dk_ref, dv_ref, dk_scr, dv_scr, *, block, num_q, scale,
-                    causal, window=None):
+                    causal, window=None, group=1):
+    """dK / dV of one kv block, summed in the f32 scratch over the q
+    blocks (the inner grid axis) and the ``group`` query heads that share
+    the kv head; written once, in k's and v's type."""
     ki = pl.program_id(1)
     qi = pl.program_id(2)
 
@@ -257,30 +321,26 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         live = jnp.logical_and(live,
                                qi <= ki + _window_blocks(window, block))
 
-    @pl.when(live)
-    def _body():
+    def head(h, _):
         k = k_ref[0].astype(jnp.float32)               # (block, D)
         v = v_ref[0].astype(jnp.float32)
-        q = q_ref[0].astype(jnp.float32) * scale
-        do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0, 0]
-        delta = delta_ref[0, 0]
+        q = q_ref[h].astype(jnp.float32) * scale
+        do = do_ref[h].astype(jnp.float32)
+        lse = lse_ref[h, 0]
+        delta = delta_ref[h, 0]
         s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
         if causal:
-            q_pos = qi * block + jax.lax.broadcasted_iota(
-                jnp.int32, (block, 1), 0)
-            k_pos = ki * block + jax.lax.broadcasted_iota(
-                jnp.int32, (1, block), 1)
-            keep = q_pos >= k_pos
-            if window is not None:
-                keep = jnp.logical_and(keep, q_pos - k_pos < window)
-            s = jnp.where(keep, s, NEG_INF)
+            s = jnp.where(_causal_keep(qi, ki, block, window), s, NEG_INF)
         p = jnp.exp(s - lse[:, None])                  # (q_block, k_block)
         dv_scr[...] += jnp.dot(p.T, do, preferred_element_type=jnp.float32)
         dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
         ds = p * (dp - delta[:, None])
         # q already carries `scale`, so ds^T q absorbs it.
         dk_scr[...] += jnp.dot(ds.T, q, preferred_element_type=jnp.float32)
+
+    @pl.when(live)
+    def _body():
+        _per_head(group, head)
 
     @pl.when(qi == num_q - 1)
     def _finalize():
@@ -440,6 +500,40 @@ def _softmax_scale(d, scale=None):
     return 1.0 / (d ** 0.5) if scale is None else float(scale)
 
 
+def _count_heads(group):
+    """The gauge hvd_flash_heads_per_step: the query heads one grid step
+    of the static kernels serves, in the attention traced last."""
+    from .. import metrics
+    metrics.FLASH_HEADS_PER_STEP.set(group)
+
+
+_VMEM_UNASKED = 16 * 2**20  # what a kernel gets on a v5e unless it asks
+_VMEM_MOST = 100 * 2**20    # of the v5e's 128 MiB
+
+
+def _vmem(group, block, d, dv, dtype):
+    """``pallas_call`` options of a kernel whose grid step serves
+    ``group`` query heads: none at group 1 (the one-head kernels fit the
+    VMEM a kernel gets unasked), else a VMEM limit sized from the shapes —
+    one bound for the three kernels: the widest step's blocks (q-side
+    (group, block, ·) rows: q, dO and dQ or out; K, V in and dK, dV out;
+    lse and delta rows padded to 8 sublanes), double-buffered, the
+    per-head f32 state (the forward's m, l, acc), and the body's f32
+    (block, block) tiles; half as much again of room. 12 heads of 128 at
+    block 512 ask 39 MiB of the v5e's 128; at most 100."""
+    if group == 1:
+        return {}
+    it = jnp.dtype(dtype).itemsize
+    rows = group * block
+    blocks = (rows * (2 * d + dv) * it + 2 * block * (d + dv) * it
+              + 2 * group * 8 * block * 4)
+    need = 2 * blocks + rows * (2 * _LANES + max(d, dv)) * 4 + (
+        6 * block * block * 4)
+    limit = max(_VMEM_UNASKED, -(-3 * need // 2 // 2**20) * 2**20)
+    limit = min(limit, _VMEM_MOST)
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=limit)}
+
+
 def _to_slab(x):
     b, s, h, d = x.shape
     return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
@@ -521,46 +615,51 @@ def _flash_fwd_impl(q, k, v, causal, block_size, interpret, window=None,
         raise _ragged_error(s, block_size)
 
     n = s // block
+    h_kv = k.shape[2]
+    _count_heads(group)
     qs, ks, vs = _to_slab(q), _to_slab(k), _to_slab(v)
     kernel = functools.partial(_fwd_kernel, block=block, num_kv=n,
-                               scale=scale, causal=causal, window=window)
+                               scale=scale, causal=causal, window=window,
+                               group=group)
+    # The grid's first axis runs over the kv slab's rows: a step fetches
+    # one K and one V block and serves the `group` query heads that share
+    # them, slab rows r*group .. r*group + group-1 — consecutive in the q
+    # slab already, so a (group, block, ·) block holds them.
     # Causal pruning must also kill the K/V DMAs, not just the compute:
     # map pruned cells (kj > qi) to the diagonal block they already hold,
     # so the pipeline sees an unchanged block index and skips the copy —
     # otherwise upper-triangle cells still stream K/V from HBM, roughly
-    # doubling memory traffic at long sequence lengths. Under GQA the
-    # K/V slab has Hkv rows; q-head row bh reads kv row bh // group, so
-    # grouped-query attention never materializes expanded K/V.
+    # doubling memory traffic at long sequence lengths.
     if causal and window is not None:
         wb = _window_blocks(window, block)
-        kv_map = lambda bh, qi, kj: (bh // group,  # noqa: E731
-                                     jnp.clip(kj, qi - wb, qi), 0)
+        kv_map = lambda r, qi, kj: (r,  # noqa: E731
+                                    jnp.clip(kj, qi - wb, qi), 0)
     elif causal:
-        kv_map = lambda bh, qi, kj: (bh // group,  # noqa: E731
-                                     jnp.minimum(kj, qi), 0)
+        kv_map = lambda r, qi, kj: (r, jnp.minimum(kj, qi), 0)  # noqa: E731
     else:
-        kv_map = lambda bh, qi, kj: (bh // group, kj, 0)  # noqa: E731
+        kv_map = lambda r, qi, kj: (r, kj, 0)  # noqa: E731
     out, lse = _named_pallas_call(
         "hvd_flash_fwd",
         kernel,
-        grid=(b * h, n, n),
+        grid=(b * h_kv, n, n),
         in_specs=[
-            pl.BlockSpec((1, block, d), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((group, block, d), lambda r, qi, kj: (r, qi, 0)),
             pl.BlockSpec((1, block, d), kv_map),
             pl.BlockSpec((1, block, dv), kv_map),
         ],
         out_specs=[
-            pl.BlockSpec((1, block, dv), lambda bh, qi, kj: (bh, qi, 0)),
+            pl.BlockSpec((group, block, dv), lambda r, qi, kj: (r, qi, 0)),
             # lse rides as (B*H, 1, block-of-S): TPU lowering needs the
             # trailing two block dims to tile (8, 128) or match the array.
-            pl.BlockSpec((1, 1, block), lambda bh, qi, kj: (bh, 0, qi)),
+            pl.BlockSpec((group, 1, block), lambda r, qi, kj: (r, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, s, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, 1, s), jnp.float32),
         ],
-        scratch_shapes=_softmax_scratch(block, dv),
+        scratch_shapes=_softmax_scratch(block, dv, group),
         interpret=interpret,
+        **_vmem(group, block, d, dv, q.dtype),
     )(qs, ks, vs)
     return _from_slab(out, b, h), lse
 
@@ -656,6 +755,7 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
             scale)
         return dq[:, :s], dk[:, :s], dv_[:, :s]
     n = s // block
+    _count_heads(group)
 
     qs, ks, vs = _to_slab(q), _to_slab(k), _to_slab(v)
     dos = _to_slab(g)
@@ -669,84 +769,78 @@ def _flash_bwd_impl(causal, block_size, interpret, q, k, v, out, lse, g,
         if g_lse is not None:
             delta = delta - g_lse.astype(jnp.float32).reshape(b * h, 1, s)
 
+    # Both grids run over the kv slab's rows, as the forward's: a step
+    # serves the `group` query heads of kv row r, q-side blocks
+    # (group, block, ·) at row r.
     def q_blk(w):
-        return pl.BlockSpec((1, block, w), lambda bh, i, j: (bh, i, 0))
+        return pl.BlockSpec((group, block, w), lambda r, i, j: (r, i, 0))
 
     wb = None if window is None else _window_blocks(window, block)
     # same DMA clamp as the forward: pruned (j > i) cells re-address the
-    # diagonal K/V block instead of streaming a block they won't use
-    # (K/V rows indexed through // group for GQA, as in the forward);
+    # diagonal K/V block instead of streaming a block they won't use;
     # a window additionally clamps below the band start
     if causal and window is not None:
-        kv_map = lambda bh, i, j: (bh // group,  # noqa: E731
-                                   jnp.clip(j, i - wb, i), 0)
+        kv_map = lambda r, i, j: (r, jnp.clip(j, i - wb, i), 0)  # noqa: E731
     elif causal:
-        kv_map = lambda bh, i, j: (bh // group,  # noqa: E731
-                                   jnp.minimum(j, i), 0)
+        kv_map = lambda r, i, j: (r, jnp.minimum(j, i), 0)  # noqa: E731
     else:
-        kv_map = lambda bh, i, j: (bh // group, j, 0)  # noqa: E731
-    vec_q = pl.BlockSpec((1, 1, block), lambda bh, i, j: (bh, 0, i))
+        kv_map = lambda r, i, j: (r, j, 0)  # noqa: E731
+    vec_q = pl.BlockSpec((group, 1, block), lambda r, i, j: (r, 0, i))
 
     dq = _named_pallas_call(
         "hvd_flash_dq",
         functools.partial(_bwd_dq_kernel, block=block, num_kv=n,
-                          scale=scale, causal=causal, window=window),
-        grid=(b * h, n, n),
+                          scale=scale, causal=causal, window=window,
+                          group=group),
+        grid=(b * h_kv, n, n),
         in_specs=[q_blk(d), pl.BlockSpec((1, block, d), kv_map),
                   pl.BlockSpec((1, block, dv), kv_map), q_blk(dv), vec_q,
                   vec_q],
         out_specs=q_blk(d),
         out_shape=jax.ShapeDtypeStruct((b * h, s, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM(_heads(group, (block, d)), jnp.float32)],
         interpret=interpret,
+        **_vmem(group, block, d, dv, q.dtype),
     )(qs, ks, vs, dos, lse, delta)
 
-    # dkv grid: (bh, k block, q block) — inner axis streams q blocks.
+    # dkv grid: (r, k block, q block) — inner axis streams q blocks.
     # Pruned cells here are j (q block) < i (k block): clamp the q-side
     # DMAs up to the diagonal.
     if causal and window is not None:
-        q_map = lambda bh, i, j: (bh, jnp.clip(j, i, i + wb), 0)  # noqa: E731
+        q_map = lambda r, i, j: (r, jnp.clip(j, i, i + wb), 0)  # noqa: E731
         vec_in = pl.BlockSpec(
-            (1, 1, block),
-            lambda bh, i, j: (bh, 0, jnp.clip(j, i, i + wb)))
+            (group, 1, block),
+            lambda r, i, j: (r, 0, jnp.clip(j, i, i + wb)))
     elif causal:
-        q_map = lambda bh, i, j: (bh, jnp.maximum(j, i), 0)  # noqa: E731
-        vec_in = pl.BlockSpec((1, 1, block),
-                              lambda bh, i, j: (bh, 0, jnp.maximum(j, i)))
+        q_map = lambda r, i, j: (r, jnp.maximum(j, i), 0)  # noqa: E731
+        vec_in = pl.BlockSpec((group, 1, block),
+                              lambda r, i, j: (r, 0, jnp.maximum(j, i)))
     else:
-        q_map = lambda bh, i, j: (bh, j, 0)  # noqa: E731
-        vec_in = pl.BlockSpec((1, 1, block), lambda bh, i, j: (bh, 0, j))
-    k_map = lambda bh, i, j: (bh // group, i, 0)  # noqa: E731
-    # dK/dV accumulate across the `group` query heads sharing each kv
-    # head. The kernel writes per-q-head partials (scratch accumulation
-    # across grid dim 0 would be clobbered by the inner k-block loop);
-    # the group-sum happens outside as one cheap XLA reduction. With
-    # group > 1 the partials stay f32 so that reduction keeps the f32
-    # accumulation used everywhere else (casting to bf16 before the
-    # group-sum would lose the low bits the sum is meant to carry).
-    part_dtype = jnp.float32 if group > 1 else k.dtype
+        q_map = lambda r, i, j: (r, j, 0)  # noqa: E731
+        vec_in = pl.BlockSpec((group, 1, block), lambda r, i, j: (r, 0, j))
+    k_map = lambda r, i, j: (r, i, 0)  # noqa: E731
+    # dK/dV sum over the q blocks AND the group's query heads in the f32
+    # scratch, and leave the kernel once, in k's and v's type: no
+    # per-q-head partials, no reduction after the call.
     dk, dv_ = _named_pallas_call(
         "hvd_flash_dkv",
         functools.partial(_bwd_dkv_kernel, block=block, num_q=n,
-                          scale=scale, causal=causal, window=window),
-        grid=(b * h, n, n),
-        in_specs=[pl.BlockSpec((1, block, d), q_map),
+                          scale=scale, causal=causal, window=window,
+                          group=group),
+        grid=(b * h_kv, n, n),
+        in_specs=[pl.BlockSpec((group, block, d), q_map),
                   pl.BlockSpec((1, block, d), k_map),
                   pl.BlockSpec((1, block, dv), k_map),
-                  pl.BlockSpec((1, block, dv), q_map), vec_in, vec_in],
-        out_specs=[q_blk(d), q_blk(dv)],
-        out_shape=[jax.ShapeDtypeStruct((b * h, s, d), part_dtype),
-                   jax.ShapeDtypeStruct((b * h, s, dv), part_dtype)],
+                  pl.BlockSpec((group, block, dv), q_map), vec_in, vec_in],
+        out_specs=[pl.BlockSpec((1, block, d), k_map),
+                   pl.BlockSpec((1, block, dv), k_map)],
+        out_shape=[jax.ShapeDtypeStruct((b * h_kv, s, d), k.dtype),
+                   jax.ShapeDtypeStruct((b * h_kv, s, dv), v.dtype)],
         scratch_shapes=[pltpu.VMEM((block, d), jnp.float32),
                         pltpu.VMEM((block, dv), jnp.float32)],
         interpret=interpret,
+        **_vmem(group, block, d, dv, q.dtype),
     )(qs, ks, vs, dos, lse, delta)
-
-    if group > 1:
-        dk = dk.reshape(b, h_kv, group, s, d).sum(axis=2).reshape(
-            b * h_kv, s, d).astype(k.dtype)
-        dv_ = dv_.reshape(b, h_kv, group, s, dv).sum(axis=2).reshape(
-            b * h_kv, s, dv).astype(v.dtype)
     return (_from_slab(dq, b, h), _from_slab(dk, b, h_kv),
             _from_slab(dv_, b, h_kv))
 

@@ -123,7 +123,7 @@ def test_flash_gqa_matches_dense(hvd_init, group, causal):
     """Grouped-query attention: H query heads share H/group K/V heads;
     the kernel must match the dense repeat-heads baseline."""
     # S = 256 with block 128 -> 2x2 blocks: the kernel path (NOT the
-    # dense fallback) runs, exercising the bh // group K/V index maps
+    # dense fallback) runs, a grid step serving the group's query heads
     B, S, H, D = 2, 256, 8, 16
     key = jax.random.PRNGKey(7)
     kq, kk, kv = jax.random.split(key, 3)
@@ -161,9 +161,10 @@ def test_flash_gqa_gradients_match_dense(hvd_init):
 
 
 def test_flash_gqa_gradients_bf16_f32_group_sum(hvd_init):
-    """bf16 K/V with a large group: the dk/dv group-sum must accumulate in
-    f32 (partials cast to bf16 BEFORE the sum lose the low bits — this
-    test's tolerance fails against that ordering)."""
+    """bf16 K/V with a large group: the dk/dv group-sum (in the kernel's
+    scratch) must accumulate in f32 (partials cast to bf16 BEFORE the sum
+    lose the low bits — this test's tolerance fails against that
+    ordering)."""
     B, S, H, D, G = 1, 256, 8, 8, 8  # one kv head, 8-way group sum
     key = jax.random.PRNGKey(9)
     kq, kk, kv = jax.random.split(key, 3)
@@ -485,3 +486,91 @@ def test_flash_forward_output_and_lse(hvd_init, S, heads, kv_heads,
     plain = flash_attention(q, k, v, True, 128, True, window)
     np.testing.assert_array_equal(np.asarray(plain, dtype=np.float32),
                                   np.asarray(out, dtype=np.float32))
+
+
+# --- a grid step serves a whole GQA group (PERF.md section 6, PR 39) ------
+
+# (q heads, kv heads, S, q/k head size, v head size, window, with lse);
+# block 128, so S 512 is a 4 x 4 grid of tiles and window 300 reaches
+# three blocks back; S 300 takes the ragged-causal pad to 384
+_GROUPED = {
+    "g1": (2, 2, 512, 64, 64, None, False),
+    "g2": (4, 2, 512, 64, 64, None, False),
+    "g4": (8, 2, 512, 64, 64, None, False),
+    "g6": (6, 1, 512, 64, 64, None, False),
+    "g9": (9, 1, 512, 64, 64, None, False),
+    "g12": (12, 1, 512, 64, 64, None, False),
+    "g4-window-one-block": (4, 1, 512, 64, 64, 128, False),
+    "g9-window-one-block": (9, 1, 512, 64, 64, 128, False),
+    "g6-window-blocks": (6, 1, 512, 64, 64, 300, False),
+    "g12-window-blocks-d128": (12, 1, 512, 128, 128, 300, False),
+    "g2-d128": (4, 2, 512, 128, 128, None, False),
+    "g1-qk192-v128": (2, 2, 256, 192, 128, None, False),
+    "g4-qk192-v128": (4, 1, 256, 192, 128, None, False),
+    "g6-ragged": (6, 1, 300, 64, 64, None, False),
+    "g9-ragged-window": (9, 1, 300, 64, 64, 200, False),
+    "g4-lse": (8, 2, 512, 64, 64, None, True),
+    "g12-lse-window": (12, 1, 512, 64, 64, 300, True),
+    "g6-lse-ragged": (6, 1, 300, 64, 64, None, True),
+}
+
+
+@pytest.mark.parametrize("case", _GROUPED)
+def test_grouped_kernels_match_fp32_reference(hvd_init, case):
+    """Forward and VJP (dq, dk, dv) of the kernels whose grid step serves
+    the query heads of one kv head, interpreted, against unfused fp32
+    attention; ``flash_attention_with_lse``'s lse and its cotangent too.
+    dK / dV sum the group in the kernel's f32 scratch."""
+    from horovod_tpu.ops.flash_attention import flash_attention_with_lse
+    h, h_kv, s, d, dv, window, with_lse = _GROUPED[case]
+    ks = jax.random.split(jax.random.PRNGKey(39), 5)
+    q = jax.random.normal(ks[0], (1, s, h, d), jnp.float32)
+    k = jax.random.normal(ks[1], (1, s, h_kv, d), jnp.float32)
+    v = jax.random.normal(ks[2], (1, s, h_kv, dv), jnp.float32)
+    g_out = jax.random.normal(ks[3], (1, s, h, dv), jnp.float32)
+    g_lse = jax.random.normal(ks[4], (1, h, s), jnp.float32)
+    if with_lse:
+        def kernels(q, k, v):
+            return flash_attention_with_lse(q, k, v, True, 128, True, window)
+
+        def reference(q, k, v):
+            return _dense_with_lse(q, k, v, True, window)
+        cot = (g_out, g_lse)
+    else:
+        def kernels(q, k, v):
+            return flash_attention(q, k, v, True, 128, True, window)
+
+        def reference(q, k, v):
+            return _dense_with_lse(q, k, v, True, window)[0]
+        cot = g_out
+    got, got_vjp = jax.vjp(kernels, q, k, v)
+    want, want_vjp = jax.vjp(reference, q, k, v)
+    for a, b in zip(jax.tree.leaves((got, got_vjp(cot))),
+                    jax.tree.leaves((want, want_vjp(cot)))):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   atol=5e-5, rtol=1e-4)
+
+
+@pytest.mark.parametrize("shape, heads", [
+    ((1, 16384, 24, 2, 128), 12),    # StarCoder2
+    ((1, 16384, 32, 8, 64), 4),      # LFM2
+    ((8, 2048, 16, 16, 128), 1),     # Cerebras
+], ids=["starcoder2", "lfm2", "cerebras"])
+def test_heads_per_step_gauge(hvd_init, shape, heads):
+    """hvd_flash_heads_per_step: the query heads one grid step served in
+    the attention traced last, forward and backward — set while tracing
+    (shapes only: nothing is computed)."""
+    from horovod_tpu import metrics
+    b, s, h, h_kv, d = shape
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, s, h_kv, d), jnp.bfloat16)
+    metrics.FLASH_HEADS_PER_STEP.set(0)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, True, 512, True).astype(
+            jnp.float32).sum()
+
+    jax.eval_shape(jax.grad(loss, argnums=(0, 1, 2)), q, k, k)
+    snap = metrics.snapshot()["hvd_flash_heads_per_step"]
+    assert snap["values"][""] == heads

@@ -122,6 +122,34 @@ def test_flash_vjp_compiles_for_v5e(one_chip, cell):
     assert text.count('custom_call_target="tpu_custom_call"') == 3
 
 
+@pytest.mark.parametrize("cell", ["sc2-3b_s16k", "laguna_window",
+                                  "lfm2_full"])
+def test_flash_vjp_text_does_not_grow_with_the_group(one_chip, cell):
+    """PR 38's refusal (PERF.md section 6, PR 39): a kernel body written
+    out once per head of the GQA group made the step's trace and lowering
+    4.35 s longer in sc2-3b_s16k_noremat, where no checkpoint shares a
+    layer's trace. One layer's flash VJP, lowered for the v5e, at the
+    cell's group (12 / 9 / 4 query heads a kv head) against the same
+    shapes with a kv head per query head: the text may be at most 1.5
+    times as long (the parent: 31,246 characters at group 12)."""
+    b, s, h, h_kv, d, window = CELLS[cell]
+
+    def text(kv_heads):
+        q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16,
+                                 sharding=one_chip)
+        k = jax.ShapeDtypeStruct((b, s, kv_heads, d), jnp.bfloat16,
+                                 sharding=one_chip)
+
+        def loss(q, k, v):
+            return flash_attention(q, k, v, True, 512, False,
+                                   window).astype(jnp.float32).sum()
+
+        return len(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, k, k).as_text())
+
+    assert text(h_kv) <= 1.5 * text(h)
+
+
 def test_kda_kernels_compile_for_v5e(one_chip):
     """kimi-linear_s16k's recurrence, 32 heads of 128 over 16,384
     positions in bf16: the forward kernel (as the forward pass calls it)
