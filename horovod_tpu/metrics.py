@@ -41,6 +41,7 @@ Design notes:
 """
 
 import json
+import math
 import os
 import threading
 import time
@@ -706,6 +707,19 @@ MOE_LOAD_MAX_OVER_MEAN = _registry.gauge(
     "observed step; 1 = even routing. The grouped matmuls' rows follow "
     "the loads, so this is the step's straggler factor, and past 2x the "
     "usual total a second chunk of rows runs (moe.chunk_rows).")
+MOE_CHUNK_ROWS = _registry.gauge(
+    "hvd_moe_chunk_rows",
+    "Rows of sorted assignments a chunk of the dropless sparse layer "
+    "traced last works through (moe.chunk_rows: twice what uniform "
+    "routing sends to the experts held, in whole 512-row tiles). Set "
+    "while the layer is traced, not per step.")
+MOE_ROWS_LIVE_SHARE = _registry.gauge(
+    "hvd_moe_rows_live_share",
+    "Live assignments over the rows of the chunks that ran (chunks x "
+    "hvd_moe_chunk_rows), lowest over the dropless sparse layers of the "
+    "most recent observed step: within one row tile per held expert, "
+    "the share of a chunk's row tiles the grouped matmuls run, since "
+    "rows past the last live assignment are skipped.")
 MOE_LOAD_BALANCE_LOSS = _registry.gauge(
     "hvd_moe_load_balance_loss",
     "Most recent Switch load-balancing aux loss (E * sum over experts "
@@ -769,6 +783,11 @@ def record_moe_routing(stats):
     MOE_LOAD_MAX_OVER_MEAN.set(max(
         (max(layer) * len(layer) / sum(layer) for layer in load
          if sum(layer)), default=1.0))
+    rows = MOE_CHUNK_ROWS.value()
+    if rows:
+        MOE_ROWS_LIVE_SHARE.set(min(
+            (sum(layer) / (math.ceil(sum(layer) / rows) * rows)
+             for layer in load if sum(layer)), default=1.0))
 
 
 # State-space layers (models/ssm.py; docs/observability.md)

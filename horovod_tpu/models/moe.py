@@ -315,11 +315,14 @@ def _chunk_rows(c, rows, x, gates, w, order, ends, top_k, dtype,
     live = (lo + jnp.arange(rows)) < ends[-1]
     bounds = jnp.clip(jnp.concatenate([jnp.zeros((1,), ends.dtype), ends]),
                       lo, lo + rows)
-    # Every row of the chunk goes through the kernels, the dead ones (zero
-    # rows in, masked out again) as the tail of the last group: a step's
-    # time then depends on how many chunks the routing fills and on
-    # nothing else, and no row is left unwritten.
-    sizes = (bounds[1:] - bounds[:-1]).at[-1].add(lo + rows - bounds[-1])
+    # The groups end at the chunk's last live assignment, so the kernels
+    # run only the row tiles that hold one: a chunk's time follows its
+    # live rows, and a full chunk runs every tile as before. The dead rows
+    # come back from each kernel unwritten (any value, NaN too), in the
+    # forward and in the rows' cotangents; only selects touch them: ``xs``
+    # in, ``out`` and ``g`` out (whose transposes zero the backward's
+    # dead cotangents), and ``tgmm`` reads the groups' rows alone.
+    sizes = bounds[1:] - bounds[:-1]
     with jax.named_scope("hvd_moe_dispatch"):
         xs = jnp.where(live[:, None], x[tok], 0)
     with jax.named_scope("hvd_moe_experts"):
@@ -424,6 +427,7 @@ def moe_dropless(params, x, cfg):
     ``hvd_moe_route`` / ``_dispatch`` / ``_experts`` / ``_combine`` /
     ``_shared``.
     """
+    from .. import metrics
     b, s, d = x.shape
     t, k = b * s, cfg.top_k
     first, count = cfg.experts_held
@@ -451,6 +455,7 @@ def moe_dropless(params, x, cfg):
             load = jnp.sum(key.reshape(-1, 1) == jnp.arange(count),
                            axis=0, dtype=jnp.int32)
             rows = chunk_rows(t, cfg)
+            metrics.MOE_CHUNK_ROWS.set(rows)
             # the last chunk may reach past the t * k assignments
             order = jnp.pad(order, (0, rows))
             stats = {"expert_load": load.astype(jnp.float32),

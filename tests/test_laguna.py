@@ -10,12 +10,16 @@ seeded random weights.
   uncut attention layer, the expert shares plus the shared expert counted
   once add up to the uncut sparse layer;
 - dropless routing against the masked dense reference under a skewed
-  router (several chunks of rows, an expert with no token);
+  router (several chunks of rows, an expert with no token), also with
+  NaN in every row a grouped matmul leaves unwritten; the groups ending
+  at a chunk's last live row against the padded form, bit for bit;
+- the routing gauges after a compiled step;
 - YaRN / partial rotary against the closed form;
 - the two copies of the reference agree.
 """
 
 import dataclasses
+import functools
 import importlib.util
 import math
 import os
@@ -29,6 +33,7 @@ from jax.tree_util import keystr, tree_flatten_with_path
 import reference_laguna as ref
 from horovod_tpu.models import moe
 from horovod_tpu.models import transformer as tfm
+from horovod_tpu.ops.grouped_matmul import grouped_matmul
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
@@ -170,11 +175,49 @@ def test_expert_shares_and_one_shared_expert_add_up_to_the_uncut_layer():
     assert rel_err(total - 3 * shared, whole) < 1e-5
 
 
-@pytest.mark.parametrize("skew", [0.0, 6.0])
-def test_dropless_routing_against_the_masked_dense_reference(skew):
-    """Output, gradients and counts under a router pushed onto the held
-    experts 0 and 2 (``skew``): more than one chunk of rows runs, and
-    expert 1 is biased away until it gets no token; nothing is dropped."""
+def nan_past_the_groups(out, sizes):
+    """What a grouped matmul leaves in the rows past its last group:
+    unwritten memory, here NaN in every one of them."""
+    dead = jnp.arange(out.shape[0]) >= jnp.sum(sizes)
+    return jnp.where(dead[:, None], jnp.nan, out)
+
+
+def poisoned_grouped_matmul(sums):
+    """``moe.grouped_matmul`` whose rows past the last group come back
+    NaN, in the forward and in the rows' cotangent (the interpreter would
+    hand back whatever it had there); ``sums`` collects each call's
+    ``sum(group_sizes)``."""
+    real = moe.grouped_matmul
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+    def gmm(lhs, rhs, sizes, out_dtype, interpret):
+        return gmm_fwd(lhs, rhs, sizes, out_dtype, interpret)[0]
+
+    def gmm_fwd(lhs, rhs, sizes, out_dtype, interpret):
+        jax.debug.callback(lambda n: sums.append(int(n)), jnp.sum(sizes))
+        out, vjp = jax.vjp(
+            lambda a, b: real(a, b, sizes, out_dtype, interpret), lhs, rhs)
+        return nan_past_the_groups(out, sizes), (vjp, sizes)
+
+    def gmm_bwd(out_dtype, interpret, res, ct):
+        vjp, sizes = res
+        d_lhs, d_rhs = vjp(ct)
+        return nan_past_the_groups(d_lhs, sizes), d_rhs, None
+
+    gmm.defvjp(gmm_fwd, gmm_bwd)
+    return gmm
+
+
+def padded_grouped_matmul(lhs, rhs, sizes, out_dtype, interpret):
+    """The form before the groups ended at the last live row: every row of
+    the chunk in some group, the dead ones as the last group's tail."""
+    sizes = sizes.at[-1].add(lhs.shape[0] - jnp.sum(sizes))
+    return grouped_matmul(lhs, rhs, sizes, out_dtype, interpret)
+
+
+def skewed_layer(skew):
+    """A sparse layer with no shared expert and its input, the router
+    pushed onto the held experts 0 and 2 by ``skew`` and away from 1."""
     cfg = dataclasses.replace(make_cfg().moe_cfg, shared_d_ff=0)
     p = moe.init_moe_params(jax.random.PRNGKey(5), cfg)
     x = jax.random.normal(jax.random.PRNGKey(6), (8, 128, 64))
@@ -183,6 +226,26 @@ def test_dropless_routing_against_the_masked_dense_reference(skew):
     bias = jnp.zeros((E,)).at[jnp.array([0, 2])].set(skew).at[1].set(
         -4 * skew)
     p["w_router"] = p["w_router"].at[0].add(bias)
+    return cfg, p, x
+
+
+@pytest.mark.parametrize("skew, poison", [
+    pytest.param(0.0, False, id="0.0"), pytest.param(6.0, False, id="6.0"),
+    pytest.param(0.0, True, id="0.0-poison"),
+    pytest.param(6.0, True, id="6.0-poison")])
+def test_dropless_routing_against_the_masked_dense_reference(
+        skew, poison, monkeypatch):
+    """Output, gradients and counts under a router pushed onto the held
+    experts 0 and 2 (``skew``): more than one chunk of rows runs, and
+    expert 1 is biased away until it gets no token; nothing is dropped.
+    ``poison``: the grouped matmuls' rows past the chunk's last live
+    assignment come back NaN, and nothing of them reaches a result; each
+    call's groups end at that assignment."""
+    cfg, p, x = skewed_layer(skew)
+    sums = []
+    if poison:
+        monkeypatch.setattr(moe, "grouped_matmul",
+                            poisoned_grouped_matmul(sums))
     moe_arch = make_arch()["moe"]
 
     def program(p, x):
@@ -209,6 +272,42 @@ def test_dropless_routing_against_the_masked_dense_reference(skew):
     for (path, g), w in zip(tree_flatten_with_path(got_g)[0],
                             jax.tree.leaves(want_g)):
         assert rel_err(g, w) < 2e-4, keystr(path)
+        assert bool(jnp.all(jnp.isfinite(g))), keystr(path)
+    if poison:
+        assert np.isfinite(float(got))
+        rows = moe.chunk_rows(8 * 128, cfg)
+        live = {min(rows, int(total) - lo)
+                for lo in range(0, int(total), rows)}
+        # forward, and the backward's recompute of each chunk: 3 matmuls
+        assert set(sums) == live and len(sums) == 6 * len(live), sums
+        assert min(sums) < rows
+
+
+@pytest.mark.parametrize("skew", [0.0, 6.0], ids=["one-chunk", "two-chunks"])
+def test_dropless_groups_ending_at_the_last_live_row_change_no_bit(
+        skew, monkeypatch):
+    """The groups ending at each chunk's last live assignment against the
+    padded form (the dead rows as the last group's tail): the live rows'
+    products are the same, and the padded form only ever added zero rows,
+    so the output and every gradient agree in every bit."""
+    cfg, p, x = skewed_layer(skew)
+
+    def run():
+        def f(p, x):
+            y, stats = moe.moe_dropless(p, x, cfg)
+            return jnp.sum(y * jnp.cos(y)), (y, stats["expert_load"])
+        return jax.jit(jax.value_and_grad(f, argnums=(0, 1),
+                                          has_aux=True))(p, x)
+
+    (_, (got, load)), got_g = run()
+    monkeypatch.setattr(moe, "grouped_matmul", padded_grouped_matmul)
+    (_, (want, _)), want_g = run()
+    chunks = math.ceil(float(load.sum()) / moe.chunk_rows(8 * 128, cfg))
+    assert chunks == (2 if skew else 1)
+    np.testing.assert_array_equal(got, want)
+    for (path, g), w in zip(tree_flatten_with_path(got_g)[0],
+                            jax.tree.leaves(want_g)):
+        np.testing.assert_array_equal(g, w, err_msg=keystr(path))
 
 
 def test_yarn_and_partial_rotary_against_the_closed_form():
@@ -305,7 +404,8 @@ def test_compiled_step_carries_the_routing_counters_out():
     for _ in range(3):
         params, opt_state, loss, aux = step(params, opt_state, tok, tgt)
         losses.append(float(loss))
-        hvd.metrics.record_moe_routing(jax.device_get(aux))
+        aux = jax.device_get(aux)
+        hvd.metrics.record_moe_routing(aux)
     assert step.compiled_steps == 3 and step.fallback_steps == 0
     assert step.cache_misses == 1
     assert losses[2] < losses[0]
@@ -324,3 +424,28 @@ def test_compiled_step_carries_the_routing_counters_out():
     assert total(after, "hvd_moe_load_max_over_mean") >= 1.0
     assert total(after, "hvd_moe_dropped_tokens_total") \
         == total(before, "hvd_moe_dropped_tokens_total")
+    # the chunk of the layer traced last: a chip's 64 tokens
+    rows = moe.chunk_rows(64, cfg.moe_cfg)
+    assert total(after, "hvd_moe_chunk_rows") == rows
+    # the last step's worst layer: its live rows over its chunks' rows
+    live = [float(n) for n in np.asarray(aux["expert_load"]).sum(axis=1)]
+    assert total(after, "hvd_moe_rows_live_share") == pytest.approx(min(
+        n / (math.ceil(n / rows) * rows) for n in live))
+
+
+def test_rows_live_share_takes_the_worst_layer_over_its_chunks():
+    """hvd_moe_rows_live_share: live assignments over the rows of the
+    chunks that ran, the lowest over the layers; a layer with no live
+    assignment ran no chunk and is left out."""
+    from horovod_tpu import metrics
+    metrics.MOE_CHUNK_ROWS.set(512)
+    metrics.record_moe_routing({
+        "expert_load": [[100.0, 200.0], [300.0, 400.0], [0.0, 0.0]],
+        "unrouted_tokens": [0.0, 0.0, 0.0]})
+    share = metrics.snapshot()["hvd_moe_rows_live_share"]
+    assert share["type"] == "gauge"
+    # 300 of one chunk against 700 of two
+    assert share["values"][""] == pytest.approx(300 / 512)
+    metrics.record_moe_routing({"expert_load": [[512.0, 512.0]],
+                                "unrouted_tokens": [0.0]})
+    assert metrics.snapshot()["hvd_moe_rows_live_share"]["values"][""] == 1
