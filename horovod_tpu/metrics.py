@@ -837,6 +837,16 @@ KDA_FUSED_LAYERS = _registry.gauge(
     "hvd_kda_layers is set.")
 
 
+CONV_FUSED_CALLS = _registry.gauge(
+    "hvd_conv_fused_calls",
+    "Depthwise causal convolutions (with their bias and SiLU) of that "
+    "model that compiled to the Pallas kernel pair (ops/causal_conv.py: "
+    "channels of whole 128-lane tiles, a length of whole time blocks, a "
+    "TPU or the interpreter): one a Mamba-2 layer, three a KDA layer; "
+    "the others, and the short convolution's, run as XLA ops. Set where "
+    "hvd_kda_layers is set.")
+
+
 def record_kda_state(stats):
     """:func:`record_ssm_state` for the KDA layers: set
     hvd_kda_state_rms{layer} from the fetched aux's ``kda_state_rms``

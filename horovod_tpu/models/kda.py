@@ -62,7 +62,9 @@ cut a chunk of 64 their own way (ops/kda_scan.py) and write only ``o``,
 the final state and, for the backward, the state before each chunk.
 
 Device scopes: ``hvd_kda`` around ``hvd_kda_in_proj`` (q, k, v, the two
-low-rank pairs, beta), ``hvd_kda_conv``, ``hvd_kda_scan`` (l2norm,
+low-rank pairs, beta), ``hvd_kda_conv`` (the three convolutions with
+their SiLU, ``ssm.conv_silu``: with its kernels, ``hvd_conv_fwd`` and
+``hvd_conv_bwd`` inside it), ``hvd_kda_scan`` (l2norm,
 softplus and decays, the chunk's products and solve, the recurrence over
 chunks — with the kernels, ``hvd_kda_fwd`` and ``hvd_kda_bwd`` inside it),
 ``hvd_kda_norm`` and ``hvd_kda_out_proj``.
@@ -81,9 +83,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..ops import kda_scan
+from ..ops import causal_conv, kda_scan
 from ..utils.logging import get_logger
-from .ssm import causal_conv1d
+from .ssm import conv_silu
 
 # Positions of a chunk and of a sub-chunk (see the module docstring) and the
 # chunks a step of the scan's loop takes in the XLA form: the
@@ -124,6 +126,13 @@ class KDAConfig:
         """Whether the recurrence runs as the kernel pair: read off the
         head size alone."""
         return kda_scan.takes(self.head_dim)
+
+    def conv_fused(self, length):
+        """Whether the three convolutions of ``length`` positions run as
+        the convolution's kernel pair: read off the shapes
+        (``ssm.conv_silu``)."""
+        return causal_conv.takes(self.d_inner, length, self.d_conv, 0,
+                                 self.interpret)
 
 
 @functools.lru_cache(maxsize=None)
@@ -392,9 +401,9 @@ def kda_mixer(params, h, cfg):
             # beta stays float32: it enters a solve
             beta = proj(h, "w_b")
         with jax.named_scope("hvd_kda_conv"):
-            q, k, v = (jax.nn.silu(causal_conv1d(
-                x, params["conv_w"][i], jnp.zeros((), f32))).astype(dtype)
-                for i, x in enumerate(qkv))
+            q, k, v = (conv_silu(x, params["conv_w"][i], None,
+                                 cfg.interpret)
+                       for i, x in enumerate(qkv))
         with jax.named_scope("hvd_kda_scan"):
             if cfg.fused:
                 o, state = kda_scan.kda_scan(

@@ -28,7 +28,14 @@ are float32 whatever ``dtype`` is; the matrix products take their
 operands in ``dtype`` and accumulate in float32, like every other matmul
 of the model.
 
-Device scopes: ``hvd_ssm`` around ``hvd_ssm_in_proj``, ``hvd_ssm_conv``,
+The convolution with its bias and SiLU is the kernel pair of
+``ops/causal_conv.py`` (:func:`conv_silu`), which reads xBC in place in
+the in-projection's output; where the shapes are not whole lane tiles and
+time blocks, or off the TPU outside the interpreter, it is
+:func:`causal_conv1d` and ``jax.nn.silu`` as XLA ops.
+
+Device scopes: ``hvd_ssm`` around ``hvd_ssm_in_proj``, ``hvd_ssm_conv``
+(with the kernels, ``hvd_conv_fwd`` and ``hvd_conv_bwd`` inside it),
 ``hvd_ssm_scan`` (softplus, decays, intra-chunk products, chunk states,
 the recurrence over chunks, state -> output, the D skip),
 ``hvd_ssm_norm`` and ``hvd_ssm_out_proj``.
@@ -46,6 +53,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ..ops import causal_conv
 
 # Chunks a step of the scan's loop takes, i.e. whose decay blocks are live
 # at once (see the module docstring). Swept on the v5e at the published
@@ -70,6 +79,7 @@ class SSMConfig:
     norm_eps: float
     dtype: Any
     param_dtype: Any
+    interpret: bool = False     # the kernels in the Pallas interpreter
 
     @property
     def d_inner(self):
@@ -78,6 +88,12 @@ class SSMConfig:
     @property
     def conv_dim(self):
         return self.d_inner + 2 * self.d_state
+
+    def conv_fused(self, length):
+        """Whether the convolution of ``length`` positions runs as the
+        kernel pair: read off the shapes (:func:`conv_silu`)."""
+        return causal_conv.takes(self.conv_dim, length, self.d_conv,
+                                 self.d_inner, self.interpret)
 
 
 def init_ssm_params(key, cfg):
@@ -133,6 +149,20 @@ def causal_conv1d(x, w, b):
             jnp.float32)
         y = tap if y is None else y + tap
     return y
+
+
+def conv_silu(x, w, b, interpret=False, offset=0):
+    """``silu(causal_conv1d(x[..., offset:], w, b))`` in x's type, the
+    channels being w's: the kernel pair of ``ops/causal_conv.py`` where
+    it takes the shapes (:func:`causal_conv.takes`), read in place at that
+    lane offset; otherwise the slice and the XLA form above."""
+    channels = w.shape[1]
+    if causal_conv.takes(channels, x.shape[1], w.shape[0], offset,
+                         interpret):
+        return causal_conv.conv_silu(x, w, b, offset=offset,
+                                     interpret=interpret)
+    x = x[..., offset:offset + channels]
+    return jax.nn.silu(causal_conv1d(x, w, b)).astype(x.dtype)
 
 
 def _ssd_block(state, blk, a, d):
@@ -237,10 +267,10 @@ def mamba2_mixer(params, h, cfg):
             # the step sizes stay float32: they are summed over a chunk
             dt = jnp.einsum("bld,dh->blh", h, w[:, di + cfg.conv_dim:],
                             preferred_element_type=f32)
-            z, xbc = zx[..., :di], zx[..., di:]
+            z = zx[..., :di]
         with jax.named_scope("hvd_ssm_conv"):
-            xbc = jax.nn.silu(causal_conv1d(
-                xbc, params["conv_w"], params["conv_b"])).astype(dtype)
+            xbc = conv_silu(zx, params["conv_w"], params["conv_b"],
+                            cfg.interpret, offset=di)
         with jax.named_scope("hvd_ssm_scan"):
             x = xbc[..., :di].reshape(b, l, hn, p)
             dt = jax.nn.softplus(dt + params["dt_bias"].astype(f32))

@@ -365,7 +365,8 @@ class TransformerConfig:
                          d_state=self.ssm_state, d_conv=self.ssm_conv,
                          chunk=self.ssm_chunk,
                          norm_eps=self.norm_eps, dtype=self.dtype,
-                         param_dtype=self.param_dtype)
+                         param_dtype=self.param_dtype,
+                         interpret=self.flash_interpret)
 
 
     @property
@@ -1165,6 +1166,11 @@ def trunk_with_stats(params, tokens, cfg, axes=None):
     metrics.KDA_FUSED_LAYERS.set(
         cfg.kda_layers if cfg.kda_cfg.fused else 0)
     metrics.SCONV_LAYERS.set(cfg.sconv_layers)
+    length = tokens.shape[1]
+    metrics.CONV_FUSED_CALLS.set(
+        sum(l.mixer == "mamba2" for l in cfg.layers)
+        * cfg.ssm_cfg.conv_fused(length)
+        + 3 * cfg.kda_layers * cfg.kda_cfg.conv_fused(length))
     x = embed_tokens(params, tokens, cfg, axes)
     aux_total = jnp.zeros((), jnp.float32)
 

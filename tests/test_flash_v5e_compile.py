@@ -191,6 +191,51 @@ def test_kda_kernels_compile_for_v5e(one_chip):
                 for line in calls) == 1
 
 
+@pytest.mark.parametrize("cell", ["granite", "kimi"])
+def test_conv_kernels_compile_for_v5e(one_chip, cell):
+    """The convolution's kernel pair at the two cells' shapes, bf16 over
+    16,384 positions: granite4h-micro_s16k's xBC, 4,352 channels read at
+    lane 4,096 of the in-projection's 8,448-wide output with a bias, and
+    one of kimi-linear_s16k's q / k / v, 4,096 channels without one —
+    forward and, under the gradient, forward and backward. Rows off the
+    8-row tiling and the VMEM a block takes are what the interpreter does
+    not see."""
+    from horovod_tpu.ops import causal_conv
+    scope, width, offset, channels, bias = {
+        "granite": ("hvd_ssm_conv", 8448, 4096, 4352, True),
+        "kimi": ("hvd_kda_conv", 4096, 0, 4096, False)}[cell]
+    assert causal_conv.takes(channels, 16384, 4, offset, interpret=True)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (like((1, 16384, width), jnp.bfloat16),
+            like((4, channels), jnp.float32),
+            like((channels,), jnp.float32) if bias else None)
+
+    def conv(x, w, b):
+        with jax.named_scope(scope):
+            return causal_conv.conv_silu(x, w, b, offset=offset)
+
+    def loss(*a):
+        return jnp.sum(jnp.square(conv(*a).astype(jnp.float32)))
+
+    # the compiled op_name joins the caller's scope and the kernel's
+    # name: what dev_ssm_conv_ms / dev_kda_conv_ms, conv_fwd_ms and
+    # conv_bwd_ms select on
+    for fn, names in ((conv, ["hvd_conv_fwd"]),
+                      (jax.grad(loss, argnums=(0, 1, 2) if bias else (0, 1)),
+                       ["hvd_conv_fwd", "hvd_conv_bwd"])):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == len(names)
+        for kernel in names:
+            assert sum(bool(re.search(
+                rf"{scope}\)*/jit\(_(for|back)ward\)/{kernel}/", line))
+                for line in calls) == 1, calls
+
+
 # (rows, contraction, columns): laguna-s21_s8k's expert matrices over one
 # chunk of sorted rows (moe.chunk_rows at 16,384 tokens), 8 experts, bf16;
 # lfm2-24b-a2b_s16k's: a chunk of 16,384 rows, experts 1536 wide (12 lane

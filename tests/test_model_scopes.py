@@ -50,7 +50,7 @@ FINER = NEW_NAMES + (
     "hvd_ssm_conv", "hvd_ssm_scan", "hvd_ssm_norm", "hvd_ssm_out_proj",
     "hvd_kda", "hvd_kda_in_proj", "hvd_kda_conv", "hvd_kda_scan",
     "hvd_kda_norm", "hvd_kda_out_proj", "hvd_kda_fwd", "hvd_kda_bwd",
-    "hvd_sconv", "hvd_sconv_in_proj", "hvd_sconv_gate",
+    "hvd_conv_fwd", "hvd_conv_bwd", "hvd_sconv", "hvd_sconv_in_proj", "hvd_sconv_gate",
     "hvd_sconv_out_proj", "hvd_qk_norm", "hvd_ici", "hvd_dcn",
     "hvd_prefill", "hvd_decode")
 
